@@ -175,9 +175,12 @@ def child_main(fast=False):
 
 def main(fast=False):
     """Re-exec in a subprocess with 2 fake CPU devices (the parent
-    process may already hold a single-device jax)."""
+    process may already hold a single-device jax, or the chip).  The
+    child runs on the CPU backend, so its rows are host times and
+    launch/collective counts, never device numbers."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("JAX_ENABLE_X64", "1")
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [sys.executable, "-m", "benchmarks.distributed", "--child"]

@@ -77,9 +77,10 @@ def _est_host_bytes(B: int, itemsize: int = 4, streaming: bool = False) -> int:
 
 def _build_rung(B: int, lchunk: int, max_rss_bytes: int):
     """Plan-construction rung measured in a FRESH subprocess
-    (tests/progs/build_smoke.py): wall time + host peak RSS of a
-    streaming B-plan build, with the dense-table canary and the RSS
-    ceiling enforced inside the program.  Returns (row, failure)."""
+    (tests/progs/build_smoke.py) on the CPU backend: host wall time +
+    host peak RSS of a streaming B-plan build, with the dense-table
+    canary and the RSS ceiling enforced inside the program.  These are
+    host numbers, never device ones.  Returns (row, failure)."""
     import json
     import subprocess
 
@@ -87,6 +88,9 @@ def _build_rung(B: int, lchunk: int, max_rss_bytes: int):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(root / "src") + os.pathsep \
         + env.get("PYTHONPATH", "")
+    # the child measures host RSS and build time only, on the CPU: this
+    # process may already hold the chip, which one process owns at a time
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, str(root / "tests" / "progs" / "build_smoke.py"),
          "--bandwidth", str(B), "--lchunk", str(lchunk),

@@ -106,6 +106,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_enable_x64", True)  # error tables need f64
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     wanted = [args.section] if args.section else list(SECTIONS)
     t_all = time.time()

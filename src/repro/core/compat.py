@@ -1,88 +1,44 @@
-"""Version-compat shims for JAX APIs that moved between releases.
+"""The repo's one place for JAX mesh and shard_map conventions.
 
-`shard_map` graduated from `jax.experimental.shard_map` to the top-level
-`jax` namespace (jax >= 0.6), and its replication-check kwarg was renamed
-(check_rep -> check_vma).  This repo targets whichever is available so the
-same code runs on the pinned 0.4.x container and on current releases.
 Import from here everywhere:
 
-    from repro.core.compat import shard_map          # kwarg-normalizing
+    from repro.core.compat import shard_map          # jax.shard_map
     from repro.core.compat import shard_map_norep    # checks disabled
-    from repro.core.compat import make_mesh          # tolerates no axis_types
+    from repro.core.compat import make_mesh          # Auto axis types
+
+Every mesh in the repo is built by :func:`make_mesh`, which pins all axes
+to ``AxisType.Auto``: the shard_map paths place their operands through
+explicit ``in_specs``/``out_specs`` and leave everything outside the
+shard_map to the compiler's sharding propagation.  Under jax's default of
+Explicit axes, host-side scatters on sharded outputs (``packed_to_dense``)
+would have to name their output sharding.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
+import jax
+from jax import shard_map
+from jax.sharding import AxisType
 
 __all__ = ["shard_map", "shard_map_norep", "make_mesh", "axis_size",
            "cost_analysis_dict"]
 
-
-def axis_size(axis_name):
-    """jax.lax.axis_size, or the psum(1) idiom where it does not exist yet
-    (pre-0.5 jax).  Only valid inside a named-axis context (shard_map)."""
-    import jax
-
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
+axis_size = jax.lax.axis_size
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() normalized to one dict.
-
-    Older jax returns a list with one per-device dict; newer returns the
-    dict directly; either may be None for empty programs.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return ca or {}
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=None,
-              check_vma=None, **kwargs):
-    """shard_map accepting either spelling of the replication-check kwarg.
-
-    check_rep (jax < 0.6) and check_vma (jax >= 0.6) are the same switch;
-    pass whichever -- the available one is used, and if the installed jax
-    accepts neither the flag is dropped (equivalent to the default True,
-    which only affects error checking, not results).
-    """
-    flag = check_vma if check_vma is not None else check_rep
-    attempts = ([{}] if flag is None else
-                [{"check_rep": flag}, {"check_vma": flag}, {}])
-    for kw in attempts:
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw, **kwargs)
-        except TypeError:
-            continue
-    raise RuntimeError("unreachable: shard_map rejected all signatures")
+    """compiled.cost_analysis() as a dict ({} for empty programs)."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
     """shard_map with replication checking off -- required for bodies that
-    contain pallas_call, which has no replication rule on older jax."""
+    contain pallas_call, which has no replication rule."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                      check_vma=False)
 
 
-def make_mesh(axis_shapes, axis_names, **kwargs):
-    """jax.make_mesh that tolerates the pre-0.5 signature (no axis_types).
-
-    On older jax the axis_types kwarg (jax.sharding.AxisType) does not
-    exist; every mesh axis behaves as Auto there, which is what the
-    shard_map paths in this repo assume anyway.
-    """
-    import jax
-
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    except (TypeError, AttributeError):
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """jax.make_mesh with every axis pinned to ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

@@ -557,7 +557,9 @@ class DistExecutor:
             # footprint stays at the two-slot buffer, not the batch.
             first = reshard(stage1(f_all[0]))
             buf = jnp.zeros((2,) + first.shape, first.dtype).at[0].set(first)
-            out = jnp.zeros((nc, V, first.shape[0], L, C), cdtype)
+            # the carry varies over the shard axes, as the body's updates do
+            out = jax.lax.pcast(jnp.zeros((nc, V, first.shape[0], L, C),
+                                          cdtype), self.axis, to="varying")
 
             def step(i, carry):
                 buf, out = carry
@@ -610,7 +612,8 @@ class DistExecutor:
             jloc = 2 * B // n
             first = stage1(packed_all[0])         # prologue: chunk 0 kernel
             buf = jnp.zeros((2,) + first.shape, first.dtype).at[0].set(first)
-            out = jnp.zeros((nc, V, 2 * B, jloc, 2 * B), cdtype)
+            out = jax.lax.pcast(jnp.zeros((nc, V, 2 * B, jloc, 2 * B),
+                                          cdtype), self.axis, to="varying")
 
             def step(i, carry):
                 buf, out = carry

@@ -13,12 +13,13 @@ of shape (B, 2B-1, 2B-1); entries with l < max(|m|, |m'|) are zero.
 Sample layout: complex array f[i, j, k] on the (alpha_i, beta_j, gamma_k)
 grid of shape (2B, 2B, 2B).
 
-jnp is used throughout so the same code runs under jit; tests run in f64.
+Everything here is numpy in complex128 on the host, whatever jax's x64
+flag says: the chip path runs f32 with x64 off and is checked against
+these f64 results.
 """
 from __future__ import annotations
 
 import numpy as np
-import jax.numpy as jnp
 
 from . import quadrature, wigner
 
@@ -120,16 +121,16 @@ def inverse_soft(fhat, d_table=None):
     iDWT (g = sum_l fhat d) followed by an unnormalized forward 2-D FFT
     over the m -> i and m' -> k axes.
     """
+    fhat = np.asarray(fhat, np.complex128)
     B = fhat.shape[0]
     if d_table is None:
         d_table = wigner.wigner_d_table(B)
-    d = jnp.asarray(d_table)
-    fhat = jnp.asarray(fhat)
-    g = jnp.einsum("lmp,lmpj->mjp", fhat, d.astype(fhat.real.dtype))
+    d = np.asarray(d_table, np.float64)
+    g = np.einsum("lmp,lmpj->mjp", fhat, d, optimize=True)
     bins = _bin_index(B)
-    gbin = jnp.zeros((2 * B, 2 * B, 2 * B), dtype=fhat.dtype)
-    gbin = gbin.at[jnp.ix_(bins, jnp.arange(2 * B), bins)].set(g)
-    return jnp.fft.fft(jnp.fft.fft(gbin, axis=0), axis=2)
+    gbin = np.zeros((2 * B, 2 * B, 2 * B), np.complex128)
+    gbin[np.ix_(bins, np.arange(2 * B), bins)] = g
+    return np.fft.fft(np.fft.fft(gbin, axis=0), axis=2)
 
 
 def forward_soft(f, B: int, d_table=None):
@@ -140,13 +141,12 @@ def forward_soft(f, B: int, d_table=None):
     """
     if d_table is None:
         d_table = wigner.wigner_d_table(B)
-    d = jnp.asarray(d_table)
-    f = jnp.asarray(f)
-    S = (2 * B) ** 2 * jnp.fft.ifft(jnp.fft.ifft(f, axis=0), axis=2)
+    d = np.asarray(d_table, np.float64)
+    f = np.asarray(f, np.complex128)
+    S = (2 * B) ** 2 * np.fft.ifft(np.fft.ifft(f, axis=0), axis=2)
     bins = _bin_index(B)
-    Ssel = S[jnp.ix_(bins, jnp.arange(2 * B), bins)]  # (2B-1, 2B, 2B-1)
-    w = jnp.asarray(quadrature.weights(B))
-    scale = jnp.asarray((2 * np.arange(B) + 1) / (8 * np.pi * B))
-    out = jnp.einsum("lmpj,j,mjp->lmp", d.astype(f.real.dtype),
-                     w.astype(f.real.dtype), Ssel)
-    return scale[:, None, None] * out * jnp.asarray(coeff_mask(B))
+    Ssel = S[np.ix_(bins, np.arange(2 * B), bins)]  # (2B-1, 2B, 2B-1)
+    w = quadrature.weights(B)
+    scale = (2 * np.arange(B) + 1) / (8 * np.pi * B)
+    out = np.einsum("lmpj,j,mjp->lmp", d, w, Ssel, optimize=True)
+    return scale[:, None, None] * out * coeff_mask(B)

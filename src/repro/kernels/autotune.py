@@ -97,9 +97,10 @@ PRECISION_BOUND_EXTRAPOLATED = frozenset({256, 512})
 # tests/test_streaming.py and benchmarks/error_table.py measure the
 # roundtrip against these gates, so a recurrence/seed change that worsens
 # the drift fails loudly instead of silently degrading f32 serving.
-# B <= 64 measured on this host (worst of 3 seeds: 7.3e-6 / 1.9e-5 /
+# B <= 64 measured on the CPU (worst of 3 seeds: 7.3e-6 / 1.9e-5 /
 # 1.5e-3 / 1.3e-3); B = 128 carries the ~0.13 streaming-plan measurement
-# recorded in ROADMAP.md.
+# on the CPU (max-abs over max-abs).  On a TPU v5e chip_smoke.py measures
+# 0.337 at B = 128 with that metric: the chip's f32 drift is larger.
 FP32_ROUNDTRIP_BOUNDS = {
     8: 3e-5,
     16: 8e-5,
@@ -233,7 +234,9 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
     (None) when the full (TK, L, C2) coefficient tile fits the VMEM
     ceiling, otherwise the LARGEST divisor lchunk of L that fits (largest
     chunk = fewest window reloads + longest in-kernel recurrence runs).
-    Raises when not even lchunk = 1 fits (shrink tk or V instead).
+    Only chunks the TPU tiling accepts are candidates: multiples of 8, or
+    L itself (:func:`repro.kernels.streaming.check_lchunk`).  Raises when
+    no candidate fits (shrink tk or V instead).
 
     ``monolithic_ok=False`` skips the monolithic fast-path and admits
     lchunk = L as a candidate: bf16 schedules have no monolithic kernel
@@ -249,13 +252,15 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
     if monolithic_ok and est(None) <= limit:
         return None
     top = L + 1 if not monolithic_ok else L
-    for lc in sorted((d for d in range(1, top) if L % d == 0), reverse=True):
+    for lc in sorted((d for d in range(1, top)
+                      if L % d == 0 and (d % 8 == 0 or d == L)),
+                     reverse=True):
         if est(lc) <= limit:
             return lc
     raise RuntimeError(
         f"no l-chunk fits the {limit}-byte VMEM ceiling at L={L}, J={J}, "
-        f"C2={C2}, tk={tk} (even lchunk=1; shrink tk/V or raise "
-        f"$REPRO_VMEM_BYTES)")
+        f"C2={C2}, tk={tk} (chunks are multiples of 8 or L; shrink tk/V "
+        f"or raise $REPRO_VMEM_BYTES)")
 
 
 def cache_path() -> pathlib.Path:

@@ -42,8 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .runtime import resolve_interpret
-from .wigner_rec import _recurrence_step
+from .runtime import I0, resolve_interpret
+from .wigner_rec import _contract_row, _recurrence_step
 
 __all__ = ["build_tile_lstarts", "dwt_fused", "idwt_fused"]
 
@@ -78,14 +78,12 @@ def _fused_fwd_kernel(L, l0_ref, seeds_ref, m_ref, mp_ref, cb_ref, r_ref,
     def body(l, _):
         row, p, c = _recurrence_step(l, m, mp, cb, prev_ref[...],
                                      cur_ref[...], seeds)
-        o_ref[:, pl.ds(l, 1), :] = jnp.einsum(
-            "kj,kjc->kc", row, r_ref[...],
-            preferred_element_type=o_ref.dtype)[:, None, :]
+        o_ref[:, pl.ds(l, 1), :] = _contract_row(row, r_ref[...],
+                                                 o_ref.dtype)[:, None, :]
         prev_ref[...] = p
         cur_ref[...] = c
-        return 0
 
-    jax.lax.fori_loop(l0, L, body, 0)
+    jax.lax.fori_loop(l0, jnp.int32(L), body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "interpret"))
@@ -113,13 +111,13 @@ def dwt_fused(seeds, m, mp, cos_beta, rhs, l0s, *, B, tk=8, interpret=None):
             num_scalar_prefetch=1,
             grid=(K // tk,),
             in_specs=[
-                pl.BlockSpec((tk, J), lambda k, l0s: (k, 0)),      # seeds
-                pl.BlockSpec((tk, 1), lambda k, l0s: (k, 0)),      # m
-                pl.BlockSpec((tk, 1), lambda k, l0s: (k, 0)),      # mp
-                pl.BlockSpec((1, J), lambda k, l0s: (0, 0)),       # cos_beta
-                pl.BlockSpec((tk, J, C2), lambda k, l0s: (k, 0, 0)),
+                pl.BlockSpec((tk, J), lambda k, l0s: (k, I0)),      # seeds
+                pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),      # m
+                pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),      # mp
+                pl.BlockSpec((1, J), lambda k, l0s: (I0, I0)),     # cos_beta
+                pl.BlockSpec((tk, J, C2), lambda k, l0s: (k, I0, I0)),
             ],
-            out_specs=pl.BlockSpec((tk, B, C2), lambda k, l0s: (k, 0, 0)),
+            out_specs=pl.BlockSpec((tk, B, C2), lambda k, l0s: (k, I0, I0)),
             scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt)],
         ),
         out_shape=jax.ShapeDtypeStruct((K, B, C2), dt),
@@ -149,9 +147,8 @@ def _fused_inv_kernel(L, l0_ref, seeds_ref, m_ref, mp_ref, cb_ref, l_ref,
         o_ref[...] += row[:, :, None] * lhs_l
         prev_ref[...] = p
         cur_ref[...] = c
-        return 0
 
-    jax.lax.fori_loop(l0, L, body, 0)
+    jax.lax.fori_loop(l0, jnp.int32(L), body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "interpret"))
@@ -173,13 +170,13 @@ def idwt_fused(seeds, m, mp, cos_beta, lhs, l0s, *, B, tk=8, interpret=None):
             num_scalar_prefetch=1,
             grid=(K // tk,),
             in_specs=[
-                pl.BlockSpec((tk, J), lambda k, l0s: (k, 0)),
-                pl.BlockSpec((tk, 1), lambda k, l0s: (k, 0)),
-                pl.BlockSpec((tk, 1), lambda k, l0s: (k, 0)),
-                pl.BlockSpec((1, J), lambda k, l0s: (0, 0)),
-                pl.BlockSpec((tk, B, C2), lambda k, l0s: (k, 0, 0)),
+                pl.BlockSpec((tk, J), lambda k, l0s: (k, I0)),
+                pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),
+                pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),
+                pl.BlockSpec((1, J), lambda k, l0s: (I0, I0)),
+                pl.BlockSpec((tk, B, C2), lambda k, l0s: (k, I0, I0)),
             ],
-            out_specs=pl.BlockSpec((tk, J, C2), lambda k, l0s: (k, 0, 0)),
+            out_specs=pl.BlockSpec((tk, J, C2), lambda k, l0s: (k, I0, I0)),
             scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt)],
         ),
         out_shape=jax.ShapeDtypeStruct((K, J, C2), dt),

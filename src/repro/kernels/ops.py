@@ -244,8 +244,9 @@ def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
     interpret = default_interpret() if interpret is None else interpret
     if _check_streaming_args(impl, lchunk, precision):
         prec = precision or "fp32"
-        lchunk = streaming.check_lchunk(plan.B, plan.B if lchunk is None
-                                        else lchunk)
+        lchunk = streaming.check_lchunk(
+            plan.B, plan.B if lchunk is None else lchunk,
+            tiled=not interpret)
         tk = min(tk, plan.n_padded)
         seeds_p, m_p, mp_p, cb, l0s, windows = streaming_inputs(
             plan, tk, lchunk, prec)
@@ -317,8 +318,9 @@ def make_idwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
     interpret = default_interpret() if interpret is None else interpret
     if _check_streaming_args(impl, lchunk, precision):
         prec = precision or "fp32"
-        lchunk = streaming.check_lchunk(plan.B, plan.B if lchunk is None
-                                        else lchunk)
+        lchunk = streaming.check_lchunk(
+            plan.B, plan.B if lchunk is None else lchunk,
+            tiled=not interpret)
         tk = min(tk, plan.n_padded)
         seeds_p, m_p, mp_p, cb, l0s, windows = streaming_inputs(
             plan, tk, lchunk, prec)

@@ -6,9 +6,14 @@ defaults without importing ops (which imports them back).
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 
-__all__ = ["default_interpret", "resolve_interpret"]
+__all__ = ["I0", "default_interpret", "resolve_interpret"]
+
+# The int32 zero of BlockSpec index maps and kernel loop bounds.  A bare
+# Python 0 becomes an i64 under jax_enable_x64, which Mosaic refuses.
+I0 = np.int32(0)
 
 
 def default_interpret() -> bool:

@@ -54,21 +54,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .runtime import resolve_interpret
-from .wigner_rec import _recurrence_step
+from .runtime import I0, resolve_interpret
+from .wigner_rec import _contract_row, _recurrence_step
 
 __all__ = ["build_windows", "dwt_streaming", "idwt_streaming",
            "check_lchunk"]
 
 
-def check_lchunk(L: int, lchunk: int) -> int:
+def check_lchunk(L: int, lchunk: int, *, tiled: bool = False) -> int:
     """Validate an l-chunk size: 1 <= lchunk <= L and lchunk | L (the
-    chunk grid must tile the degree axis exactly)."""
+    chunk grid must tile the degree axis exactly).  ``tiled`` adds the
+    TPU's rule for the (tk, lchunk, C2) blocks: lchunk is a multiple of
+    the 8-row sublane tile, or the whole degree axis.  Interpret mode has
+    no tiling, so tests there may use smaller chunks."""
     lchunk = int(lchunk)
     if not 1 <= lchunk <= L:
         raise ValueError(f"lchunk={lchunk} outside [1, L={L}]")
     if L % lchunk:
         raise ValueError(f"lchunk={lchunk} does not divide L={L}")
+    if tiled and lchunk % 8 and lchunk != L:
+        raise ValueError(
+            f"lchunk={lchunk} is neither a multiple of 8 nor L={L}: the "
+            f"TPU compiler refuses its (tk, lchunk, C2) blocks")
     return lchunk
 
 
@@ -154,12 +161,10 @@ def _stream_fwd_kernel(L, lchunk, row_dtype, l0_ref, seeds_ref, m_ref,
     def body(l, _):
         row = _stream_step(l, m, mp, cb, prev_ref, cur_ref, seeds,
                            row_dtype)
-        o_ref[:, pl.ds(l - base, 1), :] = jnp.einsum(
-            "kj,kjc->kc", row, r_ref[...],
-            preferred_element_type=o_ref.dtype)[:, None, :]
-        return 0
+        o_ref[:, pl.ds(l - base, 1), :] = _contract_row(
+            row, r_ref[...], o_ref.dtype)[:, None, :]
 
-    jax.lax.fori_loop(l0, base + lchunk, body, 0)
+    jax.lax.fori_loop(l0, base + lchunk, body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "lchunk", "precision",
@@ -177,7 +182,7 @@ def dwt_streaming(seeds, m, mp, cos_beta, rhs, l0s, windows, *, B, tk=8,
     stay in the plan dtype).  Returns out (K, B, C2) in the rhs dtype.
     """
     interpret = resolve_interpret(interpret)
-    lchunk = check_lchunk(B, lchunk)
+    lchunk = check_lchunk(B, lchunk, tiled=not interpret)
     K, J = seeds.shape
     C2 = rhs.shape[-1]
     tk = min(tk, K)
@@ -197,16 +202,16 @@ def dwt_streaming(seeds, m, mp, cos_beta, rhs, l0s, windows, *, B, tk=8,
             num_scalar_prefetch=1,
             grid=(K // tk, nL),
             in_specs=[
-                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, 0)),   # seeds
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, 0)),   # m
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, 0)),   # mp
-                pl.BlockSpec((1, J), lambda k, lc, l0s: (0, 0)),    # cos_beta
+                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, I0)),   # seeds
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # m
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # mp
+                pl.BlockSpec((1, J), lambda k, lc, l0s: (I0, I0)),  # cos_beta
                 pl.BlockSpec((1, 2, tk, J),
-                             lambda k, lc, l0s: (lc, 0, k, 0)),     # windows
-                pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, 0, 0)),
+                             lambda k, lc, l0s: (lc, I0, k, I0)),  # windows
+                pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0)),
             ],
             out_specs=pl.BlockSpec((tk, lchunk, C2),
-                                   lambda k, lc, l0s: (k, lc, 0)),
+                                   lambda k, lc, l0s: (k, lc, I0)),
             scratch_shapes=[pltpu.VMEM((tk, J), dt),
                             pltpu.VMEM((tk, J), dt)],
         ),
@@ -242,10 +247,11 @@ def _stream_inv_kernel(L, lchunk, row_dtype, l0_ref, seeds_ref, m_ref,
         row = _stream_step(l, m, mp, cb, prev_ref, cur_ref, seeds,
                            row_dtype)
         lhs_l = l_ref[:, pl.ds(l - base, 1), :]          # (TK, 1, C2)
-        o_ref[...] += (row[:, :, None] * lhs_l).astype(o_ref.dtype)
-        return 0
+        # widen the row before the broadcast (no bf16 shape cast in Mosaic)
+        o_ref[...] += (row.astype(o_ref.dtype)[:, :, None]
+                       * lhs_l).astype(o_ref.dtype)
 
-    jax.lax.fori_loop(l0, base + lchunk, body, 0)
+    jax.lax.fori_loop(l0, base + lchunk, body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "lchunk", "precision",
@@ -256,7 +262,7 @@ def idwt_streaming(seeds, m, mp, cos_beta, lhs, l0s, windows, *, B, tk=8,
     stays HBM-resident and is staged chunk-by-chunk into (tk, lchunk, C2)
     VMEM tiles; see :func:`dwt_streaming`.  Returns g (K, J, C2)."""
     interpret = resolve_interpret(interpret)
-    lchunk = check_lchunk(B, lchunk)
+    lchunk = check_lchunk(B, lchunk, tiled=not interpret)
     K, J = seeds.shape
     C2 = lhs.shape[-1]
     tk = min(tk, K)
@@ -276,17 +282,17 @@ def idwt_streaming(seeds, m, mp, cos_beta, lhs, l0s, windows, *, B, tk=8,
             num_scalar_prefetch=1,
             grid=(K // tk, nL),
             in_specs=[
-                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, 0)),
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, 0)),
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, 0)),
-                pl.BlockSpec((1, J), lambda k, lc, l0s: (0, 0)),
+                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, I0)),
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),
+                pl.BlockSpec((1, J), lambda k, lc, l0s: (I0, I0)),
                 pl.BlockSpec((1, 2, tk, J),
-                             lambda k, lc, l0s: (lc, 0, k, 0)),
+                             lambda k, lc, l0s: (lc, I0, k, I0)),
                 pl.BlockSpec((tk, lchunk, C2),
-                             lambda k, lc, l0s: (k, lc, 0)),        # staged
+                             lambda k, lc, l0s: (k, lc, I0)),      # staged
             ],
-            out_specs=pl.BlockSpec((tk, J, C2),
-                                   lambda k, lc, l0s: (k, 0, 0)),   # revisited
+            out_specs=pl.BlockSpec((tk, J, C2),     # revisited over lc
+                                   lambda k, lc, l0s: (k, I0, I0)),
             scratch_shapes=[pltpu.VMEM((tk, J), dt),
                             pltpu.VMEM((tk, J), dt)],
         ),

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .runtime import resolve_interpret
+from .runtime import I0, resolve_interpret
 
 __all__ = ["dwt_onthefly", "idwt_onthefly"]
 
@@ -62,6 +62,16 @@ def _recurrence_step(l, m, mp, cb, d_prev, d_cur, seeds):
     return row, d_prev_new, d_cur_new
 
 
+def _contract_row(row, rhs, out_dtype):
+    """Fold one generated degree-row into the forward contraction:
+    out[k, c] = sum_j row[k, j] * rhs[k, j, c], shared by every forward
+    kernel.  A broadcast multiply and a reduction over J, which Mosaic
+    lowers; it refuses the batched mat-vec einsum ("kj,kjc->kc")."""
+    # cast before the broadcast: Mosaic has no bf16 (K, J) -> (K, J, 1)
+    return jnp.sum(row.astype(out_dtype)[:, :, None] * rhs.astype(out_dtype),
+                   axis=1)
+
+
 def _fwd_kernel(L, seeds_ref, m_ref, mp_ref, cb_ref, r_ref, o_ref,
                 prev_ref, cur_ref):
     seeds = seeds_ref[...]
@@ -74,15 +84,12 @@ def _fwd_kernel(L, seeds_ref, m_ref, mp_ref, cb_ref, r_ref, o_ref,
     def body(l, _):
         row, p, c = _recurrence_step(l, m, mp, cb, prev_ref[...],
                                      cur_ref[...], seeds)
-        # fold row l into the output: out[k, l, c] = sum_j row[k, j] rhs[k, j, c]
-        o_ref[:, pl.ds(l, 1), :] = jnp.einsum(
-            "kj,kjc->kc", row, r_ref[...],
-            preferred_element_type=o_ref.dtype)[:, None, :]
+        o_ref[:, pl.ds(l, 1), :] = _contract_row(row, r_ref[...],
+                                                 o_ref.dtype)[:, None, :]
         prev_ref[...] = p
         cur_ref[...] = c
-        return 0
 
-    jax.lax.fori_loop(0, L, body, 0)
+    jax.lax.fori_loop(I0, jnp.int32(L), body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "interpret"))
@@ -106,13 +113,13 @@ def dwt_onthefly(seeds, m, mp, cos_beta, rhs, *, B, tk=8, interpret=None):
         partial(_fwd_kernel, B),
         grid=(K // tk,),
         in_specs=[
-            pl.BlockSpec((tk, J), lambda k: (k, 0)),    # seeds
-            pl.BlockSpec((tk, 1), lambda k: (k, 0)),    # m
-            pl.BlockSpec((tk, 1), lambda k: (k, 0)),    # mp
-            pl.BlockSpec((1, J), lambda k: (0, 0)),     # cos_beta
-            pl.BlockSpec((tk, J, C2), lambda k: (k, 0, 0)),
+            pl.BlockSpec((tk, J), lambda k: (k, I0)),    # seeds
+            pl.BlockSpec((tk, 1), lambda k: (k, I0)),    # m
+            pl.BlockSpec((tk, 1), lambda k: (k, I0)),    # mp
+            pl.BlockSpec((1, J), lambda k: (I0, I0)),   # cos_beta
+            pl.BlockSpec((tk, J, C2), lambda k: (k, I0, I0)),
         ],
-        out_specs=pl.BlockSpec((tk, B, C2), lambda k: (k, 0, 0)),
+        out_specs=pl.BlockSpec((tk, B, C2), lambda k: (k, I0, I0)),
         out_shape=jax.ShapeDtypeStruct((K, B, C2), dt),
         scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt)],
         interpret=interpret,
@@ -138,9 +145,8 @@ def _inv_kernel(L, seeds_ref, m_ref, mp_ref, cb_ref, l_ref, o_ref,
         o_ref[...] += row[:, :, None] * lhs_l
         prev_ref[...] = p
         cur_ref[...] = c
-        return 0
 
-    jax.lax.fori_loop(0, L, body, 0)
+    jax.lax.fori_loop(I0, jnp.int32(L), body, None)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "interpret"))
@@ -163,13 +169,13 @@ def idwt_onthefly(seeds, m, mp, cos_beta, lhs, *, B, tk=8, interpret=None):
         partial(_inv_kernel, B),
         grid=(K // tk,),
         in_specs=[
-            pl.BlockSpec((tk, J), lambda k: (k, 0)),
-            pl.BlockSpec((tk, 1), lambda k: (k, 0)),
-            pl.BlockSpec((tk, 1), lambda k: (k, 0)),
-            pl.BlockSpec((1, J), lambda k: (0, 0)),
-            pl.BlockSpec((tk, B, C2), lambda k: (k, 0, 0)),
+            pl.BlockSpec((tk, J), lambda k: (k, I0)),
+            pl.BlockSpec((tk, 1), lambda k: (k, I0)),
+            pl.BlockSpec((tk, 1), lambda k: (k, I0)),
+            pl.BlockSpec((1, J), lambda k: (I0, I0)),
+            pl.BlockSpec((tk, B, C2), lambda k: (k, I0, I0)),
         ],
-        out_specs=pl.BlockSpec((tk, J, C2), lambda k: (k, 0, 0)),
+        out_specs=pl.BlockSpec((tk, J, C2), lambda k: (k, I0, I0)),
         out_shape=jax.ShapeDtypeStruct((K, J, C2), dt),
         scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt)],
         interpret=interpret,

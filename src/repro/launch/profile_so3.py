@@ -68,9 +68,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     import numpy as np
     from repro import obs, plan as plan_mod
@@ -86,7 +83,7 @@ def main(argv=None):
     #    autotuner to actually time candidates into the trace
     plan_mod.clear_cache()
     with tempfile.TemporaryDirectory() as tmp:
-        t = plan_mod.plan(B, dtype=jnp.float64, V=V, tune="measure",
+        t = plan_mod.plan(B, dtype=jnp.float32, V=V, tune="measure",
                           tune_cache=os.path.join(tmp, "tune.json"))
     print(f"plan: B={B} V={t.describe()['V']} "
           f"[{t.describe()['source']}]")
@@ -94,11 +91,11 @@ def main(argv=None):
     # 1b. streaming plan build: windowed construction (no dense d table)
     #     emits the plan.build.window span when the kernels stage their
     #     HBM window stacks, plus the plan.host_peak_rss counter
-    ts = plan_mod.plan(B, dtype=jnp.float64, impl="fused", V=1,
-                       lchunk=max(1, B // 4), streaming=True,
-                       interpret=True)
+    #     (lchunk = B: one chunk, which every backend's tiling accepts)
+    ts = plan_mod.plan(B, dtype=jnp.float32, impl="fused", V=1, lchunk=B,
+                       streaming=True)
     ts.dwt_fn, ts.idwt_fn           # window stacks are built lazily
-    print(f"streaming plan: B={B} lchunk={max(1, B // 4)} "
+    print(f"streaming plan: B={B} lchunk={B} "
           f"d-free={ts.soft_plan.streaming}")
 
     # 2. batched executor traffic: 2V+1 lanes -> 3 chunks, one padded
@@ -112,7 +109,7 @@ def main(argv=None):
           f"{n} lanes")
 
     # 3. service traffic: packed correlation requests
-    svc = SO3Service(bandwidths=(B,), dtype=jnp.float64, lane_width=V)
+    svc = SO3Service(bandwidths=(B,), dtype=jnp.float32, lane_width=V)
     z = soft.random_s2_coeffs(B, seed=args.seed)
     futs = [svc.submit(z, z) for _ in range(args.requests)]
     svc.drain()

@@ -50,13 +50,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
-
-    jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     from repro.core import soft
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.so3 import SO3Service, ServiceError, angle_error, s2
     from repro.so3.correlate import random_rotation
 
+    enable_compile_cache()
     mesh = None
     if args.mesh_shards > 0:
         from repro.core.compat import make_mesh
@@ -71,7 +71,7 @@ def main(argv=None):
               f"(lane-packed sharded inverse)")
 
     lane_width = args.lane_width if args.lane_width > 0 else None
-    svc = SO3Service(bandwidths=args.bandwidth, dtype=jnp.float64,
+    svc = SO3Service(bandwidths=args.bandwidth, dtype=jnp.float32,
                      lane_width=lane_width, tk=args.tk,
                      max_wait_ms=args.max_wait_ms, mesh=mesh,
                      axis=("data",),

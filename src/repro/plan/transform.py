@@ -62,6 +62,7 @@ from repro import obs
 from repro.core import batched, clusters as clusters_mod, parallel
 from repro.core.batched import SoftPlan
 from repro.kernels import autotune, ops
+from repro.kernels.runtime import resolve_interpret
 
 __all__ = ["Transform", "Schedule", "plan", "clear_cache", "cache_stats",
            "dense_table_bytes_limit", "warm_bandwidths",
@@ -241,7 +242,8 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
                 raise ValueError(
                     f"no schedule fits the {limit}-byte VMEM budget for "
                     f"impl={impl} at B={soft_plan.B}, even streaming at "
-                    f"lchunk=1 (raise $REPRO_VMEM_BYTES or vmem_budget)")
+                    f"the smallest tiled chunk (raise $REPRO_VMEM_BYTES or "
+                    f"vmem_budget)")
         else:
             raise ValueError(
                 f"no lane width fits the {limit}-byte VMEM budget for "
@@ -819,6 +821,13 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
     if impl != "auto" and impl not in IMPLS:
         raise ValueError(f"impl must be 'auto' or one of {IMPLS}, "
                          f"got {impl!r}")
+    if (impl != "reference" and jnp.dtype(dtype).itemsize == 8
+            and not resolve_interpret(interpret)):
+        raise ValueError(
+            f"dtype={jnp.dtype(dtype).name} cannot run in compiled Pallas "
+            f"kernels: the TPU compiler has no 64-bit types.  Plan in "
+            f"float32 for the chip; the f64 host reference "
+            f"(repro.core.soft) is what results are checked against")
     if V != "auto" and (not isinstance(V, int) or V < 1):
         raise ValueError(f"V must be 'auto' or a positive int, got {V!r}")
     if precision not in (None, "auto", *autotune.PRECISIONS):
@@ -835,7 +844,8 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
                 "the sharded executor yet; plan without a mesh")
         if lchunk is not None:
             from repro.kernels import streaming as streaming_kernels
-            lchunk = streaming_kernels.check_lchunk(B, lchunk)
+            lchunk = streaming_kernels.check_lchunk(
+                B, lchunk, tiled=not resolve_interpret(interpret))
     if overlap is not None:
         parallel.check_overlap_mode(overlap)       # typos before mesh advice
         if overlap != "off" and mesh is None:
@@ -893,12 +903,16 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
             # per-device count instead of padding whole tk*n blocks, which
             # could idle a shard), and the shard-balanced order is dealt
             # over the PADDED count so every shard's block stays
-            # extent-sorted (maximal ragged truncation)
+            # extent-sorted (maximal ragged truncation).  Compiled kernels
+            # need whole 8-row cluster tiles per device (the TPU tiling of
+            # their (tk, J) blocks), so there each shard is padded to one.
+            pad_to = n_shards if resolve_interpret(interpret) \
+                else n_shards * _DEF_TK
             l_start = clusters_mod.build_cluster_table(B).rep[:, 0]
-            n_padded = -(-len(l_start) // n_shards) * n_shards
+            n_padded = -(-len(l_start) // pad_to) * pad_to
             order = batched.shard_balanced_order(l_start, n_shards,
                                                  n_padded=n_padded)
-            soft_plan = batched.build_plan(B, dtype=dtype, pad_to=n_shards,
+            soft_plan = batched.build_plan(B, dtype=dtype, pad_to=pad_to,
                                            order=order)
             parallel.check_mesh_compat(soft_plan, n_shards)
         else:

@@ -17,12 +17,9 @@ fields).  Asserts internally; prints one JSON dict on the last line.
 """
 import argparse
 import json
-import os
 import resource
 import sys
 import time
-
-os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 
 def peak_rss_bytes() -> int:
@@ -63,7 +60,7 @@ def main() -> None:
     baseline = peak_rss_bytes()         # interpreter + jax import cost
     t0 = time.perf_counter()
     t = planner(B, jnp.float32, impl="fused", V=1, lchunk=lchunk,
-                streaming=True, interpret=True)
+                streaming=True)
     # the window stack is built lazily with the kernels; charge it to the
     # build like the executors will
     t.dwt_fn, t.idwt_fn

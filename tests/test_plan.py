@@ -62,6 +62,39 @@ def test_plan_rejects_bad_config():
         plan_mod.plan(8, tune="sometimes")
 
 
+def test_plan_refuses_64bit_compiled_kernels(monkeypatch):
+    """Mosaic has no 64-bit types: a compiled-kernel plan in f64 fails in
+    plan() with a clear error, before anything is built or compiled."""
+    def no_build(*a, **k):
+        raise AssertionError("plan() built a SoftPlan")
+
+    monkeypatch.setattr(batched, "build_plan", no_build)
+    with pytest.raises(ValueError, match="no 64-bit types"):
+        plan_mod.plan(8, jnp.float64, interpret=False)
+    monkeypatch.undo()
+    # the pure-jnp reference and interpret-mode kernels keep f64
+    assert plan_mod.plan(8, jnp.float64, impl="reference",
+                         interpret=False).impl == "reference"
+    assert plan_mod.plan(8, jnp.float64, interpret=True).impl == "fused"
+
+
+def test_compiled_plans_follow_the_tpu_tiling():
+    """Compiled kernels get l-chunks that are multiples of 8 (or B), and
+    mesh plans whole 8-row cluster tiles per device; interpret mode keeps
+    small chunks and minimal padding."""
+    with pytest.raises(ValueError, match="multiple of 8"):
+        plan_mod.plan(16, jnp.float32, lchunk=4, interpret=False)
+    assert plan_mod.plan(16, jnp.float32, lchunk=8,
+                         interpret=False).schedule.lchunk == 8
+    assert plan_mod.plan(16, jnp.float32, lchunk=4,
+                         interpret=True).schedule.lchunk == 4
+    from repro.core.compat import make_mesh
+    mesh = make_mesh((1,), ("data",))
+    t = plan_mod.plan(8, jnp.float32, mesh=mesh, axis=("data",),
+                      interpret=False)
+    assert t.soft_plan.n_padded % 8 == 0 and t.schedule.tk == 8
+
+
 def test_plan_cache_keys_streaming_segments():
     """/L{lchunk}/P{precision} are part of the autotune cache key, and
     plans differing only in those knobs are distinct Transforms (the

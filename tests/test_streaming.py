@@ -382,12 +382,13 @@ def test_wigner_window_iter_matches_table():
 
 
 def test_static_schedule_auto_engages_streaming_under_tight_budget():
-    # monolithic V=1 at B=16/f32 needs ~27.8 KB VMEM: a 25 KB budget
-    # forces the planner onto the chunked schedule instead of failing.
+    # monolithic V=1 at B=16/f32 needs ~27.8 KB VMEM and the smallest
+    # tiled chunk (lchunk=8) ~25.8 KB: a 26 KB budget forces the planner
+    # onto the chunked schedule instead of failing.
     t = plan_mod.plan(16, dtype=jnp.float32, impl="fused",
-                      vmem_budget=25_000)
-    assert t.schedule.lchunk is not None
-    assert t.schedule.vmem_bytes <= 25_000
+                      vmem_budget=26_000)
+    assert t.schedule.lchunk == 8
+    assert t.schedule.vmem_bytes <= 26_000
     fhat = soft.random_coeffs(16, seed=7).astype(np.complex64)
     ref = plan_mod.plan(16, dtype=jnp.float32, impl="fused", V=t.V,
                         tk=t.schedule.tk)
