@@ -1,0 +1,8 @@
+"""Median client latency of the requests due in the window, from when
+each was due to when its answer arrived."""
+import numpy as np
+
+
+def read(run):
+    lat = run.latencies_s
+    return float(np.percentile(lat, 50) * 1e3) if lat else None
