@@ -702,11 +702,8 @@ class DistExecutor:
             # host-side dispatch span per chunk (the all-to-all + local
             # kernel run inside the jitted shard_map; their device-side
             # split is labeled by named_scopes -- see _forward_stages).
-            # obs.device_annotation additionally aligns this span with a
-            # jax.profiler device capture when $REPRO_OBS_JAX_TRACE is on.
             with obs.span("executor.chunk", mode="off", direction=direction,
-                          chunk=n0 // V, lanes=n, n_shards=self.n_shards), \
-                    obs.device_annotation(f"executor.chunk.{direction}"):
+                          chunk=n0 // V, lanes=n, n_shards=self.n_shards):
                 out = lanes_fn(chunk)
             if stats is not None:
                 stats["launches"] += 1
@@ -737,8 +734,7 @@ class DistExecutor:
         with obs.span("executor.pipeline", direction=direction,
                       n_chunks=n_chunks, lanes=n, padded=pad,
                       n_shards=self.n_shards,
-                      slots=[list(s) for s in pipeline_slots(n_chunks)]), \
-                obs.device_annotation(f"executor.pipeline.{direction}"):
+                      slots=[list(s) for s in pipeline_slots(n_chunks)]):
             if fwd:
                 out = self._forward_pipe_call()(
                     p.reflected, p.sign, p.gather_m, p.gather_mp, p.w,

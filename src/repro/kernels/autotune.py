@@ -312,13 +312,6 @@ def candidate_tiles(K: int, L: int, J: int, impl: str) -> list[dict]:
             for tk in tks for tl in tls for tj in tjs]
 
 
-def _time_fn(fn, *args, reps: int = 3) -> float:
-    """Deprecated private alias of :func:`repro.obs.time_fn` (kept for
-    pre-obs callers); new code should call obs.time_fn directly so the
-    measurement lands in the shared Recorder with a useful name."""
-    return obs.time_fn(fn, *args, reps=reps, name="autotune.time_fn")
-
-
 def _key(plan, impl: str, V, limit: int, n_shards: int = 1,
          overlap: str = "off", lchunk: int | None = None,
          precision: str = "fp32") -> str:

@@ -4,7 +4,7 @@ P3DFFT ships performance measurement as a first-class framework feature
 around its tuned transform, and OpenFFT's tuning story rests on a
 per-stage timing decomposition -- this module is that layer for the
 repo: ONE process-wide :class:`Recorder` that every hot layer reports
-into, instead of the pre-obs siloes (``autotune._time_fn``'s private
+into, instead of the pre-obs siloes (the autotuner's private
 timer, ``SO3Service``'s unbounded latency list, ``Transform.stats``'s
 time-less counters).
 
@@ -15,7 +15,7 @@ Three primitives, all bounded-memory and thread-safe:
     into a ring buffer AND feeds the duration into the histogram of the
     same name.  :meth:`Recorder.add_span` records a span from explicit
     ``perf_counter`` timestamps (e.g. a request's submit->done interval
-    measured across threads).
+    measured across threads); it lands in the Recorder only.
   * **counters** -- ``obs.inc("plan.cache.hit")``; monotonic ints.
   * **histograms** -- ``obs.observe("service.latency_s", dt)``; a
     bounded sample ring plus running count/total/max, with p50/p95/p99
@@ -33,21 +33,21 @@ Export paths:
     git SHA, so obs summaries ride the same BENCH_*.json perf-history
     schema as every benchmark section.
 
-Device-timeline alignment: the executor paths label their traced
-stages with ``jax.named_scope`` (zero runtime cost, shows up in XLA
-profiles), and :func:`device_annotation` optionally wraps host-side
-dispatch in ``jax.profiler.TraceAnnotation`` when
-``$REPRO_OBS_JAX_TRACE`` is set -- run under ``jax.profiler.trace``
-and the host spans line up with the device timeline.
+Device-timeline alignment: every :meth:`Recorder.span` is also a
+``jax.profiler.TraceAnnotation`` of the same name around the same body,
+so under a ``jax.profiler`` capture each span sits on the trace's host
+plane, on the device timeline's clock, in the thread that ran it.  With
+no capture running the annotation is one small native object per span.
 
-The module is dependency-free (stdlib only; :func:`time_fn` imports
-jax lazily for ``block_until_ready``), so importing it can never drag
-kernel code into a tool that only wants metrics.
+The module imports only the stdlib at import time (jax is imported
+lazily, on the first span and in :func:`time_fn`), so importing it
+can never drag kernel code into a tool that only wants metrics.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import json
 import os
 import pathlib
@@ -55,11 +55,18 @@ import threading
 import time
 
 __all__ = ["Recorder", "span", "add_span", "inc", "observe", "counter",
-           "time_fn", "get_recorder", "set_recorder", "device_annotation",
-           "check_chrome_trace"]
+           "time_fn", "get_recorder", "set_recorder", "check_chrome_trace"]
 
-# env flag: wrap instrumented dispatch sites in jax.profiler.TraceAnnotation
-_TRACE_ENV = "REPRO_OBS_JAX_TRACE"
+
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported once; a no-op context
+    where jax (or its profiler) is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:     # pragma: no cover - jax without profiler
+        return contextlib.nullcontext
+    return TraceAnnotation
 
 
 class Recorder:
@@ -88,12 +95,16 @@ class Recorder:
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         """Record one wall-clock span (Chrome-trace complete event) and
-        feed its duration into the histogram of the same name."""
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, time.perf_counter(), **attrs)
+        feed its duration into the histogram of the same name.  The body
+        also runs inside a profiler annotation of the same name, so a
+        ``jax.profiler`` capture shows the span on the device trace's
+        clock."""
+        with _annotation()(name):
+            t0 = time.perf_counter()
+            try:
+                yield self
+            finally:
+                self.add_span(name, t0, time.perf_counter(), **attrs)
 
     def add_span(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Record a span from explicit ``time.perf_counter`` timestamps
@@ -290,28 +301,13 @@ def counter(name: str) -> int:
     return get_recorder().counter(name)
 
 
-def device_annotation(name: str):
-    """Optional ``jax.profiler.TraceAnnotation`` wrapper for dispatch
-    sites: a no-op unless ``$REPRO_OBS_JAX_TRACE`` is set, in which case
-    host spans recorded here line up with the device timeline of a
-    surrounding ``jax.profiler.trace`` capture."""
-    if os.environ.get(_TRACE_ENV, "") not in ("", "0", "false"):
-        try:
-            from jax.profiler import TraceAnnotation
-            return TraceAnnotation(name)
-        except ImportError:     # pragma: no cover - jax without profiler
-            pass
-    return contextlib.nullcontext()
-
-
 def time_fn(fn, *args, reps: int = 3, name: str | None = None,
             recorder: Recorder | None = None, sync=None, **attrs) -> float:
     """Measure ``fn(*args)``: one untimed warmup call (compile + cache
     fill), then ``reps`` timed calls synced once at the end; returns
     mean seconds per call.
 
-    The public promotion of ``kernels.autotune._time_fn``: besides
-    returning the mean it records the measurement into ``recorder``
+    Besides returning the mean it records the measurement into ``recorder``
     (default: the process Recorder) as a span named ``name`` (default
     ``fn.__name__``) carrying ``reps``/``per_call_s`` plus any extra
     ``attrs`` -- so a ``tune="measure"`` sweep leaves an auditable

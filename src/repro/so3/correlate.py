@@ -37,8 +37,10 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import quadrature, soft
 
 from . import s2
@@ -243,13 +245,28 @@ class CorrelationEngine:
         cluster-sharded, one all-to-all for all V lanes).  Launch
         accounting lands in THIS engine's ``stats`` (the plan is shared;
         its counters are not ours).
+
+        Four spans split the call: ``correlate.pair`` (the pair
+        coefficients, dispatched eagerly), ``correlate.dispatch`` (the
+        enqueue of the inverse), ``correlate.wait`` (the host blocked on
+        the device) and ``correlate.readback`` (the device-to-host copy
+        and the conjugate); ``correlate.readback_bytes`` observes the
+        bytes copied.
         """
         B = self.B
         if not len(fs):
             return np.zeros((0, 2 * B, 2 * B, 2 * B), complex)
-        T = jnp.stack([self._pair_coeffs(f, g) for f, g in zip(fs, gs)])
-        Cb = self.transform.inverse_batch(T, stats=self.stats)
-        return np.conj(np.asarray(Cb))
+        tags = dict(B=B, lanes=len(fs))
+        with obs.span("correlate.pair", **tags):
+            T = jnp.stack([self._pair_coeffs(f, g) for f, g in zip(fs, gs)])
+        with obs.span("correlate.dispatch", **tags):
+            Cb = self.transform.inverse_batch(T, stats=self.stats)
+        with obs.span("correlate.wait", **tags):
+            Cb = jax.block_until_ready(Cb)
+        with obs.span("correlate.readback", **tags):
+            C = np.conj(np.asarray(Cb))
+        obs.observe("correlate.readback_bytes", Cb.nbytes)
+        return C
 
     # -- matching entry points ----------------------------------------------
 

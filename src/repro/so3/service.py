@@ -50,11 +50,13 @@ request never pays compilation.  ``stats()`` reports per-request latency
 quantiles, launch counts, lane occupancy, and the full typed-outcome
 ledger (completed / rejected / expired / cancelled / failed / retries).
 
-Observability: the service records into a :class:`repro.obs.Recorder`
-(the shared process recorder by default, or ``recorder=``): one
-``service.request`` span per request (submit -> result, with the queue
-wait as an attribute) plus ``service.pack`` / ``service.launch`` /
-``service.refine`` stage spans per launch group; bounded
+Observability: the service records into the process
+:class:`repro.obs.Recorder`, the one its engines' ``correlate.*`` and
+``executor.*`` spans land in: one ``service.request`` span per request
+(submit -> answer resolved, carrying the request's ``seq``, its
+``launch`` and the queue wait) plus ``service.pack`` /
+``service.launch`` / ``service.refine`` stage spans per launch group,
+each carrying the group's ``launch`` sequence number; bounded
 ``service.latency_s`` / ``service.queue_wait_s`` / ``service.backoff_s``
 / ``service.shed_wait_s`` histograms; and ``service.completed`` /
 ``service.rejected`` / ``service.expired`` / ``service.cancelled`` /
@@ -145,7 +147,7 @@ class SO3Service:
                  lane_width: int | None = 4, impl: str = "fused",
                  tk: int | None = 8, interpret=None,
                  max_wait_ms: float = 2.0, mesh=None,
-                 axis=("data", "model"), recorder=None,
+                 axis=("data", "model"),
                  max_queue: int | None = None,
                  deadline_s: float | None = None,
                  max_retries: int = 1, retry_backoff_s: float = 0.05):
@@ -165,10 +167,7 @@ class SO3Service:
         max_retries / retry_backoff_s: how many times a failed launch
         group's requests are requeued, with exponential not-before
         backoff ``retry_backoff_s * 2**attempt``, before the launch
-        error surfaces on the Future.
-
-        recorder: the :class:`repro.obs.Recorder` spans and latency
-        histograms land in (default: the shared process recorder)."""
+        error surfaces on the Future."""
         self.bandwidths = tuple(bandwidths)
         self.lane_width = lane_width
         self.max_wait_ms = max_wait_ms
@@ -176,7 +175,6 @@ class SO3Service:
         self.deadline_s = deadline_s
         self.max_retries = int(max_retries)
         self.retry_backoff_s = float(retry_backoff_s)
-        self.obs = obs.get_recorder() if recorder is None else recorder
         self._engine_kw = dict(dtype=dtype, impl=impl, tk=tk,
                                interpret=interpret, lane_width=lane_width,
                                mesh=mesh, axis=axis)
@@ -192,6 +190,7 @@ class SO3Service:
         self._running = False
         self._accepting = True
         self._seq = 0
+        self._launch_seq = 0            # launch groups started, retries too
         self._inflight = 0
         self._counts = {k: 0 for k in _OUTCOMES}
         self._counts["retries"] = 0
@@ -261,7 +260,7 @@ class SO3Service:
                 return False
             p.done = True
             self._counts[kind] += 1
-        self.obs.inc(f"service.{kind}")
+        obs.inc(f"service.{kind}")
         if exc is not None:
             p.future.set_exception(exc)
         else:
@@ -328,7 +327,7 @@ class SO3Service:
     def _resolve_expired(self, shed: list[tuple[int, _Pending]]) -> None:
         now = time.perf_counter()
         for B, p in shed:
-            self.obs.observe("service.shed_wait_s", now - p.t_submit)
+            obs.observe("service.shed_wait_s", now - p.t_submit)
             self._finish(p, "expired", exc=Expired(
                 f"deadline exceeded after {now - p.t_submit:.3f}s queued",
                 seq=p.seq, B=B))
@@ -357,35 +356,37 @@ class SO3Service:
         """Run one packed launch group (<= lane_width requests, one B).
         On failure the group's requests retry with backoff (up to
         max_retries) before the error surfaces on their Futures."""
+        with self._lock:
+            self._launch_seq += 1
+            launch = self._launch_seq
+        tags = dict(B=B, requests=len(group), launch=launch)
         try:
             eng = self.engine(B)
             t_start = time.perf_counter()   # group leaves the queue here
             try:
                 with self._serve_lock:
-                    with self.obs.span("service.pack", B=B,
-                                       requests=len(group)):
+                    with obs.span("service.pack", **tags):
                         fs = [eng.as_coeffs(p.f) for p in group]
                         gs = [eng.as_coeffs(p.g) for p in group]
-                    with self.obs.span("service.launch", B=B,
-                                       requests=len(group)):
+                    with obs.span("service.launch", **tags):
                         C = eng.correlation_grids(fs, gs)  # ONE launch/lane
-                done = time.perf_counter()
-                with self.obs.span("service.refine", B=B,
-                                   requests=len(group)):
+                with obs.span("service.refine", **tags):
                     results = [peak_euler(C[n], B, refine=p.refine,
                                           norm=pair_norm(fs[n], gs[n]))
                                for n, p in enumerate(group)]
             except Exception as e:
                 self._retry_or_fail(B, group, t_start, e)
                 return
+            done = time.perf_counter()      # the answers resolve now
             for p in group:
-                # span covers submit -> grids ready; queue wait = time spent
+                # span covers submit -> answer; queue wait = time spent
                 # queued before this group's processing started
                 wait = max(t_start - p.t_submit, 0.0)
-                self.obs.add_span("service.request", p.t_submit, done, B=B,
-                                  queue_wait_s=wait, attempts=p.attempts)
-                self.obs.observe("service.queue_wait_s", wait)
-                self.obs.observe("service.latency_s", done - p.t_submit)
+                obs.add_span("service.request", p.t_submit, done, B=B,
+                             seq=p.seq, launch=launch, queue_wait_s=wait,
+                             attempts=p.attempts)
+                obs.observe("service.queue_wait_s", wait)
+                obs.observe("service.latency_s", done - p.t_submit)
             for p, r in zip(group, results):
                 self._finish(p, "completed", result=r)
         finally:
@@ -416,12 +417,12 @@ class SO3Service:
                 self._counts["retries"] += len(retry)
                 self._cv.notify()
             for p, backoff in retry:
-                self.obs.inc("service.retry")
-                self.obs.observe("service.backoff_s", backoff)
+                obs.inc("service.retry")
+                obs.observe("service.backoff_s", backoff)
         for p in fail:
             self._finish(p, "failed", exc=exc)
         for p in expire:
-            self.obs.observe("service.shed_wait_s", now - p.t_submit)
+            obs.observe("service.shed_wait_s", now - p.t_submit)
             self._finish(p, "expired", exc=Expired(
                 f"retry backoff would outlive the deadline "
                 f"(launch failed: {exc})", seq=p.seq, B=B))
@@ -640,7 +641,7 @@ class SO3Service:
         # gate on OUR completions: the shared recorder may hold samples
         # from other services/tests, a fresh service must not report them
         if counts["completed"]:
-            q = self.obs.quantiles("service.latency_s")
+            q = obs.get_recorder().quantiles("service.latency_s")
             if q:
                 out["latency_s"] = {k: q[k]
                                     for k in ("mean", "p50", "p95", "p99",

@@ -103,7 +103,8 @@ def test_observability_section_covers_obs_api():
         "repro.obs", "repro.obs.Recorder", "repro.obs.span",
         "repro.obs.time_fn", "repro.obs.get_recorder",
         "repro.obs.set_recorder", "repro.obs.check_chrome_trace",
-        "repro.obs.device_annotation",
+        "repro.so3.CorrelationEngine.correlation_grids",
+        "repro.obs.Recorder.add_span",
         "repro.obs.Recorder.dump_chrome_trace", "repro.obs.Recorder.rows",
         "repro.obs.Recorder.quantiles", "repro.launch.profile_so3",
     }

@@ -1,9 +1,11 @@
 """repro.obs units: the bounded Recorder (spans / counters / histogram
-quantiles under eviction), Chrome-trace export + the structural
-validator, obs.time_fn's measurement contract, the planner/service
-instrumentation hooks, and tracing's bitwise invisibility to transform
-outputs."""
+quantiles under eviction), spans on the profiler's host plane,
+Chrome-trace export + the structural validator, obs.time_fn's
+measurement contract, the planner/engine/service instrumentation hooks,
+and tracing's bitwise invisibility to transform outputs."""
+import glob
 import json
+import time
 
 import numpy as np
 import pytest
@@ -35,6 +37,27 @@ def test_recorder_spans_counters_quantiles():
     assert rec.quantiles("a.x")["count"] == 1
     rec.clear()
     assert rec.events() == [] and rec.counters() == {}
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """Under a jax.profiler capture a Recorder span is also a host event
+    of the same name, on the profiler's clock, as long as the Recorder's
+    own reading (within 10%, or 50 us)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    rec = Recorder()
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.span("obs.test.sleep", k=1):
+            time.sleep(0.02)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    got = [e.duration_ns for plane in ProfileData.from_file(path).planes
+           if plane.name == "/host:CPU" for line in plane.lines
+           for e in line.events if e.name == "obs.test.sleep"]
+    assert len(got) == 1
+    dur_ns = rec.events()[0]["dur"] * 1e3
+    assert abs(got[0] - dur_ns) <= max(0.1 * dur_ns, 50e3)
 
 
 def test_recorder_memory_is_bounded():
@@ -114,7 +137,7 @@ def test_check_chrome_trace_catches_structural_damage():
 
 
 # ---------------------------------------------------------------------------
-# time_fn (the public promotion of autotune._time_fn)
+# time_fn
 # ---------------------------------------------------------------------------
 
 def test_time_fn_measures_and_records():
@@ -134,17 +157,6 @@ def test_time_fn_measures_and_records():
     assert ev["args"]["reps"] == 5 and ev["args"]["key"] == "k"
     assert ev["args"]["per_call_s"] == pytest.approx(per)
     assert rec.quantiles("bench.fn")["count"] == 1
-
-
-def test_autotune_time_fn_alias_still_works():
-    from repro.kernels import autotune
-    old = obs.set_recorder(Recorder())
-    try:
-        per = autotune._time_fn(lambda: 1, reps=2)
-    finally:
-        rec = obs.set_recorder(old)
-    assert per >= 0.0
-    assert rec.quantiles("autotune.time_fn")["count"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -212,18 +224,21 @@ def test_service_stats_bounded_and_quantiled():
     from repro.core import soft
     from repro.so3.service import SO3Service
     rec = Recorder(max_samples=64)
-    svc = SO3Service(bandwidths=(8,), dtype=jnp.float64, lane_width=2,
-                     recorder=rec)
-    # fresh service: no latency block even if the recorder has samples
-    rec.observe("service.latency_s", 123.0)
-    assert "latency_s" not in svc.stats()
-    rec.clear()
-    z = soft.random_s2_coeffs(8, seed=0)
-    futs = [svc.submit(z, z, refine=False) for _ in range(3)]
-    svc.drain()
-    for f in futs:
-        assert f.result(timeout=120).index is not None
-    st = svc.stats()
+    old = obs.set_recorder(rec)
+    try:
+        svc = SO3Service(bandwidths=(8,), dtype=jnp.float64, lane_width=2)
+        # fresh service: no latency block even if the recorder has samples
+        rec.observe("service.latency_s", 123.0)
+        assert "latency_s" not in svc.stats()
+        rec.clear()
+        z = soft.random_s2_coeffs(8, seed=0)
+        futs = [svc.submit(z, z, refine=False) for _ in range(3)]
+        svc.drain()
+        for f in futs:
+            assert f.result(timeout=120).index is not None
+        st = svc.stats()
+    finally:
+        obs.set_recorder(old)
     assert st["completed"] == 3
     lat = st["latency_s"]
     assert set(lat) == {"mean", "p50", "p95", "p99", "max"}
@@ -237,3 +252,82 @@ def test_service_stats_bounded_and_quantiled():
     assert all(e["args"]["queue_wait_s"] >= 0 for e in reqs)
     # storage is the bounded ring, not a per-request list
     assert rec.quantiles("service.latency_s")["count"] == 3
+
+
+CORRELATE_SPANS = ("correlate.pair", "correlate.dispatch", "correlate.wait",
+                   "correlate.readback")
+
+
+def _engine_events(served: bool):
+    """Three (f, g) pairs at B = 8 on two lanes -- two launches, of 2 and
+    1 lanes -- through the engine alone or through the service."""
+    import jax.numpy as jnp
+    from repro.core import soft
+    from repro.so3 import CorrelationEngine
+    from repro.so3.service import SO3Service
+
+    pairs = [(soft.random_s2_coeffs(8, seed=s), soft.random_s2_coeffs(
+        8, seed=s + 10)) for s in range(3)]
+    rec = Recorder()
+    old = obs.set_recorder(rec)
+    try:
+        if served:
+            svc = SO3Service(bandwidths=(8,), dtype=jnp.float64,
+                             lane_width=2)
+            futs = [svc.submit(f, g) for f, g in pairs]
+            svc.drain()
+            for fu in futs:
+                fu.result(timeout=120)
+        else:
+            eng = CorrelationEngine(8, dtype=jnp.float64, lane_width=2)
+            for n0 in (0, 2):
+                eng.match_batch(*zip(*pairs[n0:n0 + 2]))
+    finally:
+        obs.set_recorder(old)
+    return rec
+
+
+@pytest.mark.parametrize("served", [False, True])
+def test_engine_spans_split_each_launch(served):
+    rec = _engine_events(served)
+    evs = rec.events()
+    for name in CORRELATE_SPANS:
+        spans = [e for e in evs if e["name"] == name]
+        assert [e["args"]["lanes"] for e in spans] == [2, 1], name
+    # the copy is the real lanes' grids, complex128 at f64: 16 bytes each
+    q = rec.quantiles("correlate.readback_bytes")
+    assert q["count"] == 2 and q["total"] == 3 * 16 ** 3 * 16
+    if not served:
+        return
+    launches = [e for e in evs if e["name"] == "service.launch"]
+    assert len(launches) == 2
+    for e in evs:
+        if e["name"] in CORRELATE_SPANS + ("executor.chunk",):
+            assert sum(L["tid"] == e["tid"] and L["ts"] <= e["ts"] and
+                       e["ts"] + e["dur"] <= L["ts"] + L["dur"]
+                       for L in launches) == 1, e
+
+
+def test_request_spans_carry_identity_and_end_at_the_answer():
+    rec = _engine_events(served=True)
+    evs = rec.events()
+    by_launch = {}
+    for e in evs:
+        if e["name"].startswith("service.") and e["name"] != \
+                "service.request":
+            by_launch.setdefault(e["args"]["launch"], {})[e["name"]] = e
+    assert len(by_launch) == 2
+    assert all(set(v) == {"service.pack", "service.launch",
+                          "service.refine"} for v in by_launch.values())
+    reqs = sorted((e for e in evs if e["name"] == "service.request"),
+                  key=lambda e: e["args"]["seq"])
+    assert [e["args"]["seq"] for e in reqs] == [1, 2, 3]
+    first, second = sorted(by_launch)
+    assert [e["args"]["launch"] for e in reqs] == [first, first, second]
+    for e in reqs:
+        refine = by_launch[e["args"]["launch"]]["service.refine"]
+        assert e["ts"] + e["dur"] >= refine["ts"] + refine["dur"]
+    # the latency histogram reads the same interval as the request span
+    lat = rec.quantiles("service.latency_s")
+    assert lat["count"] == 3
+    assert lat["total"] * 1e6 == pytest.approx(sum(e["dur"] for e in reqs))

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import batched, quadrature, soft, wigner
 from repro.kernels import dwt_fused as dwt_fused_mod
 from repro.so3 import (Cancelled, CorrelationEngine, Expired, Rejected,
@@ -408,7 +409,7 @@ def test_service_mixed_bandwidth_fuzz_bitwise_parity(seed):
     def check_counters_monotone():
         for name in ("service.completed", "service.rejected",
                      "service.expired", "service.cancelled"):
-            v = svc.obs.counter(name)
+            v = obs.counter(name)
             assert v >= mono.get(name, 0), name
             mono[name] = v
 
