@@ -46,14 +46,17 @@ between (``impl=...`` forces one):
             impl="auto" resolves to (statically) for every B.  batch=V
             packs V transforms onto the lane axis (C2 = V*C*2): one
             launch, each generated d-row reused V times
-            (Transform.forward_batch / inverse_batch).  With
+            (Transform.forward_batch / inverse_batch).  Rows go to a
+            (TK, P, J) VMEM panel, contracted by one MXU product per
+            cluster (P = B where VMEM allows: autotune.panel_depth).  With
             ``lchunk``/``precision="bf16"`` the planner swaps in the
             STREAMING members (streaming.py): only a (TK, lchunk, C2)
             coefficient tile is VMEM-live (the stack stays HBM-resident,
             staged through double-buffered slots), the recurrence resumes
             from per-chunk two-row windows, and bf16 halves the stored
-            window table + feeds bf16 contraction rows while state and
-            accumulation stay in the plan dtype.  Keyed by /L{lchunk}/
+            window table + rounds the rows to bf16 while state, panel
+            and accumulation stay in the plan dtype; the chunk is the
+            panel.  Keyed by /L{lchunk}/
             P{precision}; auto-engaged when no monolithic V fits VMEM.
   reference Planner-only pseudo-schedule: the pure-jnp einsum path
             (differentiable, runs anywhere) -- the correctness oracle.

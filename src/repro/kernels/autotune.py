@@ -44,8 +44,9 @@ __all__ = ["autotune_dwt", "autotune_overlap", "static_overlap",
            "tuned_idwt_fn", "cache_path", "candidate_tiles",
            "estimate_vmem_bytes", "estimate_hbm_bytes",
            "estimate_live_coeff_bytes", "estimate_host_plan_bytes",
-           "vmem_limit_bytes", "PRECISIONS", "PRECISION_ERROR_BOUNDS",
-           "PRECISION_BOUND_EXTRAPOLATED", "FP32_ROUNDTRIP_BOUNDS"]
+           "panel_depth", "vmem_limit_bytes", "PRECISIONS",
+           "PRECISION_ERROR_BOUNDS", "PRECISION_BOUND_EXTRAPOLATED",
+           "FP32_ROUNDTRIP_BOUNDS"]
 
 _DEF_CACHE = "~/.cache/repro/autotune.json"
 
@@ -121,34 +122,63 @@ def vmem_limit_bytes() -> int:
 def estimate_vmem_bytes(impl: str, *, L: int, J: int, C2: int, tk: int,
                         tl: int | None = None, tj: int | None = None,
                         itemsize: int = 4, lchunk: int | None = None,
-                        precision: str = "fp32") -> int:
+                        precision: str = "fp32",
+                        panel: int | None = None,
+                        limit: int | None = None) -> int:
     """Static VMEM footprint of one grid step of a candidate tiling.
 
     Recurrence schedules (onthefly/fused) hold seeds + the two recurrence
     state rows (3 * TK * J), the order/cos-beta vectors, the rhs tile
     (TK * J * C2) and the coefficient tile; C2 = V*C*2 grows linearly
-    with lane packing, which is what caps V.  Grid schedules
+    with lane packing, which is what caps V.  The fused family adds its
+    (TK, P, J) Wigner panel: ``panel`` rows if given, else the depth
+    :func:`panel_depth` derives under ``limit``.  Grid schedules
     (dense/ragged) hold a (TK, TL, TJ) d-block plus rhs/out tiles.
 
     itemsize must be the PLAN dtype's (f64 plans really do hold 8-byte
     tiles; assuming fp32 under-guards them 2x).  An l-chunked streaming
     schedule (lchunk != None) shrinks the coefficient tile from
     TK * L * C2 to TK * lchunk * C2 -- the memory cliff this family
-    exists to cut -- and adds the staged 2 * TK * J window block, which
-    (like the bf16 contraction-row operand) is stored at 2 bytes under
-    precision="bf16".
+    exists to cut -- and adds the staged 2 * TK * J window block, stored
+    at 2 bytes under precision="bf16".
     """
     if impl in ("onthefly", "fused"):
-        sb = 2 if precision == "bf16" else itemsize
         lt = L if lchunk is None else lchunk
-        extra = sb * 2 * tk * J if lchunk is not None else 0   # window block
-        if precision == "bf16":
-            extra += 2 * tk * J   # distinct bf16 contraction-row buffer
+        extra = 0
+        if lchunk is not None:                          # window block
+            extra += (2 if precision == "bf16" else itemsize) * 2 * tk * J
+        if impl == "fused":
+            if panel is None:
+                panel = panel_depth(L=L, J=J, C2=C2, tk=tk,
+                                    itemsize=itemsize, lchunk=lchunk,
+                                    limit=limit)
+            extra += itemsize * tk * panel * J
         return (itemsize * (3 * tk * J + 2 * tk + J + tk * J * C2
                             + tk * lt * C2) + extra)
     tl = L if tl is None else tl
     tj = J if tj is None else tj
     return itemsize * (tk * tl * tj + tk * tj * C2 + tk * tl * C2)
+
+
+def panel_depth(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
+                lchunk: int | None = None, limit: int | None = None) -> int:
+    """Rows P of the (TK, P, J) Wigner panel the fused kernels contract
+    per MXU product.  A streaming schedule's panel is its chunk (P =
+    lchunk).  The monolithic kernel takes the whole degree range (P = L)
+    when :func:`estimate_vmem_bytes` fits ``limit`` (default
+    :func:`vmem_limit_bytes`), else the largest multiple of 8 dividing L
+    that fits; when none fits, the smallest, and the schedule's VMEM
+    guard judges the tile."""
+    if lchunk is not None:
+        return lchunk
+    limit = vmem_limit_bytes() if limit is None else limit
+    cands = [d for d in range(L, 0, -1) if L % d == 0
+             and (d % 8 == 0 or d == L)]
+    for p in cands:
+        if estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
+                               itemsize=itemsize, panel=p) <= limit:
+            return p
+    return cands[-1]
 
 
 def estimate_live_coeff_bytes(*, tk: int, L: int, C2: int, itemsize: int = 4,
@@ -247,7 +277,7 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
     def est(lc):
         return estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
                                    itemsize=itemsize, lchunk=lc,
-                                   precision=precision)
+                                   precision=precision, limit=limit)
 
     if monolithic_ok and est(None) <= limit:
         return None
@@ -422,7 +452,7 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
             for tile in candidate_tiles(K_eff, L, J, impl):
                 if estimate_vmem_bytes(impl, L=L, J=J, C2=V * C * 2,
                                        itemsize=itemsize, lchunk=lchunk,
-                                       precision=precision,
+                                       precision=precision, limit=limit,
                                        **tile) > limit:
                     n_skipped += 1
                     continue
@@ -434,7 +464,8 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
                         fn = ops.make_dwt_fn(plan, impl, interpret=interpret,
                                              batch=None if V == 1 else V,
                                              lchunk=lchunk,
-                                             precision=precision, **tile)
+                                             precision=precision,
+                                             vmem_limit=limit, **tile)
                         run = lambda r: fn(plan, r)   # noqa: E731
                     # per-candidate timing lands in the Recorder: every
                     # sweep leaves an auditable record, not just a winner

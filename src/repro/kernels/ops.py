@@ -227,7 +227,8 @@ def _check_streaming_args(impl, lchunk, precision):
 
 
 def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
-                lchunk=None, precision=None, interpret=None, batch=None):
+                lchunk=None, precision=None, vmem_limit=None,
+                interpret=None, batch=None):
     """Build a dwt_fn(plan, rhs) for core.batched.forward_clustered.
 
     impl: "dense" | "ragged" | "onthefly" | "fused".  batch=V makes the fn
@@ -239,7 +240,9 @@ def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
     two-row window.  precision (fused only): "fp32" (default; compute in
     the plan dtype) or "bf16" (bf16 recurrence state / d-rows, plan-dtype
     accumulation; forces the streaming kernel, monolithic has no
-    mixed-precision twin).
+    mixed-precision twin).  vmem_limit (fused only; default
+    :func:`repro.kernels.autotune.vmem_limit_bytes`) is the budget the
+    monolithic kernel derives its Wigner panel depth against.
     """
     interpret = default_interpret() if interpret is None else interpret
     if _check_streaming_args(impl, lchunk, precision):
@@ -300,7 +303,9 @@ def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
 
         def raw(p: SoftPlan, rhs2):
             out = dwt_fused.dwt_fused(seeds_p, m_p, mp_p, cb, rhs2[perm],
-                                      l0s, B=p.B, tk=tk, interpret=interpret)
+                                      l0s, B=p.B, tk=tk,
+                                      vmem_limit=vmem_limit,
+                                      interpret=interpret)
             return out[inv_perm]
         return _wrap_batch(raw, batch)
 
@@ -308,12 +313,14 @@ def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
 
 
 def make_idwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
-                 lchunk=None, precision=None, interpret=None, batch=None):
+                 lchunk=None, precision=None, vmem_limit=None,
+                 interpret=None, batch=None):
     """Build an idwt_fn(plan, lhs) for core.batched.inverse_clustered.
 
     impl: "dense" | "onthefly" | "fused"; batch as in make_dwt_fn (lhs
     gains a leading V axis, packed onto lanes for one launch); lchunk /
-    precision select the streaming inverse (fused only, see make_dwt_fn).
+    precision select the streaming inverse and vmem_limit the panel
+    budget (fused only, see make_dwt_fn).
     """
     interpret = default_interpret() if interpret is None else interpret
     if _check_streaming_args(impl, lchunk, precision):
@@ -359,7 +366,9 @@ def make_idwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
 
         def raw(p: SoftPlan, lhs2):
             out = dwt_fused.idwt_fused(seeds_p, m_p, mp_p, cb, lhs2[perm],
-                                       l0s, B=p.B, tk=tk, interpret=interpret)
+                                       l0s, B=p.B, tk=tk,
+                                       vmem_limit=vmem_limit,
+                                       interpret=interpret)
             return out[inv_perm]
         return _wrap_batch(raw, batch)
 

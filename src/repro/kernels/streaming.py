@@ -15,21 +15,25 @@ C2) coefficient tile is ever VMEM-live:
     DistExecutor pipeline uses per V-chunk, here at the DMA level inside
     one kernel -- chunk i's tile contracts while chunk i+1's tile streams);
   * the on-the-fly recurrence carries only a TWO-ROW SEED WINDOW per
-    chunk: :func:`build_windows` marches the three-term recurrence once on
-    the host (same jnp ops as the kernel -- fp32/f64 chunking is therefore
-    BITWISE equal to the monolithic kernel) and emits the (d_{l-1}, d_l)
+    chunk: :func:`build_windows` marches the three-term recurrence once
+    (same jnp ops as the kernel, so every chunk generates the rows the
+    monolithic kernel generates, bit for bit) and emits the (d_{l-1}, d_l)
     state at each chunk boundary, a (nL, 2, K, J) table that is
     lchunk/2 x smaller than the full Wigner table the dense schedules
     stream;
+  * the chunk is the PANEL of the shared MXU contraction
+    (dwt_fused.fill_panel / contract_panel, P = lchunk): a grid step
+    generates its chunk's rows into a (TK, lchunk, J) VMEM panel, then
+    runs one matrix product per cluster;
   * the ragged zero-triangle skip survives chunking: each (tile, chunk)
     grid step runs l = max(l0s[g], lc*lchunk) .. (lc+1)*lchunk, so chunks
-    entirely below a tile's l-start cost one memset and no recurrence
-    steps;
+    entirely below a tile's l-start cost one memset (forward) or nothing
+    (inverse): no recurrence step and no product;
   * mixed precision (``precision="bf16"``): bfloat16 is a STORAGE format,
     not a compute format -- the HBM-resident window table is stored bf16
-    (halving the largest new paper-scale object) and the generated d-rows
-    are fed to the contraction as the MXU's native bf16 operand, while
-    the in-kernel recurrence state and the accumulation stay in the plan
+    (halving the largest new paper-scale object) and each generated d-row
+    is rounded to bf16 before it enters the panel, while the in-kernel
+    recurrence state, the panel and the accumulation stay in the plan
     dtype (>= fp32).  Rounding therefore happens nL + 1 times per value
     (once per chunk boundary + once per row), not once per recurrence
     step: carrying the state itself in bf16 compounds rounding through
@@ -40,22 +44,27 @@ C2) coefficient tile is ever VMEM-live:
 Grid layout: (K/TK, nL) with the chunk axis innermost.  The forward rhs
 block index is constant over lc (the tile stays VMEM-resident across a
 cluster-tile's chunks); the inverse output block revisits (K-indexed, lc
-ignored) and accumulates across the chunk axis -- initialization happens
-at lc == 0, and ascending-l accumulation order keeps fp32/f64 chunked
-results bitwise equal to the monolithic kernel.
+ignored) and accumulates one product per chunk -- initialization happens
+at lc == 0.  Every forward output element is one product over J, as in
+the monolithic kernel, so the forward and the lchunk = L inverse are
+bitwise equal to it in fp32/f64 (but for lchunk = 1 under XLA's CPU
+backend, which sums a one-row product in another order); an inverse at
+lchunk < L sums nL chunk products where the monolithic kernel sums one,
+so it agrees to the dtype's rounding, and bit for bit only with a
+monolithic kernel whose panel is lchunk rows deep.
 """
 from __future__ import annotations
 
 from functools import partial
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .dwt_fused import contract_panel, fill_panel, march
 from .runtime import I0, resolve_interpret
-from .wigner_rec import _contract_row, _recurrence_step
+from .wigner_rec import _recurrence_step
 
 __all__ = ["build_windows", "dwt_streaming", "idwt_streaming",
            "check_lchunk"]
@@ -77,24 +86,6 @@ def check_lchunk(L: int, lchunk: int, *, tiled: bool = False) -> int:
             f"lchunk={lchunk} is neither a multiple of 8 nor L={L}: the "
             f"TPU compiler refuses its (tk, lchunk, C2) blocks")
     return lchunk
-
-
-def _stream_step(l, m, mp, cb, prev_ref, cur_ref, seeds, row_dtype):
-    """One recurrence step against compute-dtype state refs.
-
-    The arithmetic is the kernel-shared :func:`~repro.kernels.wigner_rec.
-    _recurrence_step`; the state scratch stays in the compute dtype
-    (cb.dtype, the plan dtype) so bf16 schedules do not compound rounding
-    through the recurrence -- only the RETURNED row is cast to the
-    contraction operand dtype.  When row_dtype == compute dtype the cast
-    is a no-op, which is what makes fp32/f64 chunking bitwise-identical
-    to the monolithic kernel.
-    """
-    row, p, c = _recurrence_step(l, m, mp, cb, prev_ref[...], cur_ref[...],
-                                 seeds)
-    prev_ref[...] = p
-    cur_ref[...] = c
-    return row.astype(row_dtype)
 
 
 @partial(jax.jit, static_argnames=("L", "lchunk", "state_dtype"))
@@ -142,29 +133,92 @@ def build_windows(seeds, m, mp, cos_beta, *, L, lchunk, state_dtype=None):
     return wins[:nL]
 
 
-def _stream_fwd_kernel(L, lchunk, row_dtype, l0_ref, seeds_ref, m_ref,
-                       mp_ref, cb_ref, w_ref, r_ref, o_ref, prev_ref,
-                       cur_ref):
+def _stream_kernel(lchunk, row_dtype, inverse, l0_ref, seeds_ref, m_ref,
+                   mp_ref, cb_ref, w_ref, x_ref, o_ref, prev_ref, cur_ref,
+                   panel_ref):
     g = pl.program_id(0)
     lc = pl.program_id(1)
     base = lc * lchunk
-    l0 = jnp.maximum(l0_ref[g], base)
+    lo = jnp.maximum(l0_ref[g], base)
+    live = lo < base + lchunk       # some row of the chunk is at or past l0
     seeds = seeds_ref[...]
     m = m_ref[...]            # (TK, 1)
     mp = mp_ref[...]
     cb = cb_ref[...]          # (1, J)
     prev_ref[...] = w_ref[0, 0].astype(prev_ref.dtype)
     cur_ref[...] = w_ref[0, 1].astype(cur_ref.dtype)
-    # rows below l0 (and whole chunks below a tile's l-start) are zero.
-    o_ref[...] = jnp.zeros_like(o_ref)
 
-    def body(l, _):
-        row = _stream_step(l, m, mp, cb, prev_ref, cur_ref, seeds,
-                           row_dtype)
-        o_ref[:, pl.ds(l - base, 1), :] = _contract_row(
-            row, r_ref[...], o_ref.dtype)[:, None, :]
+    if inverse:
+        # the output block revisits across the (innermost) chunk axis:
+        # initialize once, then every chunk adds its product
+        @pl.when(lc == 0)
+        def _init():
+            o_ref[...] = jnp.zeros_like(o_ref)
+    else:
+        @pl.when(jnp.logical_not(live))
+        def _below():            # a chunk below the tile's l-start
+            o_ref[...] = jnp.zeros_like(o_ref)
 
-    jax.lax.fori_loop(l0, base + lchunk, body, None)
+    # the state stays in the plan dtype; only the row is rounded to the
+    # storage precision (a no-op for fp32/f64)
+    def row(l):
+        return march(l, m, mp, cb, seeds, prev_ref, cur_ref).astype(row_dtype)
+
+    @pl.when(live)
+    def _chunk():
+        fill_panel(row, lo, base, panel_ref)
+        contract_panel(panel_ref, x_ref, o_ref, 0, inverse=inverse)
+
+
+def _stream_call(seeds, m, mp, cos_beta, x, l0s, windows, *, B, tk, lchunk,
+                 precision, inverse, interpret):
+    interpret = resolve_interpret(interpret)
+    lchunk = check_lchunk(B, lchunk, tiled=not interpret)
+    K, J = seeds.shape
+    C2 = x.shape[-1]
+    tk = min(tk, K)
+    if K % tk:
+        raise ValueError(f"K={K} % tk={tk}")
+    nL = B // lchunk
+    if windows.shape != (nL, 2, K, J):
+        raise ValueError(f"windows {windows.shape} != {(nL, 2, K, J)}")
+    dt = seeds.dtype
+    sdt = jnp.bfloat16 if precision == "bf16" else dt
+    mf = m.astype(dt)[:, None]
+    mpf = mp.astype(dt)[:, None]
+    cb = cos_beta.astype(dt)[None, :]
+    if inverse:     # coefficients staged chunk by chunk; output revisited
+        x_spec = pl.BlockSpec((tk, lchunk, C2),
+                              lambda k, lc, l0s: (k, lc, I0))
+        o_spec = pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0))
+        o_rows = J
+    else:           # the rhs tile stays resident across a tile's chunks
+        x_spec = pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0))
+        o_spec = pl.BlockSpec((tk, lchunk, C2),
+                              lambda k, lc, l0s: (k, lc, I0))
+        o_rows = B
+    return pl.pallas_call(
+        partial(_stream_kernel, lchunk, sdt, inverse),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(K // tk, nL),
+            in_specs=[
+                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, I0)),   # seeds
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # m
+                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # mp
+                pl.BlockSpec((1, J), lambda k, lc, l0s: (I0, I0)),  # cos_beta
+                pl.BlockSpec((1, 2, tk, J),
+                             lambda k, lc, l0s: (lc, I0, k, I0)),  # windows
+                x_spec,
+            ],
+            out_specs=o_spec,
+            scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt),
+                            pltpu.VMEM((tk, lchunk, J), dt)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((K, o_rows, C2), x.dtype),
+        interpret=interpret,
+    )(jnp.asarray(l0s, jnp.int32), seeds, mf, mpf, cb,
+      windows.astype(sdt), x)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "lchunk", "precision",
@@ -176,82 +230,15 @@ def dwt_streaming(seeds, m, mp, cos_beta, rhs, l0s, windows, *, B, tk=8,
     Same contract as :func:`repro.kernels.dwt_fused.dwt_fused` plus:
     windows -- the (nL, 2, K, J) chunk-boundary state from
     :func:`build_windows` (in the storage dtype); lchunk -- chunk length
-    (must divide B); precision -- "fp32" (everything in the plan dtype;
-    bitwise-equal to the monolithic kernel) or "bf16" (bf16 window
-    storage + bf16 contraction rows; recurrence state and accumulation
-    stay in the plan dtype).  Returns out (K, B, C2) in the rhs dtype.
+    (must divide B), which is also the panel depth; precision -- "fp32"
+    (everything in the plan dtype; bitwise-equal to the monolithic
+    kernel) or "bf16" (bf16 window storage + bf16-rounded rows; recurrence
+    state and accumulation stay in the plan dtype).  Returns out
+    (K, B, C2) in the rhs dtype.
     """
-    interpret = resolve_interpret(interpret)
-    lchunk = check_lchunk(B, lchunk, tiled=not interpret)
-    K, J = seeds.shape
-    C2 = rhs.shape[-1]
-    tk = min(tk, K)
-    if K % tk:
-        raise ValueError(f"K={K} % tk={tk}")
-    nL = B // lchunk
-    if windows.shape != (nL, 2, K, J):
-        raise ValueError(f"windows {windows.shape} != {(nL, 2, K, J)}")
-    dt = seeds.dtype
-    sdt = jnp.bfloat16 if precision == "bf16" else dt
-    mf = m.astype(dt)[:, None]
-    mpf = mp.astype(dt)[:, None]
-    cb = cos_beta.astype(dt)[None, :]
-    out = pl.pallas_call(
-        partial(_stream_fwd_kernel, B, lchunk, sdt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(K // tk, nL),
-            in_specs=[
-                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, I0)),   # seeds
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # m
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),   # mp
-                pl.BlockSpec((1, J), lambda k, lc, l0s: (I0, I0)),  # cos_beta
-                pl.BlockSpec((1, 2, tk, J),
-                             lambda k, lc, l0s: (lc, I0, k, I0)),  # windows
-                pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0)),
-            ],
-            out_specs=pl.BlockSpec((tk, lchunk, C2),
-                                   lambda k, lc, l0s: (k, lc, I0)),
-            scratch_shapes=[pltpu.VMEM((tk, J), dt),
-                            pltpu.VMEM((tk, J), dt)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((K, B, C2), rhs.dtype),
-        interpret=interpret,
-    )(jnp.asarray(l0s, jnp.int32), seeds, mf, mpf, cb,
-      windows.astype(sdt), rhs)
-    return out
-
-
-def _stream_inv_kernel(L, lchunk, row_dtype, l0_ref, seeds_ref, m_ref,
-                       mp_ref, cb_ref, w_ref, l_ref, o_ref, prev_ref,
-                       cur_ref):
-    g = pl.program_id(0)
-    lc = pl.program_id(1)
-    base = lc * lchunk
-    l0 = jnp.maximum(l0_ref[g], base)
-    seeds = seeds_ref[...]
-    m = m_ref[...]
-    mp = mp_ref[...]
-    cb = cb_ref[...]
-    prev_ref[...] = w_ref[0, 0].astype(prev_ref.dtype)
-    cur_ref[...] = w_ref[0, 1].astype(cur_ref.dtype)
-
-    # the output block revisits across the (innermost) chunk axis:
-    # initialize once, then every chunk accumulates its l-slice in the
-    # same ascending order the monolithic kernel uses.
-    @pl.when(lc == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    def body(l, _):
-        row = _stream_step(l, m, mp, cb, prev_ref, cur_ref, seeds,
-                           row_dtype)
-        lhs_l = l_ref[:, pl.ds(l - base, 1), :]          # (TK, 1, C2)
-        # widen the row before the broadcast (no bf16 shape cast in Mosaic)
-        o_ref[...] += (row.astype(o_ref.dtype)[:, :, None]
-                       * lhs_l).astype(o_ref.dtype)
-
-    jax.lax.fori_loop(l0, base + lchunk, body, None)
+    return _stream_call(seeds, m, mp, cos_beta, rhs, l0s, windows, B=B,
+                        tk=tk, lchunk=lchunk, precision=precision,
+                        inverse=False, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("B", "tk", "lchunk", "precision",
@@ -261,43 +248,6 @@ def idwt_streaming(seeds, m, mp, cos_beta, lhs, l0s, windows, *, B, tk=8,
     """Inverse fused iDWT, l-chunked: the (K, B, C2) coefficient stack
     stays HBM-resident and is staged chunk-by-chunk into (tk, lchunk, C2)
     VMEM tiles; see :func:`dwt_streaming`.  Returns g (K, J, C2)."""
-    interpret = resolve_interpret(interpret)
-    lchunk = check_lchunk(B, lchunk, tiled=not interpret)
-    K, J = seeds.shape
-    C2 = lhs.shape[-1]
-    tk = min(tk, K)
-    if K % tk:
-        raise ValueError(f"K={K} % tk={tk}")
-    nL = B // lchunk
-    if windows.shape != (nL, 2, K, J):
-        raise ValueError(f"windows {windows.shape} != {(nL, 2, K, J)}")
-    dt = seeds.dtype
-    sdt = jnp.bfloat16 if precision == "bf16" else dt
-    mf = m.astype(dt)[:, None]
-    mpf = mp.astype(dt)[:, None]
-    cb = cos_beta.astype(dt)[None, :]
-    out = pl.pallas_call(
-        partial(_stream_inv_kernel, B, lchunk, sdt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(K // tk, nL),
-            in_specs=[
-                pl.BlockSpec((tk, J), lambda k, lc, l0s: (k, I0)),
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),
-                pl.BlockSpec((tk, 1), lambda k, lc, l0s: (k, I0)),
-                pl.BlockSpec((1, J), lambda k, lc, l0s: (I0, I0)),
-                pl.BlockSpec((1, 2, tk, J),
-                             lambda k, lc, l0s: (lc, I0, k, I0)),
-                pl.BlockSpec((tk, lchunk, C2),
-                             lambda k, lc, l0s: (k, lc, I0)),      # staged
-            ],
-            out_specs=pl.BlockSpec((tk, J, C2),     # revisited over lc
-                                   lambda k, lc, l0s: (k, I0, I0)),
-            scratch_shapes=[pltpu.VMEM((tk, J), dt),
-                            pltpu.VMEM((tk, J), dt)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((K, J, C2), lhs.dtype),
-        interpret=interpret,
-    )(jnp.asarray(l0s, jnp.int32), seeds, mf, mpf, cb,
-      windows.astype(sdt), lhs)
-    return out
+    return _stream_call(seeds, m, mp, cos_beta, lhs, l0s, windows, B=B,
+                        tk=tk, lchunk=lchunk, precision=precision,
+                        inverse=True, interpret=interpret)

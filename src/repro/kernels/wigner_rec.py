@@ -64,9 +64,10 @@ def _recurrence_step(l, m, mp, cb, d_prev, d_cur, seeds):
 
 def _contract_row(row, rhs, out_dtype):
     """Fold one generated degree-row into the forward contraction:
-    out[k, c] = sum_j row[k, j] * rhs[k, j, c], shared by every forward
-    kernel.  A broadcast multiply and a reduction over J, which Mosaic
-    lowers; it refuses the batched mat-vec einsum ("kj,kjc->kc")."""
+    out[k, c] = sum_j row[k, j] * rhs[k, j, c].  A broadcast multiply and
+    a reduction over J, which Mosaic lowers; it refuses the batched
+    mat-vec einsum ("kj,kjc->kc").  The fused family contracts a panel
+    of rows on the MXU instead (dwt_fused.contract_panel)."""
     # cast before the broadcast: Mosaic has no bf16 (K, J) -> (K, J, 1)
     return jnp.sum(row.astype(out_dtype)[:, :, None] * rhs.astype(out_dtype),
                    axis=1)

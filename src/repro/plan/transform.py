@@ -216,7 +216,7 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
         return autotune.estimate_vmem_bytes(impl, L=L, J=J, C2=v * C * 2,
                                             tk=tk, tl=tl, tj=tj,
                                             itemsize=itemsize, lchunk=lc,
-                                            precision=prec)
+                                            precision=prec, limit=limit)
 
     if V == "auto":
         fits = [v for v in AUTO_V_CANDIDATES if est(v, lchunk) <= limit] \
@@ -321,7 +321,7 @@ def _measured_schedule(soft_plan: SoftPlan, impl, V, limit: int, interpret,
         best_impl, L=L, J=J, C2=best["V"] * C * 2, tk=best["tk"],
         tl=best["tl"], tj=best["tj"],
         itemsize=jnp.dtype(soft_plan.dtype).itemsize,
-        lchunk=lchunk, precision=prec)
+        lchunk=lchunk, precision=prec, limit=limit)
     return Schedule(best_impl, best["V"], best["tk"], best["tl"], best["tj"],
                     "measured", est, limit, n_shards, overlap=omode,
                     lchunk=lchunk, precision=prec,
@@ -406,7 +406,11 @@ class Transform:
         streaming engages), and ``est_peak_hbm_bytes`` the estimated
         whole-transform HBM residency (grid + stacks + Wigner working
         set) -- read these BEFORE launching a large B to see which tier
-        would blow up.  ``est_host_plan_bytes`` is the host-tier twin:
+        would blow up.  ``panel`` is the depth P of the fused kernels'
+        Wigner panel (rows per MXU product; the whole degree range B when
+        the budget allows, lchunk when streaming; None off the fused
+        family), at the schedule's lane width V.
+        ``est_host_plan_bytes`` is the host-tier twin:
         the peak RSS plan CONSTRUCTION costs (the dense O(B^3) table
         cliff, or the streaming generator's O(P*J) panels when
         ``streaming`` is True).  ``precision_bound_extrapolated`` flags
@@ -436,6 +440,10 @@ class Transform:
             "streaming": sp.streaming,
             "vmem_bytes": s.vmem_bytes,
             "vmem_limit": s.vmem_limit, "n_shards": self.n_shards,
+            "panel": autotune.panel_depth(
+                L=L, J=J, C2=s.V * C * 2, tk=s.tk, itemsize=itemsize,
+                lchunk=s.lchunk, limit=s.vmem_limit)
+            if s.impl == "fused" else None,
             "n_clusters": sp.n_clusters,
             "n_padded": sp.n_padded,
             "est_live_coeff_bytes": autotune.estimate_live_coeff_bytes(
@@ -503,7 +511,8 @@ class Transform:
         s = self.schedule
         return maker(self.soft_plan, impl, tk=s.tk, tl=s.tl, tj=s.tj,
                      lchunk=s.lchunk, precision=s.precision,
-                     interpret=self.interpret, batch=batch)
+                     vmem_limit=s.vmem_limit, interpret=self.interpret,
+                     batch=batch)
 
     def shard_meta(self):
         """Fused-kernel shard metadata (seeds / orders / per-tile l0s),

@@ -1,11 +1,13 @@
 """Fused ragged+on-the-fly DWT kernel: parity against the jnp oracle and
-the other schedules, multi-transform lane batching, the batch transform
-wrappers, and the measured autotuner."""
+the other schedules, the Wigner panel contraction (full and short panels,
+padded clusters, lane batching), multi-transform lane batching, the batch
+transform wrappers, and the measured autotuner."""
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
+from repro import plan as plan_mod
 from repro.core import batched, soft
 from repro.kernels import autotune, dwt_fused, ops, ref
 
@@ -74,6 +76,63 @@ def test_fused_inverse_matches_oracle():
     expect = np.asarray(ref.idwt_ref(plan.d, lhs.reshape(K, L, 16)))
     np.testing.assert_allclose(out.reshape(K, J, 16), expect, rtol=1e-10,
                                atol=1e-11)
+
+
+# ---------------------------------------------------------------------------
+# the Wigner panel: rows generated into VMEM, one MXU product per cluster
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,panel", [(8, "B"), (16, "B"), (32, "B"),
+                                     (16, "short"), (32, "short")])
+@pytest.mark.parametrize("V", [1, 2])
+@pytest.mark.parametrize("direction", ["fwd", "inv"])
+def test_panel_contraction_matches_oracle(B, panel, V, direction):
+    """Padded clusters (pad_to=32), tiles whose l0 > 0, and, for "short",
+    a VMEM budget that leaves room for an 8-row panel only, so the kernel
+    closes B/8 panels per grid step."""
+    tk, C2 = 8, V * 16
+    plan = batched.build_plan(B, dtype=jnp.float64, pad_to=32)
+    K, L, J = plan.d.shape
+    assert plan.n_padded > plan.n_clusters
+    _, l_start, l0s = ops.fused_metadata(plan, tk)
+    assert (l0s > 0).any()
+    limit = None
+    if panel == "short":
+        limit = autotune.estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
+                                             itemsize=8, panel=8)
+    want_P = 8 if panel == "short" else B
+    assert autotune.panel_depth(L=L, J=J, C2=C2, tk=tk, itemsize=8,
+                                limit=limit) == want_P
+    maker = ops.make_dwt_fn if direction == "fwd" else ops.make_idwt_fn
+    fn = maker(plan, "fused", tk=tk, vmem_limit=limit,
+               batch=None if V == 1 else V)
+    if direction == "fwd":
+        x = rand((V, K, J, 8, 2), scale=0.3)
+    else:   # coefficients are zero below each cluster's l-start
+        x = rand((V, K, L, 8, 2)) * (np.arange(L)[:, None, None]
+                                     >= l_start[:, None, None, None])
+    out = np.asarray(fn(plan, x[0] if V == 1 else x)).reshape(V, K, -1, 16)
+    oracle = ref.dwt_ref if direction == "fwd" else ref.idwt_ref
+    for v in range(V):
+        expect = np.asarray(oracle(plan.d, x[v].reshape(K, -1, 16)))
+        np.testing.assert_allclose(out[v], expect, rtol=1e-10, atol=1e-11)
+    if direction == "fwd":
+        # rows below each cluster's l-start, and padded clusters, read zero
+        below = np.arange(L)[None, :] < l_start[:, None]
+        assert not out[:, below].any()
+        assert not out[:, plan.n_clusters:].any()
+
+
+def test_describe_reports_the_panel():
+    """The paper's B = 128 in f32 runs one 128-row panel per grid step:
+    one product per cluster.  A streaming schedule's panel is its chunk."""
+    t = plan_mod.plan(128, jnp.float32)
+    d = t.describe()
+    assert (d["impl"], d["V"], d["lchunk"], d["tk"]) == ("fused", 8, None, 8)
+    assert d["panel"] == 128
+    s = plan_mod.plan(16, dtype=jnp.float32, impl="fused", tk=4, lchunk=8)
+    assert s.describe()["panel"] == 8
+    assert plan_mod.plan(8, impl="reference").describe()["panel"] is None
 
 
 # ---------------------------------------------------------------------------
@@ -182,6 +241,18 @@ def test_vmem_estimate_grows_with_lanes_and_tiles():
     # lane packing (C2 = V*C*2) and cluster tiling both grow the footprint
     assert autotune.estimate_vmem_bytes("fused", tk=8, C2=128, **kw) > base
     assert autotune.estimate_vmem_bytes("fused", tk=16, C2=16, **kw) > base
+    # the fused kernels' (tk, P, J) Wigner panel is counted: the whole
+    # degree range under the default budget, 4 bytes a row element
+    short = autotune.estimate_vmem_bytes("fused", tk=8, C2=16, panel=8, **kw)
+    assert base - short == 4 * 8 * (16 - 8) * 32
+    assert autotune.estimate_vmem_bytes("onthefly", tk=8, C2=16,
+                                        **kw) == base - 4 * 8 * 16 * 32
+    # a budget between the two shrinks the panel instead of failing
+    assert autotune.estimate_vmem_bytes("fused", tk=8, C2=16, limit=short,
+                                        **kw) == short
+    # a streaming schedule's panel is its chunk
+    assert autotune.estimate_vmem_bytes("fused", tk=8, C2=16, lchunk=8,
+                                        **kw) < base
     dense = autotune.estimate_vmem_bytes("dense", tk=8, tl=16, tj=32, C2=16,
                                          **kw)
     assert dense > 0

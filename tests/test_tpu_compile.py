@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import plan
-from repro.kernels import dwt_fused, streaming
+from repro.kernels import autotune, dwt_fused, streaming
 
 F32, I32 = jnp.float32, jnp.int32
 
@@ -71,13 +71,31 @@ def _operands(B, K, tk, V, direction):
 
 @pytest.mark.parametrize("direction", ["fwd", "inv"])
 @pytest.mark.parametrize("B,lanes", [(128, "one"), (128, "plan"),
-                                     (256, "plan")])
+                                     (256, "plan"), (64, "served")])
 def test_fused_kernel_compiles_for_v5e(one_chip, B, lanes, direction):
+    """``served`` is the match service's launch: B = 64, 4 lanes (C2 =
+    64)."""
     K, tk, V = _schedule(B)
-    V = 1 if lanes == "one" else V
+    V = {"one": 1, "served": 4}.get(lanes, V)
     fn = dwt_fused.dwt_fused if direction == "fwd" else dwt_fused.idwt_fused
     hlo = _compile_hlo(fn, _operands(B, K, tk, V, direction), one_chip,
                        B=B, tk=tk)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("direction", ["fwd", "inv"])
+def test_fused_kernel_compiles_with_short_panels(one_chip, direction):
+    """A budget that leaves room for a 32-row Wigner panel only: the
+    kernel closes four panels per grid step."""
+    B, P = 128, 32
+    K, tk, _ = _schedule(B)
+    limit = autotune.estimate_vmem_bytes("fused", L=B, J=2 * B, C2=16,
+                                         tk=tk, panel=P)
+    assert autotune.panel_depth(L=B, J=2 * B, C2=16, tk=tk,
+                                limit=limit) == P
+    fn = dwt_fused.dwt_fused if direction == "fwd" else dwt_fused.idwt_fused
+    hlo = _compile_hlo(fn, _operands(B, K, tk, 1, direction), one_chip,
+                       B=B, tk=tk, vmem_limit=limit)
     assert "tpu_custom_call" in hlo
 
 
