@@ -8,6 +8,9 @@
   streaming.py         the fused family at paper-scale B: l-chunked
                        coefficient staging (HBM-resident stacks, two-row
                        recurrence windows) + bf16 storage precision
+  peaks.py             grid_peaks: per-lane maximum and first argmax of a
+                       stack of correlation grids (a bank query's peak
+                       search, so no grid leaves the device)
   folded_attention.py  causal flash attention on the paper's folded grid
   autotune.py          measured (tk, tl, tj, V) sweep, on-disk cache
   ops.py               jit'd wrappers (auto interpret-mode on CPU)
@@ -81,5 +84,5 @@ grammar).  benchmarks/dwt_schedules.py prints the block/HBM accounting
 behind the guidance above, and benchmarks/planner.py smokes the plan
 build/cache/executor path.
 """
-from . import (autotune, dwt, dwt_fused, folded_attention, ops, ref,  # noqa: F401
-               runtime, streaming, wigner_rec)
+from . import (autotune, dwt, dwt_fused, folded_attention, ops, peaks,  # noqa: F401
+               ref, runtime, streaming, wigner_rec)

@@ -625,6 +625,19 @@ class Transform:
                            out_shape=(2 * self.B,) * 3, stats=stats,
                            overlap=overlap)
 
+    def inverse_lanes(self, fhats):
+        """iFSOFT of exactly V lane-packed transforms, traceable inside a
+        caller's ``jax.jit``: (V, B, 2B-1, 2B-1) -> (V, 2B, 2B, 2B).  One
+        chunk of :meth:`inverse_batch`, with no padding, slicing or
+        launch accounting: a bank query (``CorrelationEngine.match_bank``)
+        compiles it into its per-chunk executable.  On a mesh plan it is
+        the executor's sharded lane launch."""
+        if self.mesh is not None:
+            return self.executor().inverse_lanes(
+                parallel.dense_to_packed_batch(self.soft_plan, fhats))
+        return batched.inverse_clustered_batch(self.soft_plan, fhats,
+                                               idwt_fn=self.idwt_fn_batch)
+
     def _batch(self, xs, engine, get_fn, fn_kw, out_shape, stats,
                overlap=None):
         stats = self.stats if stats is None else stats

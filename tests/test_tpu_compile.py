@@ -2,12 +2,13 @@
 
 Interpret mode, which every other test runs, never goes through the TPU's
 compiler (Mosaic).  These tests compile the fused and streaming forward
-and inverse kernels for one chip of a described ``v5e:2x2`` topology at
-paper-scale shapes (no chip is needed or used) and assert that each
-compiled program holds its ``tpu_custom_call``.  They run under the
-suite's global x64, so they also guard the int32 index maps and loop
-bounds.  Lane width V and cluster tile tk come from the planner's static
-resolution, so its VMEM guard is checked against the real compiler.
+and inverse kernels, and the bank query's peak search, for one chip of a
+described ``v5e:2x2`` topology at paper-scale shapes (no chip is needed or
+used) and assert that each compiled program holds its ``tpu_custom_call``.
+They run under the suite's global x64, so they also guard the int32 index
+maps and loop bounds.  Lane width V and cluster tile tk come from the
+planner's static resolution, so its VMEM guard is checked against the real
+compiler.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every pytest worker
@@ -21,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import plan
-from repro.kernels import autotune, dwt_fused, streaming
+from repro.kernels import autotune, dwt_fused, peaks, streaming
 
 F32, I32 = jnp.float32, jnp.int32
 
@@ -112,3 +113,14 @@ def test_streaming_kernel_compiles_for_v5e(one_chip, precision, direction):
     hlo = _compile_hlo(fn, shapes, one_chip, B=B, tk=tk, lchunk=lchunk,
                        precision=precision)
     assert "tpu_custom_call" in hlo
+
+
+def test_grid_peaks_compiles_for_v5e(one_chip):
+    """The bank query's peak search: V = 8 lanes of B = 64 grids, read as
+    (V, (2B)^3 / 128, 128) blocks with the int32 row iota."""
+    B = 64
+    _, _, V = _schedule(B)
+    assert V == 8
+    hlo = _compile_hlo(peaks.grid_peaks, [((V,) + (2 * B,) * 3, F32)],
+                       one_chip)
+    assert "tpu_custom_call" in hlo and "%grid_peaks" in hlo
