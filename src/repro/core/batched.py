@@ -8,7 +8,9 @@ the whole DWT stage (all clusters) becomes ONE batched contraction
 
 where k runs over symmetry clusters (paper's work packages, kappa-ordered),
 c over the <= 8 cluster members, and d is the fundamental-domain Wigner
-table.  Gather/scatter/sign metadata comes from :mod:`clusters`.
+table.  Gather/sign metadata comes from :mod:`clusters`; the inverse
+index tables that place members into FFT bins and dense coefficients are
+built with the plan (:func:`member_sources`).
 
 The same plan drives
   * the pure-jnp path below (runs anywhere, differentiable),
@@ -63,6 +65,8 @@ class SoftPlan:
     scatter_mp: jnp.ndarray # (K, C)
     sign: jnp.ndarray       # (K, C) f32    0 marks unused slots
     reflected: jnp.ndarray  # (K, C) bool
+    bin_src: jnp.ndarray    # ((2B)^2,) int32    member k*C+c of FFT bin (m, m')
+    coeff_src: jnp.ndarray  # ((2B-1)^2,) int32  member of dense bin (m, m')
     w: jnp.ndarray          # (J,)   quadrature weights
     scale: jnp.ndarray      # (L,)   (2l+1)/(8 pi B)
     parity: jnp.ndarray     # (L,)   (-1)^l
@@ -100,7 +104,8 @@ class SoftPlan:
 # flattens to zero leaves (None is a registered empty pytree), so jit
 # tracing works unchanged for both variants.
 _PLAN_LEAVES = ("d", "gather_m", "gather_mp", "scatter_m", "scatter_mp",
-                "sign", "reflected", "w", "scale", "parity")
+                "sign", "reflected", "bin_src", "coeff_src", "w", "scale",
+                "parity")
 
 
 def _plan_flatten(p: SoftPlan):
@@ -254,16 +259,21 @@ def build_plan(B: int, dtype=jnp.float64, pad_to: int | None = None,
         d = jnp.asarray(padk(fund[tab.fund_row]), dtype=dtype)
 
     trash = 2 * B - 1
+    gm, gmp = padk(tab.gather_m), padk(tab.gather_mp)
+    sm, smp = padk(tab.scatter_m, fill=trash), padk(tab.scatter_mp, fill=trash)
+    sign = padk(tab.sign)
     plan = SoftPlan(
         B=B,
         table=tab,
         d=d,
-        gather_m=jnp.asarray(padk(tab.gather_m)),
-        gather_mp=jnp.asarray(padk(tab.gather_mp)),
-        scatter_m=jnp.asarray(padk(tab.scatter_m, fill=trash)),
-        scatter_mp=jnp.asarray(padk(tab.scatter_mp, fill=trash)),
-        sign=jnp.asarray(padk(tab.sign)).astype(dtype),
+        gather_m=jnp.asarray(gm),
+        gather_mp=jnp.asarray(gmp),
+        scatter_m=jnp.asarray(sm),
+        scatter_mp=jnp.asarray(smp),
+        sign=jnp.asarray(sign).astype(dtype),
         reflected=jnp.asarray(padk(tab.reflected)),
+        bin_src=jnp.asarray(member_sources(gm, gmp, sign, 2 * B)),
+        coeff_src=jnp.asarray(member_sources(sm, smp, sign, 2 * B - 1)),
         w=jnp.asarray(quadrature.weights(B), dtype=dtype),
         scale=jnp.asarray((2 * np.arange(B) + 1) / (8 * np.pi * B), dtype=dtype),
         parity=jnp.asarray((-1.0) ** np.arange(B), dtype=dtype),
@@ -274,6 +284,22 @@ def build_plan(B: int, dtype=jnp.float64, pad_to: int | None = None,
     )
     _plan_cache_put(key, plan)
     return plan
+
+
+def member_sources(row, col, sign, n: int) -> np.ndarray:
+    """Inverse of a member placement: for each cell (row, col) of an n x n
+    layout, the flat member index k*C + c that lands there.
+
+    Valid members (sign != 0) fill distinct cells, so every cell has at
+    most one source and a placement is a gather.  Cells no member fills
+    (the FFT's Nyquist bins) hold K*C, out of range: the gather's fill."""
+    valid = np.flatnonzero(np.asarray(sign).reshape(-1) != 0)
+    cell = (np.asarray(row, np.int64) * n + col).reshape(-1)[valid]
+    if len(np.unique(cell)) != len(cell):
+        raise ValueError("two cluster members share one output cell")
+    src = np.full(n * n, sign.size, np.int32)
+    src[cell] = valid
+    return src
 
 
 def _permute_table(tab, perm):
@@ -381,7 +407,7 @@ def fft_synthesis(gbin):
 # monolithic S / gbin intermediates (nor the (K, C, J) complex gather
 # temporaries) that the dense path materializes.
 #
-# The only j-coupling in the surrounding gather/scatter is the beta
+# The only j-coupling in the surrounding gathers is the beta
 # reflection: a reflected member's output slab [j0, j1) reads the MIRROR
 # slab [J-j1, J-j0) reversed.  Slab bounds need no symmetry for that --
 # the mirror slab's FFT is computed directly from the matching f slab.
@@ -428,16 +454,16 @@ def streamed_rhs(plan: SoftPlan, f):
 
 
 def streamed_synthesis(plan: SoftPlan, gc):
-    """Scatter-to-bins + FFT-synthesis, streamed in beta slabs: bitwise
-    equal to fft_synthesis(_scatter_bins(plan, gc)) without the monolithic
-    (2B+1, 2B, 2B+1) bin buffer."""
+    """Bin placement + FFT-synthesis, streamed in beta slabs: bitwise
+    equal to fft_synthesis(_place_bins(plan, gc)) without the monolithic
+    (2B, 2B, 2B) bin buffer."""
     J = 2 * plan.B
     parts = []
     for j0, j1 in _slab_bounds(J):
         direct = gc[:, j0:j1, :]
         mirror = gc[:, J - j1:J - j0, :][:, ::-1, :]
         gs = jnp.where(plan.reflected[:, None, :], mirror, direct)
-        parts.append(fft_synthesis(_scatter_bins_nomirror(plan, gs)))
+        parts.append(fft_synthesis(_place_bins_nomirror(plan, gs)))
     return jnp.concatenate(parts, axis=1)
 
 
@@ -483,17 +509,31 @@ def idwt_apply(plan: SoftPlan, lhs):
     return out.reshape(out.shape[0], out.shape[1], lhs.shape[2], lhs.shape[3])
 
 
-def _scatter_coeffs(plan: SoftPlan, out):
-    """Scatter out[k, l, c] (complex) into the dense coefficient layout."""
-    B = plan.B
-    # output sign: (-1)^l for reflected members; scale (2l+1)/(8 pi B)
+def place_members(src, rows):
+    """Gather complex member rows (N, n) into the cells of ``src``, an index
+    table of :func:`member_sources`: (len(src), n), 0 where a cell has no
+    member.  One gather moves both parts, each row laid out [Re | Im] so
+    that it is lane-dense."""
+    n = rows.shape[-1]
+    ri = jnp.concatenate([rows.real, rows.imag], axis=-1)      # (N, 2n)
+    out = ri.at[src].get(mode="fill", fill_value=0,
+                         wrap_negative_indices=False)
+    return jax.lax.complex(out[:, :n], out[:, n:])
+
+
+def place_coeffs(plan: SoftPlan, out):
+    """out[k, l, c] (complex) -> dense coefficients (L, 2B-1, 2B-1)."""
+    n = 2 * plan.B - 1
+    rows = jnp.swapaxes(out, 1, 2).reshape(-1, out.shape[1])   # (K*C, L)
+    return place_members(plan.coeff_src, rows).T.reshape(-1, n, n)
+
+
+def _output_coeffs(plan: SoftPlan, out):
+    """out[k, l, c] (complex) -> the dense coefficient layout, with the
+    output sign (-1)^l of reflected members and scale (2l+1)/(8 pi B)."""
     sgn = jnp.where(plan.reflected[:, None, :], plan.parity[None, :, None],
                     jnp.ones((), plan.parity.dtype))
-    out = out * (sgn * plan.scale[None, :, None])
-    buf = jnp.zeros((B, 2 * B, 2 * B), dtype=out.dtype)
-    buf = buf.at[:, plan.scatter_m.reshape(-1), plan.scatter_mp.reshape(-1)].set(
-        out.transpose(1, 0, 2).reshape(B, -1), mode="drop")
-    return buf[:, : 2 * B - 1, : 2 * B - 1]
+    return place_coeffs(plan, out * (sgn * plan.scale[None, :, None]))
 
 
 def _gather_coeffs(plan: SoftPlan, fhat):
@@ -508,23 +548,20 @@ def _gather_coeffs(plan: SoftPlan, fhat):
     return jnp.stack([lhs.real, lhs.imag], axis=-1)  # (K, L, C, 2)
 
 
-def _scatter_bins_nomirror(plan: SoftPlan, g):
-    """Scatter g[k, j, c] (complex, reflection already applied) into FFT
-    bins (2B, j, 2B).  j-independent, so slab callers pass partial-j g."""
-    B = plan.B
-    buf = jnp.zeros((2 * B + 1, g.shape[1], 2 * B + 1), dtype=g.dtype)
-    # member bins; unused slots -> trash bin 2B (sliced off)
-    gm = jnp.where(plan.sign != 0, plan.gather_m, 2 * B).reshape(-1)
-    gmp = jnp.where(plan.sign != 0, plan.gather_mp, 2 * B).reshape(-1)
-    buf = buf.at[gm, :, gmp].set(
-        jnp.swapaxes(g, 1, 2).reshape(-1, g.shape[1]), mode="drop")
-    return buf[: 2 * B, :, : 2 * B]
+def _place_bins_nomirror(plan: SoftPlan, g):
+    """FFT bins (2B, j, 2B) of g[k, j, c] (complex, reflection already
+    applied): each bin gathers its member's column (plan.bin_src), the
+    Nyquist bins read 0.  j-independent, so slab callers pass partial-j g."""
+    n = 2 * plan.B
+    rows = jnp.swapaxes(g, 1, 2).reshape(-1, g.shape[1])       # (K*C, j)
+    bins = place_members(plan.bin_src, rows).reshape(n, n, -1)
+    return bins.transpose(0, 2, 1)
 
 
-def _scatter_bins(plan: SoftPlan, g):
-    """Scatter g[k, j, c] (complex) into FFT bins (2B, j, 2B)."""
+def _place_bins(plan: SoftPlan, g):
+    """FFT bins (2B, j, 2B) of g[k, j, c] (complex)."""
     g = jnp.where(plan.reflected[:, None, :], g[:, ::-1, :], g)
-    return _scatter_bins_nomirror(plan, g)
+    return _place_bins_nomirror(plan, g)
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +574,7 @@ def _forward_jit(plan: SoftPlan, f):
     rhs = _gather_rhs(plan, S)
     out = dwt_apply(plan, rhs)
     outc = out[..., 0] + 1j * out[..., 1]
-    return _scatter_coeffs(plan, outc)
+    return _output_coeffs(plan, outc)
 
 
 def _require_recurrence_fn(plan: SoftPlan, fn, which: str):
@@ -562,7 +599,7 @@ def forward_clustered(plan: SoftPlan, f, dwt_fn=None):
         else _gather_rhs(plan, fft_analysis(f))
     out = dwt_fn(plan, rhs)
     outc = out[..., 0] + 1j * out[..., 1]
-    return _scatter_coeffs(plan, outc)
+    return _output_coeffs(plan, outc)
 
 
 @partial(jax.jit, static_argnums=())
@@ -570,7 +607,7 @@ def _inverse_jit(plan: SoftPlan, fhat):
     lhs = _gather_coeffs(plan, fhat)
     g = idwt_apply(plan, lhs)
     gc = g[..., 0] + 1j * g[..., 1]
-    gbin = _scatter_bins(plan, gc)
+    gbin = _place_bins(plan, gc)
     return fft_synthesis(gbin)
 
 
@@ -585,7 +622,7 @@ def inverse_clustered(plan: SoftPlan, fhat, idwt_fn=None):
     gc = g[..., 0] + 1j * g[..., 1]
     if plan.streaming:
         return streamed_synthesis(plan, gc)
-    return fft_synthesis(_scatter_bins(plan, gc))
+    return fft_synthesis(_place_bins(plan, gc))
 
 
 # ---------------------------------------------------------------------------
@@ -615,7 +652,7 @@ def forward_clustered_batch(plan: SoftPlan, f, dwt_fn=None):
     else:
         out = dwt_fn(plan, rhs)                          # (V, K, L, C, 2)
     outc = out[..., 0] + 1j * out[..., 1]
-    return jax.vmap(lambda o: _scatter_coeffs(plan, o))(outc)
+    return jax.vmap(lambda o: _output_coeffs(plan, o))(outc)
 
 
 def inverse_clustered_batch(plan: SoftPlan, fhat, idwt_fn=None):
@@ -631,5 +668,5 @@ def inverse_clustered_batch(plan: SoftPlan, fhat, idwt_fn=None):
     gc = g[..., 0] + 1j * g[..., 1]
     if plan.streaming:
         return jax.vmap(lambda x: streamed_synthesis(plan, x))(gc)
-    gbin = jax.vmap(lambda x: _scatter_bins(plan, x))(gc)
+    gbin = jax.vmap(lambda x: _place_bins(plan, x))(gc)
     return jax.vmap(fft_synthesis)(gbin)

@@ -21,7 +21,7 @@ Cluster types (paper: m=0 / m'=0 / m=m' "treated in advance"):
   ZERO (0, 0):               1 member
 
 All clusters are packed into one uniform (K, 8)-slotted table; unused slots
-have sign 0 and scatter to a trash cell, so the whole DWT stage is a single
+have sign 0 and address a trash cell, so the whole DWT stage is a single
 batched contraction -- the TPU-native agglomeration of the paper's packages.
 """
 from __future__ import annotations

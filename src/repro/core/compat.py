@@ -10,7 +10,7 @@ Every mesh in the repo is built by :func:`make_mesh`, which pins all axes
 to ``AxisType.Auto``: the shard_map paths place their operands through
 explicit ``in_specs``/``out_specs`` and leave everything outside the
 shard_map to the compiler's sharding propagation.  Under jax's default of
-Explicit axes, host-side scatters on sharded outputs (``packed_to_dense``)
+Explicit axes, host-side ops on sharded outputs (``packed_to_dense``)
 would have to name their output sharding.
 """
 from __future__ import annotations

@@ -81,7 +81,7 @@ from repro import obs
 
 from .compat import shard_map, shard_map_norep
 
-from .batched import SoftPlan, fft_analysis, fft_synthesis
+from .batched import SoftPlan, fft_analysis, fft_synthesis, place_coeffs
 
 __all__ = [
     "DistExecutor", "dist_executor", "check_mesh_compat",
@@ -806,11 +806,7 @@ def distributed_inverse(plan: SoftPlan, packed, mesh, axis=("data", "model"),
 
 def packed_to_dense(plan: SoftPlan, packed):
     """packed[k, l, c] -> dense fhat[l, m + B - 1, m' + B - 1]."""
-    B = plan.B
-    buf = jnp.zeros((B, 2 * B, 2 * B), dtype=packed.dtype)
-    buf = buf.at[:, plan.scatter_m.reshape(-1), plan.scatter_mp.reshape(-1)].set(
-        jnp.asarray(packed).transpose(1, 0, 2).reshape(B, -1), mode="drop")
-    return buf[:, : 2 * B - 1, : 2 * B - 1]
+    return place_coeffs(plan, jnp.asarray(packed))
 
 
 def dense_to_packed(plan: SoftPlan, fhat):

@@ -151,6 +151,8 @@ def soft_plan_specs(B, n_shards, dtype=jnp.float32):
         gather_m=sds((Kp, C), jnp.int32), gather_mp=sds((Kp, C), jnp.int32),
         scatter_m=sds((Kp, C), jnp.int32), scatter_mp=sds((Kp, C), jnp.int32),
         sign=sds((Kp, C), dtype), reflected=sds((Kp, C), jnp.bool_),
+        bin_src=sds((J * J,), jnp.int32),
+        coeff_src=sds(((J - 1) ** 2,), jnp.int32),
         w=sds((J,), dtype), scale=sds((L,), dtype), parity=sds((L,), dtype),
     )
     return b.SoftPlan(B=B, table=None, n_padded=Kp, **leaves)
@@ -163,4 +165,5 @@ def soft_shardings(plan, ctx, axis):
         d=_ns(ctx, ax), gather_m=_ns(ctx), gather_mp=_ns(ctx),
         scatter_m=_ns(ctx), scatter_mp=_ns(ctx),
         sign=_ns(ctx), reflected=_ns(ctx, ax),
+        bin_src=_ns(ctx), coeff_src=_ns(ctx),
         w=_ns(ctx, ax), scale=_ns(ctx), parity=_ns(ctx))
