@@ -2,22 +2,14 @@
 
   * folded vs naive causal-attention grid slots (kernels/folded_attention):
     slots = executed MXU block-steps per (batch, head) -- the structural
-    2x win, exact, no hardware needed;
-  * ragged vs dense DWT work-list blocks (kernels/dwt.build_work_list):
-    MXU blocks skipped by bucketing clusters by l-start (paper P3).
+    2x win, exact, no hardware needed.
 
 Also times both attention schedules in interpret mode at a small shape as a
 sanity check that they compute identical outputs (asserted in tests).
 """
 from __future__ import annotations
 
-import numpy as np
-import jax.numpy as jnp
-
-from repro.core import batched
-from repro.kernels import dwt as dwt_k
 from repro.kernels import folded_attention as fa
-from repro.kernels import ops
 
 
 def attention_slots(seqs=(2048, 4096, 8192, 32768), bq=256):
@@ -30,26 +22,6 @@ def attention_slots(seqs=(2048, 4096, 8192, 32768), bq=256):
     return rows
 
 
-def dwt_blocks(bandwidths=(64, 128, 256, 512), tk=8):
-    rows = []
-    for B in bandwidths:
-        # metadata only -- no table build at large B
-        from repro.core import clusters
-        tab = clusters.build_cluster_table(B)
-        K = tab.n_clusters
-        Kp = ((K + tk - 1) // tk) * tk
-        l_start = np.zeros(Kp, np.int32)
-        l_start[:K] = tab.rep[:, 0]
-        perm = np.argsort(l_start, kind="stable")
-        tl = max(B // 8, 8)  # 8 l-tiles per cluster: tiles below the
-        #                      cluster's l-start = m are skippable
-        kk, ll, n_dense = dwt_k.build_work_list(l_start[perm], tk, tl, B)
-        rows.append({"B": B, "tl": tl, "dense_blocks": n_dense,
-                     "ragged_blocks": len(kk),
-                     "flop_ratio": n_dense / len(kk)})
-    return rows
-
-
 def main(fast=False):
     print("# kernel_schedule: paper-P3 fold applied to kernel grids")
     print("## causal attention grid slots per (batch, head)")
@@ -57,12 +29,6 @@ def main(fast=False):
     for r in attention_slots():
         print(f"{r['S']},{r['bq']},{r['naive']},{r['folded']},"
               f"{r['ratio']:.3f}")
-    print("## clustered-DWT MXU blocks (ragged work list vs dense grid)")
-    print("B,l_tile,dense_blocks,ragged_blocks,flop_ratio")
-    bws = (64, 128) if fast else (64, 128, 256, 512)
-    for r in dwt_blocks(bws):
-        print(f"{r['B']},{r['tl']},{r['dense_blocks']},{r['ragged_blocks']},"
-              f"{r['flop_ratio']:.2f}")
     return True
 
 

@@ -6,8 +6,7 @@ Sections:
   error_table      paper Table 1 (roundtrip error, f64 + f32 ladder)
   workbalance      paper Figs 2-4 analog (schedule speedup bounds)
   soft_runtime     measured 1-core runtime (sequential vs clustered)
-  kernel_schedule  folded-attention / ragged-DWT grid savings
-  dwt_schedules    dense/ragged/onthefly/fused DWT kernels + V batching
+  kernel_schedule  folded-attention grid savings
   plan             repro.plan planner: build time, cache hits, executors
   distributed      serial-loop vs lane-packed sharded batches, overlap
                    off vs pipelined rows (2-dev mesh)
@@ -87,7 +86,7 @@ def lm_step(fast=False):
 
 
 SECTIONS = ("error_table", "workbalance", "soft_runtime", "kernel_schedule",
-            "dwt_schedules", "plan", "distributed", "correlation",
+            "plan", "distributed", "correlation",
             "serve_mixed", "lm_step", "roofline", "paper_scale")
 
 
@@ -127,9 +126,6 @@ def main() -> None:
         elif name == "kernel_schedule":
             from benchmarks import kernel_schedule
             rows = kernel_schedule.main(fast=args.fast)
-        elif name == "dwt_schedules":
-            from benchmarks import dwt_schedules
-            rows = dwt_schedules.main(fast=args.fast)
         elif name == "plan":
             from benchmarks import planner
             rows = planner.main(fast=args.fast)

@@ -7,8 +7,8 @@ resolves the kernel schedule and builds every cached resource ONCE, the
 returned Transform executes many times.  We synthesize a random
 bandlimited function on the Euler grid (iFSOFT), analyze it back
 (FSOFT), verify roundtrip error at paper-Table-1 magnitudes, then plan
-the same transform on the Pallas dense-grid kernel (interpret mode on
-CPU) and check it agrees.
+the same transform on the fused Pallas kernels (interpret mode on CPU)
+and check it agrees.
 """
 import argparse
 import sys
@@ -50,9 +50,9 @@ def main():
     print(f"roundtrip max abs error: {err:.2e}  (paper Table 1: ~1e-14)")
     assert err < 1e-12
 
-    # same transform planned onto the Pallas dense-grid kernel
-    # (interpret mode on CPU; `impl="auto"` would pick the fused schedule)
-    tk = plan(B, impl="dense", V=1, tk=4, tl=min(B, 16), tj=2 * B)
+    # same transform planned onto the fused Pallas kernels (the default
+    # impl; interpret mode on CPU)
+    tk = plan(B, V=1, tk=4)
     back_k = tk.forward(f)
     kerr = np.abs(np.asarray(back_k) - np.asarray(back)).max()
     print(f"pallas DWT kernel vs reference: {kerr:.2e}")
@@ -60,7 +60,7 @@ def main():
 
     # the plan is memoized: a second identical call is free
     t0 = time.time()
-    again = plan(B, impl="dense", V=1, tk=4, tl=min(B, 16), tj=2 * B)
+    again = plan(B, V=1, tk=4)
     assert again is tk
     print(f"plan cache hit in {time.time() - t0 + 1e-6:.6f}s "
           f"(same Transform object, same compiled kernels)")

@@ -15,7 +15,8 @@ built with the plan (:func:`member_sources`).
 The same plan drives
   * the pure-jnp path below (runs anywhere, differentiable),
   * the shard_map-distributed path (:mod:`parallel`) -- shard over k,
-  * the Pallas DWT kernel (:mod:`repro.kernels.dwt`) -- grid over k/l tiles.
+  * the fused Pallas DWT kernels (:mod:`repro.kernels.dwt_fused`) -- grid
+    over k tiles, Wigner rows generated on the fly.
 
 Complex arithmetic is carried as a trailing real/imag axis so the heavy
 contraction is a real matmul (MXU-friendly; complex einsum would promote the
@@ -25,7 +26,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 from functools import partial
 
 import numpy as np
@@ -50,10 +50,9 @@ class SoftPlan:
 
     ``d is None`` marks a STREAMING plan (build_plan(streaming=True)):
     the dense (K, L, J) Wigner table is never materialized -- on the
-    host or anywhere else -- and the recurrence family (fused/onthefly
-    kernels, seeded from ``table.rep``) is the only executor.  The
-    dense-table consumers (reference einsum, dense/ragged kernels,
-    bucketed truncation) reject streaming plans loudly.
+    host or anywhere else -- and the fused kernel family (seeded from
+    ``table.rep``) is the only executor.  The dense-table consumer (the
+    reference einsum) rejects streaming plans loudly.
     """
 
     B: int
@@ -94,8 +93,8 @@ class SoftPlan:
             raise ValueError(
                 f"{consumer} needs the dense (K, L, J) Wigner table, but "
                 f"this B={self.B} plan was built streaming (d=None; the "
-                f"table was never materialized).  Use the recurrence "
-                f"family (impl='fused'/'onthefly') or rebuild with "
+                f"table was never materialized).  Use the fused "
+                f"kernels (impl='fused') or rebuild with "
                 f"build_plan(streaming=False)")
         return self.d
 
@@ -131,7 +130,8 @@ def shard_balanced_order(l_start: np.ndarray, n_shards: int,
     Deal the extent-sorted clusters round-robin (paper-P3's balanced static
     schedule, cf. indexing.balanced_order) and lay shard s's hand out as
     global block s: each hand is itself descending in work, so every
-    local block supports bucketed l-truncation (make_bucketed_dwt_fn).
+    local block supports the fused kernels' per-tile l-truncation
+    (core.parallel.fused_shard_meta).
 
     n_padded: the cluster count AFTER build_plan's pad_to padding.  Pad
     rows are appended at the global end, i.e. they land in the tail of
@@ -313,31 +313,9 @@ def _permute_table(tab, perm):
     return clusters_mod.ClusterTable(**kw)
 
 
-def bucket_boundaries_from_lstart(l_start: np.ndarray, n_shards: int,
-                                  n_buckets: int):
-    """Static (k0, k1, l0) LOCAL bucket slices for the bucketed DWT.
-
-    l_start: (Kp,) per-cluster first valid degree in the (padded, permuted)
-    global order.  Requires shard_balanced_order: every contiguous Kp/n
-    block is extent-sorted, so boundaries computed at LOCAL offsets are
-    valid for every shard simultaneously (l0 = min over shards)."""
-    K = len(l_start)
-    kloc = K // n_shards
-    per_shard = np.asarray(l_start).reshape(n_shards, kloc)
-    bounds = np.linspace(0, kloc, n_buckets + 1).astype(int)
-    out = []
-    for i in range(n_buckets):
-        k0, k1 = int(bounds[i]), int(bounds[i + 1])
-        if k0 == k1:
-            continue
-        l0 = int(per_shard[:, k0:k1].min())
-        out.append((k0, k1, l0))
-    return tuple(out)
-
-
 def plan_lstart(plan: SoftPlan) -> np.ndarray:
     """(Kp,) l-start per cluster.  Padded rows get B-1 (their Wigner blocks
-    are zero, so any l0 is correct; B-1 maximizes bucket truncation)."""
+    are zero, so any l0 is correct; B-1 maximizes tile truncation)."""
     l_start = np.full(plan.n_padded, plan.B - 1, np.int32)
     l_start[: plan.n_clusters] = plan.table.rep[:, 0]
     return l_start
@@ -347,43 +325,9 @@ def shard_lstart(plan: SoftPlan, n_shards: int) -> np.ndarray:
     """(n_shards, kloc) per-shard l-start blocks in the contiguous layout
     shard_map hands each device.  With shard_balanced_order every row is
     descending in work (ascending l-start after the extent sort), which is
-    what the per-local-tile l0 schedules (fused_shard_meta,
-    bucket_boundaries_from_lstart) rely on."""
+    what the per-local-tile l0 schedule (fused_shard_meta) relies on."""
     return plan_lstart(plan).reshape(n_shards, plan.n_padded // n_shards)
 
-
-@functools.lru_cache(maxsize=32)
-def bucket_boundaries(plan: SoftPlan, n_shards: int, n_buckets: int):
-    """Memoized by (plan, n_shards, n_buckets) identity -- every consumer
-    (make_bucketed_dwt_fn, core.parallel, repro.plan) shares one slice
-    table per plan instead of recomputing it per call."""
-    return bucket_boundaries_from_lstart(plan_lstart(plan), n_shards,
-                                         n_buckets)
-
-
-def make_bucketed_dwt_fn(plan: SoftPlan, n_shards: int = 1, n_buckets: int = 8):
-    """dwt_fn with static l-truncation per extent bucket (paper P3 ragged
-    tiling as pure jnp): each bucket contracts only l >= l0 rows, skipping
-    the zero triangle (~2.4x fewer FLOPs and d-table bytes at B = 512)."""
-    plan.require_dense("make_bucketed_dwt_fn")
-    slices = bucket_boundaries(plan, n_shards, n_buckets)
-    kloc = plan.n_padded // n_shards
-
-    def fn(p: SoftPlan, rhs):
-        # operate per shard-block so slices line up (n_shards=1: one block)
-        K, J, C, _ = rhs.shape
-        rhs2 = rhs.reshape(n_shards, kloc, J, C * 2)
-        d3 = p.d.reshape(n_shards, kloc, p.d.shape[1], J)
-        outs = []
-        for (k0, k1, l0) in slices:
-            o = jnp.einsum("sklj,skjc->sklc", d3[:, k0:k1, l0:, :],
-                           rhs2[:, k0:k1], preferred_element_type=p.d.dtype)
-            o = jnp.pad(o, ((0, 0), (0, 0), (l0, 0), (0, 0)))
-            outs.append(o)
-        out = jnp.concatenate(outs, axis=1).reshape(K, -1, C, 2)
-        return out
-
-    return fn
 
 def fft_analysis(f):
     """Samples (2B, 2B, 2B) -> S[mbin, j, m'bin]: (2B)^2 * ifft2."""
@@ -488,8 +432,8 @@ def _gather_rhs(plan: SoftPlan, S):
 def dwt_apply(plan: SoftPlan, rhs):
     """The clustered DWT contraction: (K,L,J) x (K,J,C,2) -> (K,L,C,2).
 
-    Kept as its own function: this is the compute hot-spot the Pallas kernel
-    (kernels/dwt.py) replaces 1:1.
+    Kept as its own function: this is the compute hot-spot the fused
+    Pallas kernels (kernels/dwt_fused.py) replace 1:1.
     """
     d = plan.require_dense("dwt_apply")
     C2 = rhs.shape[2] * rhs.shape[3]
@@ -581,8 +525,8 @@ def _require_recurrence_fn(plan: SoftPlan, fn, which: str):
     if plan.streaming and fn is None:
         raise ValueError(
             f"streaming plan (B={plan.B}, d=None) has no dense Wigner table "
-            f"for the jnp einsum fallback; pass a recurrence-family "
-            f"{which} (kernels.ops.make_{which}(..., impl='fused'/'onthefly'))")
+            f"for the jnp einsum fallback; pass a fused-family "
+            f"{which} (kernels.ops.make_{which})")
 
 
 def forward_clustered(plan: SoftPlan, f, dwt_fn=None):
@@ -591,7 +535,7 @@ def forward_clustered(plan: SoftPlan, f, dwt_fn=None):
 
     Streaming plans route the FFT+gather stage through beta slabs
     (streamed_rhs) -- bitwise-identical output, no monolithic grid
-    intermediate -- and require a recurrence-family dwt_fn."""
+    intermediate -- and require a fused-family dwt_fn."""
     _require_recurrence_fn(plan, dwt_fn, "dwt_fn")
     if dwt_fn is None:
         return _forward_jit(plan, f)

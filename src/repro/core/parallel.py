@@ -86,7 +86,7 @@ from .batched import SoftPlan, fft_analysis, fft_synthesis, place_coeffs
 __all__ = [
     "DistExecutor", "dist_executor", "check_mesh_compat",
     "distributed_forward", "distributed_inverse",
-    "LocalDWT", "ShardMeta", "fused_shard_meta", "make_bucketed_local_dwt",
+    "LocalDWT", "ShardMeta", "fused_shard_meta",
     "make_fused_local_dwt", "make_fused_local_idwt", "packed_to_dense",
     "dense_to_packed", "packed_to_dense_batch", "dense_to_packed_batch",
     "OVERLAP_MODES", "pipeline_steps", "pipeline_slots",
@@ -211,22 +211,6 @@ def _normalize_local_dwt(plan, local_dwt, einsum_spec):
                     (True,), local_dwt)
 
 
-def make_bucketed_local_dwt(slices, B):
-    """Local DWT with static l-truncation per extent bucket (paper-P3
-    ragged tiling; see core.batched.bucket_boundaries_from_lstart).
-    `slices`: [(k0, k1, l0)] local-index bucket boundaries."""
-
-    def fn(d, rhs2):
-        outs = []
-        for (k0, k1, l0) in slices:
-            o = jnp.einsum("klj,kjc->klc", d[k0:k1, l0:, :], rhs2[k0:k1],
-                           preferred_element_type=d.dtype)
-            outs.append(jnp.pad(o, ((0, 0), (l0, 0), (0, 0))))
-        return jnp.concatenate(outs, axis=0)
-
-    return fn
-
-
 @dataclasses.dataclass(frozen=True, eq=False)
 class ShardMeta:
     """Shard metadata of one (plan, n_shards) pairing, computed ONCE and
@@ -246,8 +230,8 @@ class ShardMeta:
 @functools.lru_cache(maxsize=16)
 def fused_shard_meta(plan: SoftPlan, n_shards: int,
                      tk: int | None = None) -> ShardMeta:
-    """Seeds/orders plus per-local-tile l0s valid for EVERY shard (min over
-    shards at each local offset, cf. bucket_boundaries_from_lstart).
+    """Seeds/orders plus per-local-tile l0s valid for EVERY shard (the
+    min over shards at each local offset: core.batched.shard_lstart).
 
     Memoized by (plan, n_shards, tk) identity -- plans themselves are
     memoized by build_plan, so a planner (repro.plan) and both transform

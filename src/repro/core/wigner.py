@@ -189,8 +189,8 @@ def wigner_d_fundamental(B: int, beta: np.ndarray | None = None,
     Returns (table, pairs).  Row p holds d(l, m_p, m'_p; beta_j) for
     l = 0..B-1 with zeros for l < m_p.  Built by running the three-term
     recurrence for all P pairs simultaneously (vectorized over (P, J)),
-    which is exactly the computation the on-the-fly Pallas kernel fuses
-    into the DWT (kernels/wigner_rec.py).
+    which is exactly the computation the fused Pallas kernels run on the
+    fly inside the DWT (kernels/dwt_fused.py).
 
     Calls on the default quadrature grid (beta=None) are memoized by
     (B, dtype); the cached arrays are marked read-only -- copy before

@@ -1,10 +1,10 @@
-"""Measured tile autotuner for the clustered-DWT kernel schedules.
+"""Measured tile autotuner for the fused clustered-DWT kernel family.
 
 OpenFFT's lesson (arXiv:1501.07350): an exhaustive-but-cheap measured sweep
 over decompositions is what turns a parallel transform design into actual
 speedup.  This module times real kernel launches for a small candidate set
-of (tk, tl, tj, V) tilings and memoizes the winner on disk keyed by
-(B, dtype, backend, impl, V, vmem_limit, n_shards, overlap, lchunk,
+of (tk, V) tilings and memoizes the winner on disk keyed by
+(B, dtype, backend, V, vmem_limit, n_shards, overlap, lchunk,
 precision) -- one sweep
 per machine/shape/mesh-decomposition, then every subsequent make_dwt_fn
 call reads the cache.  n_shards > 1 tunes the per-device cluster shard
@@ -16,14 +16,13 @@ mesh plan's batch executors run (measured on the real mesh, or the
 static n_shards > 1 heuristic).
 
     from repro.kernels import autotune
-    cfg = autotune.autotune_dwt(plan, impl="fused")      # {'tk': ..., ...}
-    dwt_fn = autotune.tuned_dwt_fn(plan, impl="fused")   # ready to use
+    cfg = autotune.autotune_dwt(plan)       # {'tk': ..., 'V': ..., ...}
+    dwt_fn = autotune.tuned_dwt_fn(plan)    # ready to use
 
 Cache location: $REPRO_AUTOTUNE_CACHE, else ~/.cache/repro/autotune.json.
 Delete the file (or pass refresh=True) to re-measure after a toolchain or
-hardware change.  Candidate tiles respect the kernel divisibility
-constraints (tk | K, tl | L, tj | J); V candidates pack V transforms onto
-the lane axis and are scored by *per-transform* time.
+hardware change.  Candidate cluster tiles divide K; V candidates pack V
+transforms onto the lane axis and are scored by *per-transform* time.
 """
 from __future__ import annotations
 
@@ -119,21 +118,18 @@ def vmem_limit_bytes() -> int:
     return int(os.environ.get("REPRO_VMEM_BYTES", _DEF_VMEM))
 
 
-def estimate_vmem_bytes(impl: str, *, L: int, J: int, C2: int, tk: int,
-                        tl: int | None = None, tj: int | None = None,
+def estimate_vmem_bytes(*, L: int, J: int, C2: int, tk: int,
                         itemsize: int = 4, lchunk: int | None = None,
                         precision: str = "fp32",
                         panel: int | None = None,
                         limit: int | None = None) -> int:
-    """Static VMEM footprint of one grid step of a candidate tiling.
+    """Static VMEM footprint of one grid step of a fused-family tiling.
 
-    Recurrence schedules (onthefly/fused) hold seeds + the two recurrence
-    state rows (3 * TK * J), the order/cos-beta vectors, the rhs tile
-    (TK * J * C2) and the coefficient tile; C2 = V*C*2 grows linearly
-    with lane packing, which is what caps V.  The fused family adds its
-    (TK, P, J) Wigner panel: ``panel`` rows if given, else the depth
-    :func:`panel_depth` derives under ``limit``.  Grid schedules
-    (dense/ragged) hold a (TK, TL, TJ) d-block plus rhs/out tiles.
+    A grid step holds seeds + the two recurrence state rows (3 * TK * J),
+    the order/cos-beta vectors, the rhs tile (TK * J * C2), the
+    coefficient tile and the (TK, P, J) Wigner panel: ``panel`` rows if
+    given, else the depth :func:`panel_depth` derives under ``limit``.
+    C2 = V*C*2 grows linearly with lane packing, which is what caps V.
 
     itemsize must be the PLAN dtype's (f64 plans really do hold 8-byte
     tiles; assuming fp32 under-guards them 2x).  An l-chunked streaming
@@ -142,22 +138,16 @@ def estimate_vmem_bytes(impl: str, *, L: int, J: int, C2: int, tk: int,
     exists to cut -- and adds the staged 2 * TK * J window block, stored
     at 2 bytes under precision="bf16".
     """
-    if impl in ("onthefly", "fused"):
-        lt = L if lchunk is None else lchunk
-        extra = 0
-        if lchunk is not None:                          # window block
-            extra += (2 if precision == "bf16" else itemsize) * 2 * tk * J
-        if impl == "fused":
-            if panel is None:
-                panel = panel_depth(L=L, J=J, C2=C2, tk=tk,
-                                    itemsize=itemsize, lchunk=lchunk,
-                                    limit=limit)
-            extra += itemsize * tk * panel * J
-        return (itemsize * (3 * tk * J + 2 * tk + J + tk * J * C2
-                            + tk * lt * C2) + extra)
-    tl = L if tl is None else tl
-    tj = J if tj is None else tj
-    return itemsize * (tk * tl * tj + tk * tj * C2 + tk * tl * C2)
+    lt = L if lchunk is None else lchunk
+    extra = 0
+    if lchunk is not None:                              # window block
+        extra += (2 if precision == "bf16" else itemsize) * 2 * tk * J
+    if panel is None:
+        panel = panel_depth(L=L, J=J, C2=C2, tk=tk, itemsize=itemsize,
+                            lchunk=lchunk, limit=limit)
+    extra += itemsize * tk * panel * J
+    return (itemsize * (3 * tk * J + 2 * tk + J + tk * J * C2
+                        + tk * lt * C2) + extra)
 
 
 def panel_depth(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
@@ -175,8 +165,8 @@ def panel_depth(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
     cands = [d for d in range(L, 0, -1) if L % d == 0
              and (d % 8 == 0 or d == L)]
     for p in cands:
-        if estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
-                               itemsize=itemsize, panel=p) <= limit:
+        if estimate_vmem_bytes(L=L, J=J, C2=C2, tk=tk, itemsize=itemsize,
+                               panel=p) <= limit:
             return p
     return cands[-1]
 
@@ -190,28 +180,23 @@ def estimate_live_coeff_bytes(*, tk: int, L: int, C2: int, itemsize: int = 4,
     return tk * (L if lchunk is None else lchunk) * C2 * itemsize
 
 
-def estimate_hbm_bytes(impl: str, *, B: int, K: int, L: int, J: int,
-                       C2: int, itemsize: int = 4,
-                       lchunk: int | None = None,
+def estimate_hbm_bytes(*, B: int, K: int, L: int, J: int, C2: int,
+                       itemsize: int = 4, lchunk: int | None = None,
                        precision: str = "fp32") -> int:
     """Estimated peak HBM residency of one transform at bandwidth B.
 
     Counts the (2B)^3 complex grid (the paper's second memory cliff), the
     (K, L, C2) coefficient stack and (K, J, C2) beta-grid stack, and the
-    schedule's Wigner working set: the dense/ragged families stream a
-    (K, L, J) table, the recurrence family only seeds (K, J) plus -- for
+    fused family's Wigner working set: seeds (K, J) plus -- for
     streaming schedules -- the (nL, 2, K, J) chunk-boundary window table
     (2-byte elements under precision="bf16").  Diagnostic, not an
     allocator: use it to see WHICH term goes over before launching."""
     grid = 2 * (2 * B) ** 3 * itemsize            # complex samples (re+im)
     stacks = (K * L * C2 + K * J * C2) * itemsize
-    if impl in ("onthefly", "fused"):
-        tables = K * J * itemsize                 # seed rows
-        if lchunk is not None:
-            sb = 2 if precision == "bf16" else itemsize
-            tables += (L // lchunk) * 2 * K * J * sb
-    else:
-        tables = K * L * J * itemsize             # dense Wigner table
+    tables = K * J * itemsize                     # seed rows
+    if lchunk is not None:
+        sb = 2 if precision == "bf16" else itemsize
+        tables += (L // lchunk) * 2 * K * J * sb
     return grid + stacks + tables
 
 
@@ -275,7 +260,7 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
     limit = vmem_limit_bytes() if limit is None else limit
 
     def est(lc):
-        return estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
+        return estimate_vmem_bytes(L=L, J=J, C2=C2, tk=tk,
                                    itemsize=itemsize, lchunk=lc,
                                    precision=precision, limit=limit)
 
@@ -318,31 +303,22 @@ def _store_cache(path: pathlib.Path, entries: dict) -> None:
     tmp.replace(path)
 
 
-def _divisors_leq(n: int, cands, fallback: int = 1) -> list[int]:
-    out = [c for c in cands if c <= n and n % c == 0]
-    return out or [fallback]
+def _divisors_leq(n: int, cands) -> list[int]:
+    return [c for c in cands if c <= n and n % c == 0] or [1]
 
 
-def candidate_tiles(K: int, L: int, J: int, impl: str) -> list[dict]:
-    """Small exhaustive candidate set per schedule family.
-
-    Recurrence schedules (onthefly/fused) only tile the cluster axis; the
-    grid schedules (dense/ragged) tile all three.
-    """
+def candidate_tiles(K: int) -> list[int]:
+    """Small exhaustive set of cluster tiles tk dividing K (the fused
+    kernels tile only the cluster axis)."""
     tks = _divisors_leq(K, (4, 8, 16, 32))
     if tks == [1]:
         # no primary tile divides K (common for per-device cluster shards
         # of a mesh plan): fall back to the smaller divisors
         tks = _divisors_leq(K, (2, 3, 6))
-    if impl in ("onthefly", "fused"):
-        return [{"tk": tk, "tl": L, "tj": J} for tk in tks]
-    tls = _divisors_leq(L, (8, 16, 32, 64, 128), fallback=L)
-    tjs = _divisors_leq(J, (32, 64, 128, 256, 512), fallback=J)
-    return [{"tk": tk, "tl": tl, "tj": tj}
-            for tk in tks for tl in tls for tj in tjs]
+    return tks
 
 
-def _key(plan, impl: str, V, limit: int, n_shards: int = 1,
+def _key(plan, kind: str, V, limit: int, n_shards: int = 1,
          overlap: str = "off", lchunk: int | None = None,
          precision: str = "fp32") -> str:
     # the VMEM ceiling is part of the key: a winner measured under a
@@ -357,8 +333,9 @@ def _key(plan, impl: str, V, limit: int, n_shards: int = 1,
     # (0 = monolithic) and /P{prec} key the streaming l-chunk and the
     # storage precision: a bf16 or chunked schedule runs a different
     # kernel, so its measurements must never be served to -- or poisoned
-    # by -- the monolithic fp32 schedule of the same shape.
-    return (f"{impl}/B{plan.B}/K{plan.n_padded}/{jnp.dtype(plan.dtype).name}"
+    # by -- the monolithic fp32 schedule of the same shape.  ``kind`` is
+    # "fused" for tile sweeps and "overlap" for the mode timings.
+    return (f"{kind}/B{plan.B}/K{plan.n_padded}/{jnp.dtype(plan.dtype).name}"
             f"/{jax.default_backend()}/V{V}/M{limit}/S{n_shards}/O{overlap}"
             f"/L{lchunk or 0}/P{precision}")
 
@@ -384,14 +361,13 @@ def _local_shard_timer(plan, tk: int, n_shards: int, interpret):
     return fn
 
 
-def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
-                 refresh: bool = False, cache: str | os.PathLike | None = None,
-                 interpret=None, vmem_limit: int | None = None,
-                 n_shards: int = 1, lchunk: int | None = None,
-                 precision: str = "fp32") -> dict:
-    """Measure-and-cache the best (tk, tl, tj, V) for one schedule.
+def autotune_dwt(plan, *, Vs=(1,), reps: int = 3, refresh: bool = False,
+                 cache: str | os.PathLike | None = None, interpret=None,
+                 vmem_limit: int | None = None, n_shards: int = 1,
+                 lchunk: int | None = None, precision: str = "fp32") -> dict:
+    """Measure-and-cache the best (tk, V) for one fused-family schedule.
 
-    Returns {"tk", "tl", "tj", "V", "per_transform_s"}.  Sweeps the
+    Returns {"tk", "V", "per_transform_s"}.  Sweeps the
     candidate tilings for every V in Vs (V > 1 packs V transforms onto the
     kernel lane axis; scored per transform so wider packing must EARN its
     place by amortizing launch + Wigner-generation cost).
@@ -403,8 +379,6 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
     l0s schedule).  Winners are cached under a mesh-shape-specific key,
     so every mesh shape earns its own sweep (the OpenFFT lesson:
     decomposition-shape-specific tuning is where the speedup lives).
-    Only the recurrence family runs on-device in the sharded paths, so
-    n_shards > 1 requires impl in ("onthefly", "fused").
 
     Candidates whose static per-grid-step footprint exceeds the VMEM
     ceiling (vmem_limit, default :func:`vmem_limit_bytes`) are skipped
@@ -412,10 +386,6 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
     otherwise fail at compile time on hardware instead of gracefully
     losing the sweep.
     """
-    if n_shards > 1 and impl not in ("onthefly", "fused"):
-        raise ValueError(
-            f"per-mesh autotuning times the fused device-local kernel; "
-            f"impl must be 'onthefly' or 'fused', got {impl!r}")
     if (lchunk is not None or precision == "bf16") and n_shards > 1:
         raise ValueError(
             "streaming schedules (lchunk/bf16) are not wired into the "
@@ -425,7 +395,7 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
     path = pathlib.Path(cache) if cache is not None else cache_path()
     store = _load_cache(path)
     limit = vmem_limit_bytes() if vmem_limit is None else vmem_limit
-    key = _key(plan, impl, tuple(Vs) if len(Vs) > 1 else Vs[0], limit,
+    key = _key(plan, "fused", tuple(Vs) if len(Vs) > 1 else Vs[0], limit,
                n_shards, lchunk=lchunk, precision=precision)
     if not refresh and key in store:
         obs.inc("autotune.cache.hit")
@@ -439,7 +409,7 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
     rng = np.random.default_rng(0)
     best = None
     n_skipped = 0
-    sweep = obs.get_recorder().span("autotune.sweep", key=key, impl=impl,
+    sweep = obs.get_recorder().span("autotune.sweep", key=key,
                                     n_shards=n_shards)
     with sweep:
         for V in Vs:
@@ -449,33 +419,33 @@ def autotune_dwt(plan, impl: str = "fused", *, Vs=(1,), reps: int = 3,
             else:
                 shape = (K, J, C, 2) if V == 1 else (V, K, J, C, 2)
                 rhs = jnp.asarray(rng.normal(size=shape), plan.dtype)
-            for tile in candidate_tiles(K_eff, L, J, impl):
-                if estimate_vmem_bytes(impl, L=L, J=J, C2=V * C * 2,
+            for tk in candidate_tiles(K_eff):
+                if estimate_vmem_bytes(L=L, J=J, C2=V * C * 2, tk=tk,
                                        itemsize=itemsize, lchunk=lchunk,
-                                       precision=precision, limit=limit,
-                                       **tile) > limit:
+                                       precision=precision,
+                                       limit=limit) > limit:
                     n_skipped += 1
                     continue
                 try:
                     if n_shards > 1:
-                        run = _local_shard_timer(plan, tile["tk"], n_shards,
+                        run = _local_shard_timer(plan, tk, n_shards,
                                                  interpret)
                     else:
-                        fn = ops.make_dwt_fn(plan, impl, interpret=interpret,
+                        fn = ops.make_dwt_fn(plan, tk=tk, interpret=interpret,
                                              batch=None if V == 1 else V,
                                              lchunk=lchunk,
                                              precision=precision,
-                                             vmem_limit=limit, **tile)
+                                             vmem_limit=limit)
                         run = lambda r: fn(plan, r)   # noqa: E731
                     # per-candidate timing lands in the Recorder: every
                     # sweep leaves an auditable record, not just a winner
                     t = obs.time_fn(run, rhs, reps=reps,
                                     name="autotune.candidate", key=key,
-                                    V=V, **tile) / V
+                                    V=V, tk=tk) / V
                 except Exception:   # tiling rejected by the kernel -> skip
                     continue
                 if best is None or t < best["per_transform_s"]:
-                    best = dict(tile, V=V, per_transform_s=t)
+                    best = dict(tk=tk, V=V, per_transform_s=t)
     if best is None:
         raise RuntimeError(
             f"no viable tiling for {key}"
@@ -562,27 +532,25 @@ def autotune_overlap(plan, mesh, axis, *, V: int = 1, tk: int | None = None,
     return min(results.values(), key=lambda r: r["per_transform_s"])
 
 
-def tuned_dwt_fn(plan, impl: str = "fused", *, Vs=(1,), interpret=None,
+def tuned_dwt_fn(plan, *, Vs=(1,), interpret=None,
                  lchunk: int | None = None, precision: str = "fp32",
                  **tune_kw):
     """make_dwt_fn with autotuned tiles (sweeps + caches on first call)."""
-    cfg = autotune_dwt(plan, impl, Vs=Vs, interpret=interpret,
-                       lchunk=lchunk, precision=precision, **tune_kw)
+    cfg = autotune_dwt(plan, Vs=Vs, interpret=interpret, lchunk=lchunk,
+                       precision=precision, **tune_kw)
     V = cfg["V"]
-    return ops.make_dwt_fn(plan, impl, tk=cfg["tk"], tl=cfg["tl"],
-                           tj=cfg["tj"], batch=None if V == 1 else V,
+    return ops.make_dwt_fn(plan, tk=cfg["tk"], batch=None if V == 1 else V,
                            lchunk=lchunk, precision=precision,
                            interpret=interpret)
 
 
-def tuned_idwt_fn(plan, impl: str = "fused", *, Vs=(1,), interpret=None,
+def tuned_idwt_fn(plan, *, Vs=(1,), interpret=None,
                   lchunk: int | None = None, precision: str = "fp32",
                   **tune_kw):
     """make_idwt_fn sharing the forward sweep's tiling (same data layout)."""
-    cfg = autotune_dwt(plan, impl, Vs=Vs, interpret=interpret,
-                       lchunk=lchunk, precision=precision, **tune_kw)
+    cfg = autotune_dwt(plan, Vs=Vs, interpret=interpret, lchunk=lchunk,
+                       precision=precision, **tune_kw)
     V = cfg["V"]
-    return ops.make_idwt_fn(plan, impl, tk=cfg["tk"], tl=cfg["tl"],
-                            tj=cfg["tj"], batch=None if V == 1 else V,
+    return ops.make_idwt_fn(plan, tk=cfg["tk"], batch=None if V == 1 else V,
                             lchunk=lchunk, precision=precision,
                             interpret=interpret)

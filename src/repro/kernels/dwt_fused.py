@@ -1,21 +1,18 @@
-"""Pallas TPU kernel: FUSED ragged + on-the-fly clustered DWT.
+"""Pallas TPU kernel: the FUSED clustered DWT/iDWT (ragged l-range +
+on-the-fly Wigner rows), the one kernel family of this package.
 
-The two big levers of the paper's DWT stage lived in separate kernels:
+It joins the two levers of the paper's DWT stage, plus multi-transform
+lane batching:
 
-  * dwt.py (ragged)        -- skip the l < max(|m|,|m'|) zero-triangle via a
-    host-enumerated work list (paper point P3), but reads the precomputed
-    Wigner-d table from HBM (~0.37 TB at B = 512 in f64);
-  * wigner_rec.py          -- generate the d-rows on the fly from the
-    three-term recurrence (paper Eq. 2) so the table never touches HBM,
-    but marches l from 0 and therefore still *executes* the zero-triangle.
-
-This kernel family gets both at once, plus multi-transform lane batching:
-
+  * the Wigner-d rows are generated on the fly by the three-term
+    recurrence (paper Eq. 2), so the table never touches HBM (~0.37 TB
+    at B = 512 in f64, what pinned the paper's benchmark to a 128 GB
+    node);
   * clusters are host-sorted by ascending l-start (= m from the kappa
-    fold) and tiled TK at a time, exactly like the ragged schedule;
-  * a scalar-prefetch array l0s[g] carries each tile's first valid degree,
-    and the in-kernel recurrence loop runs l = l0s[g] .. L-1 -- the
-    zero-triangle is neither stored nor executed;
+    fold) and tiled TK at a time, and a scalar-prefetch array l0s[g]
+    carries each tile's first valid degree, so the in-kernel recurrence
+    loop runs l = l0s[g] .. L-1: the l < max(|m|, |m'|) zero-triangle
+    (paper point P3) is neither stored nor executed;
   * seeds + (d_prev, d_cur) recurrence state live in VMEM; HBM traffic is
     seeds (K*J) + rhs (K*J*C2) + out (K*L*C2) with NO d-table term;
   * the contraction lane axis C2 is V*C*2 for V simultaneous transforms
@@ -37,10 +34,9 @@ the VMEM estimate allows, else the largest multiple of 8 dividing L that
 fits (:func:`repro.kernels.autotune.panel_depth`); the streaming kernels
 (streaming.py) run the same helpers with P = lchunk.
 
-Work accounting (what benchmarks/dwt_schedules.py reports):
-
-    row-steps(onthefly) = (K/TK) * L
-    row-steps(fused)    = sum_g (L - l0s[g])   (~2.4x fewer at B = 512)
+Work accounting: a grid step of tile g marches L - l0s[g] rows, so a
+launch runs sum_g (L - l0s[g]) row-steps where a full-range march would
+run (K/TK) * L (~2.4x more at B = 512).
 
 VMEM per grid step (f32, TK=8, B=512): seeds/prev/cur 3*TK*J = 96 KB,
 rhs TK*J*C2 = 512 KB (V=1), out TK*L*C2 = 256 KB, panel TK*P*J = 4 MB
@@ -58,7 +54,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import I0, resolve_interpret
-from .wigner_rec import _recurrence_step
 
 __all__ = ["build_tile_lstarts", "dwt_fused", "idwt_fused"]
 
@@ -76,6 +71,32 @@ def build_tile_lstarts(l_start: np.ndarray, tk: int) -> np.ndarray:
     if K % tk:
         raise ValueError(f"K={K} not divisible by tk={tk}")
     return np.asarray(l_start, np.int32).reshape(K // tk, tk).min(axis=1)
+
+
+def _recurrence_step(l, m, mp, cb, d_prev, d_cur, seeds):
+    """One l-step of the three-term recurrence, shared by the fused and
+    streaming kernels.  Returns (row_l, d_prev', d_cur').
+
+    row_l is the valid (zero-masked below l = m) Wigner-d row for degree l.
+    """
+    lf = l.astype(d_cur.dtype)
+    d_cur = jnp.where(m == lf, seeds, d_cur)
+    active = m <= lf
+    row = jnp.where(active, d_cur, 0.0)
+
+    lp1 = lf + 1.0
+    den = jax.lax.rsqrt(jnp.maximum((lp1**2 - m**2) * (lp1**2 - mp**2), 1.0))
+    A = lp1 * (2.0 * lf + 1.0) * den
+    safe_l = jnp.maximum(lf, 1.0)
+    mu = jnp.where(lf > 0, m * mp / (safe_l * lp1), 0.0)
+    C = jnp.where(lf > 0,
+                  lp1 * jnp.sqrt(jnp.maximum((lf**2 - m**2) * (lf**2 - mp**2),
+                                             0.0)) * den / safe_l,
+                  0.0)
+    d_next = A * (cb - mu) * d_cur - C * d_prev
+    d_prev_new = jnp.where(active, d_cur, 0.0)
+    d_cur_new = jnp.where(active, d_next, 0.0)
+    return row, d_prev_new, d_cur_new
 
 
 def march(l, m, mp, cb, seeds, prev_ref, cur_ref):

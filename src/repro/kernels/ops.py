@@ -2,10 +2,10 @@
 
   * :func:`make_dwt_fn` / :func:`make_idwt_fn` -- drop-in replacements for
     core.batched.dwt_apply / idwt_apply (plug into forward_clustered /
-    inverse_clustered via the dwt_fn argument).  Implementations:
-      "dense"    -- kernels/dwt.py dense grid
-      "ragged"   -- kernels/dwt.py work-list grid (paper P3 schedule)
-      "onthefly" -- kernels/wigner_rec.py fused recurrence (no d-table HBM)
+    inverse_clustered via the dwt_fn argument).  They bind the fused
+    kernel family: the monolithic kernels of dwt_fused.py, or their
+    streaming members (streaming.py) when ``lchunk`` is set or
+    ``precision="bf16"``.
   * :func:`attention` -- folded causal flash attention with automatic
     interpret-mode selection (CPU validates, TPU compiles).
 
@@ -24,11 +24,9 @@ import jax.numpy as jnp
 from repro.core import quadrature, wigner
 from repro.core.batched import SoftPlan
 
-from . import dwt as dwt_kernels
 from . import dwt_fused
 from . import folded_attention as fa
 from . import streaming
-from . import wigner_rec
 from .runtime import default_interpret
 
 __all__ = ["default_interpret", "make_dwt_fn", "make_idwt_fn",
@@ -74,20 +72,6 @@ def pad_lanes(x, V):
         x = jnp.concatenate(
             [x, jnp.zeros((V - n,) + x.shape[1:], x.dtype)])
     return x, n
-
-
-def _ragged_metadata(plan: SoftPlan, tk: int, tl: int):
-    """Host-side: sort clusters by l-start so tiles bucket uniform work
-    (integer-only bookkeeping, DESIGN.md P3), then enumerate blocks."""
-    l_start = np.zeros(plan.n_padded, np.int32)
-    l_start[: plan.n_clusters] = plan.table.rep[:, 0]
-    # padded clusters have zero Wigner blocks; give them full "extent" so
-    # they sort to the front together -- they cost nothing extra since the
-    # kernel output is masked anyway. Sort ascending l_start.
-    perm = np.argsort(l_start, kind="stable").astype(np.int32)
-    kk, ll, n_dense = dwt_kernels.build_work_list(l_start[perm], tk, tl,
-                                                  plan.B)
-    return perm, l_start, kk, ll, n_dense
 
 
 @functools.lru_cache(maxsize=16)
@@ -212,167 +196,79 @@ def _wrap_batch(raw, batch):
     return fn
 
 
-def _check_streaming_args(impl, lchunk, precision):
+def _check_streaming_args(lchunk, precision):
     """lchunk/precision select the streaming members of the fused family;
-    reject them loudly on the schedules that have no streaming twin."""
+    returns whether they do."""
     if precision not in (None, "fp32", "bf16"):
         raise ValueError(f"precision must be 'fp32' or 'bf16', "
                          f"got {precision!r}")
-    streaming_on = lchunk is not None or precision == "bf16"
-    if streaming_on and impl != "fused":
-        raise ValueError(
-            f"lchunk/precision='bf16' need the streaming kernels, which "
-            f"exist only for impl='fused' (got impl={impl!r})")
-    return streaming_on
+    return lchunk is not None or precision == "bf16"
 
 
-def make_dwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
-                lchunk=None, precision=None, vmem_limit=None,
-                interpret=None, batch=None):
-    """Build a dwt_fn(plan, rhs) for core.batched.forward_clustered.
+def _make_fn(plan: SoftPlan, *, inverse, tk, lchunk, precision, vmem_limit,
+             interpret, batch):
+    interpret = default_interpret() if interpret is None else interpret
+    tk = min(tk, plan.n_padded)
+    perm, _, l0s = fused_metadata(plan, tk)
+    if _check_streaming_args(lchunk, precision):
+        prec = precision or "fp32"
+        lchunk = streaming.check_lchunk(
+            plan.B, plan.B if lchunk is None else lchunk,
+            tiled=not interpret)
+        seeds_p, m_p, mp_p, cb, l0s, windows = streaming_inputs(
+            plan, tk, lchunk, prec)
+        kernel = streaming.idwt_streaming if inverse \
+            else streaming.dwt_streaming
 
-    impl: "dense" | "ragged" | "onthefly" | "fused".  batch=V makes the fn
-    accept a (V, K, J, C, 2) stack of RHS (core.batched.
-    forward_clustered_batch) contracted in ONE kernel launch with V*C*2
-    lanes.  lchunk (fused only) selects the l-chunked streaming kernel
-    (kernels/streaming.py): HBM-resident coefficients staged as
-    (tk, lchunk, C2) VMEM tiles, recurrence re-seeded per chunk from a
-    two-row window.  precision (fused only): "fp32" (default; compute in
-    the plan dtype) or "bf16" (bf16 recurrence state / d-rows, plan-dtype
-    accumulation; forces the streaming kernel, monolithic has no
-    mixed-precision twin).  vmem_limit (fused only; default
+        def launch(p: SoftPlan, x2):
+            return kernel(seeds_p, m_p, mp_p, cb, x2, l0s, windows, B=p.B,
+                          tk=tk, lchunk=lchunk, precision=prec,
+                          interpret=interpret)
+    else:
+        seeds, m, mp, cb = onthefly_inputs(plan)
+        seeds_p, m_p, mp_p = seeds[perm], m[perm], mp[perm]
+        kernel = dwt_fused.idwt_fused if inverse else dwt_fused.dwt_fused
+
+        def launch(p: SoftPlan, x2):
+            return kernel(seeds_p, m_p, mp_p, cb, x2, l0s, B=p.B, tk=tk,
+                          vmem_limit=vmem_limit, interpret=interpret)
+    inv_perm = np.argsort(perm)
+
+    def raw(p: SoftPlan, x2):
+        return launch(p, x2[perm])[inv_perm]
+    return _wrap_batch(raw, batch)
+
+
+def make_dwt_fn(plan: SoftPlan, *, tk=8, lchunk=None, precision=None,
+                vmem_limit=None, interpret=None, batch=None):
+    """Build a dwt_fn(plan, rhs) for core.batched.forward_clustered on the
+    fused kernel family.
+
+    batch=V makes the fn accept a (V, K, J, C, 2) stack of RHS
+    (core.batched.forward_clustered_batch) contracted in ONE kernel launch
+    with V*C*2 lanes.  lchunk selects the l-chunked streaming kernel
+    (kernels/streaming.py): HBM-resident coefficients staged as (tk,
+    lchunk, C2) VMEM tiles, recurrence re-seeded per chunk from a two-row
+    window.  precision: "fp32" (default; compute in the plan dtype) or
+    "bf16" (bf16 recurrence windows / d-rows, plan-dtype accumulation;
+    forces the streaming kernel, the monolithic one has no
+    mixed-precision twin).  vmem_limit (default
     :func:`repro.kernels.autotune.vmem_limit_bytes`) is the budget the
     monolithic kernel derives its Wigner panel depth against.
     """
-    interpret = default_interpret() if interpret is None else interpret
-    if _check_streaming_args(impl, lchunk, precision):
-        prec = precision or "fp32"
-        lchunk = streaming.check_lchunk(
-            plan.B, plan.B if lchunk is None else lchunk,
-            tiled=not interpret)
-        tk = min(tk, plan.n_padded)
-        seeds_p, m_p, mp_p, cb, l0s, windows = streaming_inputs(
-            plan, tk, lchunk, prec)
-        perm, _, _ = fused_metadata(plan, tk)
-        inv_perm = np.argsort(perm)
-
-        def raw(p: SoftPlan, rhs2):
-            out = streaming.dwt_streaming(seeds_p, m_p, mp_p, cb,
-                                          rhs2[perm], l0s, windows, B=p.B,
-                                          tk=tk, lchunk=lchunk,
-                                          precision=prec,
-                                          interpret=interpret)
-            return out[inv_perm]
-        return _wrap_batch(raw, batch)
-    if impl == "dense":
-        plan.require_dense("make_dwt_fn(impl='dense')")
-
-        def raw(p: SoftPlan, rhs2):
-            return dwt_kernels.dwt_dense(p.d, rhs2, tk=tk, tl=tl, tj=tj,
-                                         interpret=interpret)
-        return _wrap_batch(raw, batch)
-
-    if impl == "ragged":
-        plan.require_dense("make_dwt_fn(impl='ragged')")
-        perm, l_start, kk, ll, _ = _ragged_metadata(plan, tk, tl)
-        inv_perm = np.argsort(perm)
-        l_grid = np.arange(plan.B)
-        mask = jnp.asarray((l_grid[None, :] >= l_start[:, None]))  # (K, L)
-
-        def raw(p: SoftPlan, rhs2):
-            out = dwt_kernels.dwt_ragged(p.d[perm], rhs2[perm], kk, ll,
-                                         tk=tk, tl=tl, tj=tj,
-                                         interpret=interpret)
-            out = out[inv_perm]
-            return jnp.where(mask[:, :, None], out, 0.0)
-        return _wrap_batch(raw, batch)
-
-    if impl == "onthefly":
-        seeds, m, mp, cb = onthefly_inputs(plan)
-
-        def raw(p: SoftPlan, rhs2):
-            return wigner_rec.dwt_onthefly(seeds, m, mp, cb, rhs2, B=p.B,
-                                           tk=tk, interpret=interpret)
-        return _wrap_batch(raw, batch)
-
-    if impl == "fused":
-        seeds, m, mp, cb = onthefly_inputs(plan)
-        perm, _, l0s = fused_metadata(plan, min(tk, plan.n_padded))
-        inv_perm = np.argsort(perm)
-        seeds_p, m_p, mp_p = seeds[perm], m[perm], mp[perm]
-
-        def raw(p: SoftPlan, rhs2):
-            out = dwt_fused.dwt_fused(seeds_p, m_p, mp_p, cb, rhs2[perm],
-                                      l0s, B=p.B, tk=tk,
-                                      vmem_limit=vmem_limit,
-                                      interpret=interpret)
-            return out[inv_perm]
-        return _wrap_batch(raw, batch)
-
-    raise ValueError(impl)
+    return _make_fn(plan, inverse=False, tk=tk, lchunk=lchunk,
+                    precision=precision, vmem_limit=vmem_limit,
+                    interpret=interpret, batch=batch)
 
 
-def make_idwt_fn(plan: SoftPlan, impl="dense", *, tk=8, tl=128, tj=512,
-                 lchunk=None, precision=None, vmem_limit=None,
-                 interpret=None, batch=None):
-    """Build an idwt_fn(plan, lhs) for core.batched.inverse_clustered.
-
-    impl: "dense" | "onthefly" | "fused"; batch as in make_dwt_fn (lhs
-    gains a leading V axis, packed onto lanes for one launch); lchunk /
-    precision select the streaming inverse and vmem_limit the panel
-    budget (fused only, see make_dwt_fn).
-    """
-    interpret = default_interpret() if interpret is None else interpret
-    if _check_streaming_args(impl, lchunk, precision):
-        prec = precision or "fp32"
-        lchunk = streaming.check_lchunk(
-            plan.B, plan.B if lchunk is None else lchunk,
-            tiled=not interpret)
-        tk = min(tk, plan.n_padded)
-        seeds_p, m_p, mp_p, cb, l0s, windows = streaming_inputs(
-            plan, tk, lchunk, prec)
-        perm, _, _ = fused_metadata(plan, tk)
-        inv_perm = np.argsort(perm)
-
-        def raw(p: SoftPlan, lhs2):
-            out = streaming.idwt_streaming(seeds_p, m_p, mp_p, cb,
-                                           lhs2[perm], l0s, windows, B=p.B,
-                                           tk=tk, lchunk=lchunk,
-                                           precision=prec,
-                                           interpret=interpret)
-            return out[inv_perm]
-        return _wrap_batch(raw, batch)
-    if impl == "dense":
-        plan.require_dense("make_idwt_fn(impl='dense')")
-
-        def raw(p: SoftPlan, lhs2):
-            return dwt_kernels.idwt_dense(p.d, lhs2, tk=tk, tl=tl, tj=tj,
-                                          interpret=interpret)
-        return _wrap_batch(raw, batch)
-
-    if impl == "onthefly":
-        seeds, m, mp, cb = onthefly_inputs(plan)
-
-        def raw(p: SoftPlan, lhs2):
-            return wigner_rec.idwt_onthefly(seeds, m, mp, cb, lhs2, B=p.B,
-                                            tk=tk, interpret=interpret)
-        return _wrap_batch(raw, batch)
-
-    if impl == "fused":
-        seeds, m, mp, cb = onthefly_inputs(plan)
-        perm, _, l0s = fused_metadata(plan, min(tk, plan.n_padded))
-        inv_perm = np.argsort(perm)
-        seeds_p, m_p, mp_p = seeds[perm], m[perm], mp[perm]
-
-        def raw(p: SoftPlan, lhs2):
-            out = dwt_fused.idwt_fused(seeds_p, m_p, mp_p, cb, lhs2[perm],
-                                       l0s, B=p.B, tk=tk,
-                                       vmem_limit=vmem_limit,
-                                       interpret=interpret)
-            return out[inv_perm]
-        return _wrap_batch(raw, batch)
-
-    raise ValueError(impl)
+def make_idwt_fn(plan: SoftPlan, *, tk=8, lchunk=None, precision=None,
+                 vmem_limit=None, interpret=None, batch=None):
+    """Build an idwt_fn(plan, lhs) for core.batched.inverse_clustered;
+    arguments as in :func:`make_dwt_fn` (with batch=V, lhs gains a
+    leading V axis, packed onto lanes for one launch)."""
+    return _make_fn(plan, inverse=True, tk=tk, lchunk=lchunk,
+                    precision=precision, vmem_limit=vmem_limit,
+                    interpret=interpret, batch=batch)
 
 
 def batched_rhs(plan: SoftPlan, S):
@@ -380,8 +276,7 @@ def batched_rhs(plan: SoftPlan, S):
 
     S: (V, 2B, J, 2B) complex FFT-analysis outputs (stage 1 of V forward
     transforms).  Returns (K, J, V*C*2) real -- the widened-C2 operand the
-    DWT kernels contract in a single launch (the dwt.py docstring's
-    "batching V transforms widens C2 to V*16" path).
+    fused DWT kernels contract in a single launch.
     """
     from repro.core import batched as _b
 
@@ -391,7 +286,7 @@ def batched_rhs(plan: SoftPlan, S):
 
 @functools.lru_cache(maxsize=16)
 def onthefly_inputs(plan: SoftPlan):
-    """Seeds/orders/cos(beta) for the fused-recurrence kernels.
+    """Seeds/orders/cos(beta) for the fused-family kernels.
 
     Padded clusters get zero seeds -> identically zero Wigner rows.
     Memoized by plan identity (plans are memoized by build_plan), so the

@@ -1,8 +1,9 @@
 """Kernel runtime helpers shared by every Pallas wrapper in this package.
 
-Lives below ops.py so the kernel modules themselves (dwt.py, dwt_fused.py,
-wigner_rec.py, folded_attention.py) can resolve their `interpret=None`
-defaults without importing ops (which imports them back).
+Lives below ops.py so the kernel modules themselves (dwt_fused.py,
+streaming.py, peaks.py, folded_attention.py) can resolve their
+`interpret=None` defaults without importing ops (which imports them
+back).
 """
 from __future__ import annotations
 

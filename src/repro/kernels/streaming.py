@@ -19,8 +19,7 @@ C2) coefficient tile is ever VMEM-live:
     (same jnp ops as the kernel, so every chunk generates the rows the
     monolithic kernel generates, bit for bit) and emits the (d_{l-1}, d_l)
     state at each chunk boundary, a (nL, 2, K, J) table that is
-    lchunk/2 x smaller than the full Wigner table the dense schedules
-    stream;
+    lchunk/2 x smaller than the full (K, L, J) Wigner table;
   * the chunk is the PANEL of the shared MXU contraction
     (dwt_fused.fill_panel / contract_panel, P = lchunk): a grid step
     generates its chunk's rows into a (TK, lchunk, J) VMEM panel, then
@@ -62,9 +61,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dwt_fused import contract_panel, fill_panel, march
+from .dwt_fused import _recurrence_step, contract_panel, fill_panel, march
 from .runtime import I0, resolve_interpret
-from .wigner_rec import _recurrence_step
 
 __all__ = ["build_windows", "dwt_streaming", "idwt_streaming",
            "check_lchunk"]

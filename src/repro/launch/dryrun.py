@@ -130,8 +130,8 @@ def build_decode(cfg, ctx, shape):
     return fn, (p_shape, b_shape, st_shape, pos)
 
 
-def build_soft(soft_cfg, ctx, mesh, direction="forward", impl="plain"):
-    from repro.core import batched, clusters, parallel
+def build_soft(soft_cfg, ctx, mesh, direction="forward"):
+    from repro.core import parallel
 
     B = soft_cfg.bandwidth
     # shard over the largest mesh-axis suffix whose size divides the beta
@@ -146,19 +146,10 @@ def build_soft(soft_cfg, ctx, mesh, direction="forward", impl="plain"):
     n = int(np.prod([mesh.shape[a] for a in axis]))
     plan = speclib.soft_plan_specs(B, n)
     plan_sh = speclib.soft_shardings(plan, ctx, axis)
-    local_dwt = None
-    if impl == "bucketed":
-        # extent buckets from the cluster metadata only (no table build)
-        tab = clusters.build_cluster_table(B)
-        perm = batched.shard_balanced_order(tab.rep[:, 0], n)
-        l_start = np.full(plan.n_padded, B - 1, np.int32)
-        l_start[: len(perm)] = tab.rep[perm, 0]
-        slices = batched.bucket_boundaries_from_lstart(l_start, n, 8)
-        local_dwt = parallel.make_bucketed_local_dwt(slices, B)
     if direction == "forward":
         f_sds = jax.ShapeDtypeStruct((2 * B,) * 3, jnp.complex64)
         fn = jax.jit(lambda pl, f: parallel.distributed_forward(
-            pl, f, mesh, axis, local_dwt=local_dwt))
+            pl, f, mesh, axis))
         return fn, (plan, f_sds)
     packed = jax.ShapeDtypeStruct((plan.n_padded, B, 8), jnp.complex64)
     fn = jax.jit(lambda pl, x: parallel.distributed_inverse(
@@ -216,9 +207,7 @@ def run_cell(arch, shape_name, multi_pod, opt_override=None, save_hlo=None,
         soft_cfg = configs.SOFT_CONFIGS[arch]
         fn, args = build_soft(soft_cfg, ctx, mesh,
                               "forward" if shape_name == "forward"
-                              else "inverse",
-                              impl=os.environ.get("REPRO_SOFT_IMPL",
-                                                  "plain"))
+                              else "inverse")
         extra = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
                  "kind": "soft", "bandwidth": soft_cfg.bandwidth,
                  "devices": mesh.size}

@@ -25,9 +25,7 @@ def build(cell):
     if arch.startswith("soft_b"):
         fn, args = dr.build_soft(configs.SOFT_CONFIGS[arch], ctx, mesh,
                                  "forward" if cell["shape"] == "forward"
-                                 else "inverse",
-                                 impl=os.environ.get("REPRO_SOFT_IMPL",
-                                                     "plain"))
+                                 else "inverse")
         return fn, args, mesh
     cfg = configs.get(arch)
     shape = {s.name: s for s in shapes_for(cfg)}[cell["shape"]]

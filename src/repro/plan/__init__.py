@@ -13,14 +13,13 @@ from __future__ import annotations
 import sys
 import types
 
-from .transform import (AUTO_IMPL_CANDIDATES, AUTO_V_CANDIDATES,  # noqa: F401
-                        IMPLS, Schedule, Transform, cache_stats,
-                        clear_cache, dense_table_bytes_limit, plan,
-                        warm_bandwidths)
+from .transform import (AUTO_V_CANDIDATES, IMPLS, Schedule,  # noqa: F401
+                        Transform, cache_stats, clear_cache,
+                        dense_table_bytes_limit, plan, warm_bandwidths)
 
 __all__ = ["plan", "Transform", "Schedule", "clear_cache", "cache_stats",
            "dense_table_bytes_limit", "warm_bandwidths",
-           "IMPLS", "AUTO_IMPL_CANDIDATES", "AUTO_V_CANDIDATES"]
+           "IMPLS", "AUTO_V_CANDIDATES"]
 
 
 class _CallableModule(types.ModuleType):

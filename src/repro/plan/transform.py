@@ -11,13 +11,14 @@ own plan -- ``ops.make_dwt_fn``, ``core.batched.forward_clustered*``,
 caches independently.  Now the decision is made ONCE:
 
     from repro import plan
-    t = plan(B, impl="auto", V="auto")     # resolve + materialize
+    t = plan(B, V="auto")                  # resolve + materialize
     fhat = t.forward(f)                    # local / sharded routed here
     grids = t.inverse_batch(fhats)         # V-lane packed launches
     res = t.correlate(f_s2, g_s2)          # application executor
 
-A ``Transform`` resolves the kernel schedule (dense / ragged / onthefly
-/ fused / pure-jnp reference) through :mod:`repro.kernels.autotune` --
+A ``Transform`` resolves the kernel schedule (a member of the fused
+kernel family, or the pure-jnp reference) through
+:mod:`repro.kernels.autotune` --
 statically via the VMEM-guard estimator by default, or with the
 measured on-disk-cached sweep under ``tune="measure"`` (or
 ``$REPRO_PLAN_TUNE=measure``) -- then materializes and owns every
@@ -42,10 +43,10 @@ returns the SAME ``Transform`` object (see :func:`cache_stats`), so a
 serving loop, a benchmark sweep, and a correlation engine at one
 bandwidth all share one set of compiled resources.  Memoization rules:
 the cache key is the full configuration tuple (B, dtype, impl, V,
-tiles, mesh identity + shard axes, tune mode, overlap, VMEM limit,
-interpret, bucket count, tune-cache path); meshes hash by object
-value/identity, so two distinct-but-equal mesh objects may plan twice
-while one mesh object always shares.  The cache holds the 16 most
+tk, lchunk, precision, streaming, mesh identity + shard axes, tune
+mode, overlap, VMEM limit, interpret, tune-cache path); meshes hash by
+object value/identity, so two distinct-but-equal mesh objects may plan
+twice while one mesh object always shares.  The cache holds the 16 most
 recent configurations (LRU) and :func:`cache_stats` counts mesh plans
 separately.  See docs/ARCHITECTURE.md for the full layer map.
 """
@@ -66,13 +67,10 @@ from repro.kernels.runtime import resolve_interpret
 
 __all__ = ["Transform", "Schedule", "plan", "clear_cache", "cache_stats",
            "dense_table_bytes_limit", "warm_bandwidths",
-           "IMPLS", "AUTO_IMPL_CANDIDATES", "AUTO_V_CANDIDATES"]
+           "IMPLS", "AUTO_V_CANDIDATES"]
 
-# impl="auto" resolves to one of these executor schedules
-IMPLS = ("reference", "dense", "ragged", "onthefly", "fused")
-# measured auto-selection sweeps the recurrence schedules (cheap candidate
-# sets; dense/ragged stay available by explicit request)
-AUTO_IMPL_CANDIDATES = ("fused", "onthefly")
+# the executor schedules: the pure-jnp oracle and the fused kernel family
+IMPLS = ("reference", "fused")
 AUTO_V_CANDIDATES = (1, 2, 4, 8)
 
 _DEF_TK = 8
@@ -89,7 +87,7 @@ class Schedule:
     ``n_shards`` is the mesh key: schedules of mesh plans are resolved
     against the per-device cluster shard (kloc = K/n_shards) -- tiles
     must divide the LOCAL cluster count and the VMEM guard sees the
-    local footprint -- so every mesh shape gets its own (tk, tl, tj, V).
+    local footprint -- so every mesh shape gets its own (tk, V).
 
     ``overlap`` is the distributed batch execution mode ("off" |
     "pipelined", :data:`repro.core.parallel.OVERLAP_MODES`): how the
@@ -114,8 +112,6 @@ class Schedule:
     impl: str               # executor schedule (one of IMPLS)
     V: int                  # lane width of the batch executors
     tk: int
-    tl: int
-    tj: int
     source: str             # "explicit" | "static" | "measured"
     vmem_bytes: int         # static per-grid-step footprint estimate
     vmem_limit: int         # budget the schedule was resolved under
@@ -124,12 +120,6 @@ class Schedule:
     lchunk: int | None = None   # streaming l-chunk (None = monolithic)
     precision: str = "fp32"     # streaming storage precision
     per_transform_s: float | None = None   # measured (tune="measure") only
-
-    @property
-    def inverse_impl(self) -> str:
-        """iDWT twin: the ragged grid has no inverse kernel; its plans
-        run the inverse on the dense grid with the same tiles."""
-        return "dense" if self.impl == "ragged" else self.impl
 
 
 def _tune_mode(tune) -> str:
@@ -157,9 +147,9 @@ def _resolve_overlap(overlap, n_shards: int) -> str:
     return parallel.check_overlap_mode(overlap)
 
 
-def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
-                     limit: int, n_shards: int = 1, overlap=None,
-                     lchunk=None, precision=None) -> Schedule:
+def _static_schedule(soft_plan: SoftPlan, impl, V, tk, limit: int,
+                     n_shards: int = 1, overlap=None, lchunk=None,
+                     precision=None) -> Schedule:
     """Largest lane width under the VMEM guard, default tiles.
 
     Mesh plans (n_shards > 1) resolve against the per-device cluster
@@ -169,7 +159,7 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
     distributed batch mode resolves through the static overlap heuristic
     unless the caller fixed it (``overlap="off" | "pipelined"``).
 
-    Streaming resolution (fused, single-shard): an explicit ``lchunk``
+    Streaming resolution (single-shard): an explicit ``lchunk``
     is honored; with lchunk=None the resolver first tries the monolithic
     kernel at every lane width, and only when NONE fits the VMEM budget
     does it auto-engage the streaming family -- widest lane width first,
@@ -188,13 +178,6 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
     K_local = K // n_shards
     C = soft_plan.gather_m.shape[1]
     itemsize = jnp.dtype(soft_plan.dtype).itemsize
-    impl = "fused" if impl == "auto" else impl
-    if soft_plan.streaming and impl in ("reference", "dense", "ragged"):
-        raise ValueError(
-            f"impl={impl!r} needs the dense Wigner table, but this "
-            f"B={soft_plan.B} plan was built streaming (d=None); use the "
-            f"recurrence family (impl='fused'/'onthefly') or plan with "
-            f"streaming=False")
     omode = _resolve_overlap(overlap, n_shards)
     prec = autotune.static_precision(soft_plan.B, precision,
                                      dtype=soft_plan.dtype) \
@@ -204,17 +187,14 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
         tk = _shard_tk(_DEF_TK if tk is None else tk, K_local)
     elif tk is None:
         tk = _default_tk(K_local)
-    tl = L if tl is None else tl
-    tj = J if tj is None else tj
     if impl == "reference":     # pure jnp: no kernel, no VMEM constraint
         source = "static" if V == "auto" else "explicit"
         V = 4 if V == "auto" else V
-        return Schedule(impl, V, tk, tl, tj, source, 0, limit, n_shards,
+        return Schedule(impl, V, tk, source, 0, limit, n_shards,
                         overlap=omode)
 
     def est(v, lc=None):
-        return autotune.estimate_vmem_bytes(impl, L=L, J=J, C2=v * C * 2,
-                                            tk=tk, tl=tl, tj=tj,
+        return autotune.estimate_vmem_bytes(L=L, J=J, C2=v * C * 2, tk=tk,
                                             itemsize=itemsize, lchunk=lc,
                                             precision=prec, limit=limit)
 
@@ -224,7 +204,7 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
         if fits:
             V = max(fits)
             source = "static"
-        elif lchunk is None and impl == "fused" and n_shards == 1:
+        elif lchunk is None and n_shards == 1:
             # the monolithic coefficient tile is over budget at every
             # lane width (or bf16 forces the streaming family outright):
             # engage streaming, widest lane width first, each with its
@@ -262,50 +242,35 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, tl, tj,
                 f"explicit schedule impl={impl} V={V} tk={tk} needs "
                 f"{est(V, lchunk)} bytes of VMEM per grid step, over the "
                 f"{limit} budget (raise $REPRO_VMEM_BYTES or vmem_budget)")
-    return Schedule(impl, V, tk, tl, tj, source, est(V, lchunk), limit,
-                    n_shards, overlap=omode, lchunk=lchunk, precision=prec)
+    return Schedule(impl, V, tk, source, est(V, lchunk), limit, n_shards,
+                    overlap=omode, lchunk=lchunk, precision=prec)
 
 
-def _measured_schedule(soft_plan: SoftPlan, impl, V, limit: int, interpret,
+def _measured_schedule(soft_plan: SoftPlan, V, limit: int, interpret,
                        reps: int, cache, n_shards: int = 1, overlap=None,
                        mesh=None, axis=None, lchunk=None,
                        precision=None) -> Schedule:
     """Resolve via the measured autotune sweep (disk-cached winners).
 
     Mesh plans sweep the per-device cluster shard (autotune_dwt's
-    n_shards key): the device-local kernel on a mesh is always the fused
-    family, so "auto" collapses to one fused sweep instead of timing the
-    same local kernel twice.  When the overlap mode is not fixed by the
-    caller, mesh plans also time the distributed batch under both modes
+    n_shards key).  When the overlap mode is not fixed by the caller,
+    mesh plans also time the distributed batch under both modes
     (:func:`repro.kernels.autotune.autotune_overlap`, each cached under
     its own /O{mode} key) and take the faster.
     """
     prec = autotune.static_precision(soft_plan.B, precision,
                                      dtype=soft_plan.dtype) \
-        if n_shards == 1 and impl in ("auto", "fused") else "fp32"
+        if n_shards == 1 else "fp32"
     if prec == "bf16" and lchunk is None:
         # bf16 has no monolithic kernel: make_dwt_fn forces the streaming
         # family at lchunk=B, so sweep/key/estimate the kernel that will
         # actually launch instead of mislabeling it monolithic
         lchunk = soft_plan.B
-    streaming = lchunk is not None or prec == "bf16"
-    if streaming:       # only the fused family has a streaming kernel
-        impls = ("fused",)
-    elif n_shards > 1:
-        impls = ("fused",) if impl == "auto" else (impl,)
-    else:
-        impls = AUTO_IMPL_CANDIDATES if impl == "auto" else (impl,)
     Vs = AUTO_V_CANDIDATES if V == "auto" else (V,)
-    best, best_impl = None, None
-    for im in impls:
-        cfg = autotune.autotune_dwt(soft_plan, im, Vs=Vs, reps=reps,
-                                    interpret=interpret, vmem_limit=limit,
-                                    cache=cache, n_shards=n_shards,
-                                    lchunk=lchunk,
-                                    precision=prec if im == "fused"
-                                    else "fp32")
-        if best is None or cfg["per_transform_s"] < best["per_transform_s"]:
-            best, best_impl = cfg, im
+    best = autotune.autotune_dwt(soft_plan, Vs=Vs, reps=reps,
+                                 interpret=interpret, vmem_limit=limit,
+                                 cache=cache, n_shards=n_shards,
+                                 lchunk=lchunk, precision=prec)
     if overlap is None and n_shards > 1 and mesh is not None:
         omode = autotune.autotune_overlap(
             soft_plan, mesh, axis, V=best["V"],
@@ -314,17 +279,14 @@ def _measured_schedule(soft_plan: SoftPlan, impl, V, limit: int, interpret,
             vmem_limit=limit)["overlap"]
     else:
         omode = _resolve_overlap(overlap, n_shards)
-    K, L, J = soft_plan.n_padded, soft_plan.B, 2 * soft_plan.B
+    L, J = soft_plan.B, 2 * soft_plan.B
     C = soft_plan.gather_m.shape[1]
-    prec = prec if best_impl == "fused" else "fp32"
     est = autotune.estimate_vmem_bytes(
-        best_impl, L=L, J=J, C2=best["V"] * C * 2, tk=best["tk"],
-        tl=best["tl"], tj=best["tj"],
+        L=L, J=J, C2=best["V"] * C * 2, tk=best["tk"],
         itemsize=jnp.dtype(soft_plan.dtype).itemsize,
         lchunk=lchunk, precision=prec, limit=limit)
-    return Schedule(best_impl, best["V"], best["tk"], best["tl"], best["tj"],
-                    "measured", est, limit, n_shards, overlap=omode,
-                    lchunk=lchunk, precision=prec,
+    return Schedule("fused", best["V"], best["tk"], "measured", est, limit,
+                    n_shards, overlap=omode, lchunk=lchunk, precision=prec,
                     per_transform_s=best["per_transform_s"])
 
 
@@ -352,8 +314,8 @@ class Transform:
     """
 
     def __init__(self, *, soft_plan: SoftPlan, schedule: Schedule,
-                 mesh=None, axis=None, n_shards: int = 1, n_buckets: int = 8,
-                 interpret=None, tune: str = "static"):
+                 mesh=None, axis=None, n_shards: int = 1, interpret=None,
+                 tune: str = "static"):
         self.soft_plan = soft_plan
         self.schedule = schedule
         self.B = soft_plan.B
@@ -361,7 +323,6 @@ class Transform:
         self.mesh = mesh
         self.axis = axis
         self.n_shards = n_shards
-        self.n_buckets = n_buckets
         self.interpret = interpret
         self.tune = tune
         self.reset_stats()
@@ -413,7 +374,8 @@ class Transform:
         ``est_host_plan_bytes`` is the host-tier twin:
         the peak RSS plan CONSTRUCTION costs (the dense O(B^3) table
         cliff, or the streaming generator's O(P*J) panels when
-        ``streaming`` is True).  ``precision_bound_extrapolated`` flags
+        ``streaming`` is True); ``est_peak_hbm_bytes`` is None off the
+        fused family.  ``precision_bound_extrapolated`` flags
         -- loudly, with a UserWarning -- a bf16 schedule whose error gate
         is still an extrapolation rather than an error_table.py
         measurement."""
@@ -433,7 +395,7 @@ class Transform:
                 f"replace it with a measurement", stacklevel=2)
         out = {
             "B": self.B, "dtype": jnp.dtype(self.dtype).name,
-            "impl": s.impl, "V": s.V, "tk": s.tk, "tl": s.tl, "tj": s.tj,
+            "impl": s.impl, "V": s.V, "tk": s.tk,
             "tune": self.tune, "source": s.source, "overlap": s.overlap,
             "lchunk": s.lchunk, "precision": s.precision,
             "precision_bound_extrapolated": extrapolated,
@@ -450,8 +412,9 @@ class Transform:
                 tk=s.tk, L=L, C2=s.V * C * 2, itemsize=itemsize,
                 lchunk=s.lchunk),
             "est_peak_hbm_bytes": autotune.estimate_hbm_bytes(
-                s.impl, B=self.B, K=K, L=L, J=J, C2=s.V * C * 2,
-                itemsize=itemsize, lchunk=s.lchunk, precision=s.precision),
+                B=self.B, K=K, L=L, J=J, C2=s.V * C * 2, itemsize=itemsize,
+                lchunk=s.lchunk, precision=s.precision)
+            if s.impl == "fused" else None,
             "est_host_plan_bytes": autotune.estimate_host_plan_bytes(
                 self.B, n_clusters=sp.n_clusters, itemsize=itemsize,
                 streaming=sp.streaming),
@@ -487,32 +450,31 @@ class Transform:
     def dwt_fn(self):
         """Single-transform (plan, rhs) DWT closure; None = jnp path."""
         return self._res("dwt_1", lambda: self._make(ops.make_dwt_fn,
-                                                     self.schedule.impl, None))
+                                                     None))
 
     @property
     def idwt_fn(self):
-        return self._res("idwt_1", lambda: self._make(
-            ops.make_idwt_fn, self.schedule.inverse_impl, None))
+        return self._res("idwt_1", lambda: self._make(ops.make_idwt_fn,
+                                                      None))
 
     @property
     def dwt_fn_batch(self):
         """V-lane batch DWT closure ((V, K, J, C, 2) rhs, one launch)."""
         return self._res("dwt_V", lambda: self._make(
-            ops.make_dwt_fn, self.schedule.impl, self.schedule.V))
+            ops.make_dwt_fn, self.schedule.V))
 
     @property
     def idwt_fn_batch(self):
         return self._res("idwt_V", lambda: self._make(
-            ops.make_idwt_fn, self.schedule.inverse_impl, self.schedule.V))
+            ops.make_idwt_fn, self.schedule.V))
 
-    def _make(self, maker, impl, batch):
+    def _make(self, maker, batch):
         if self.schedule.impl == "reference":
             return None
         s = self.schedule
-        return maker(self.soft_plan, impl, tk=s.tk, tl=s.tl, tj=s.tj,
-                     lchunk=s.lchunk, precision=s.precision,
-                     vmem_limit=s.vmem_limit, interpret=self.interpret,
-                     batch=batch)
+        return maker(self.soft_plan, tk=s.tk, lchunk=s.lchunk,
+                     precision=s.precision, vmem_limit=s.vmem_limit,
+                     interpret=self.interpret, batch=batch)
 
     def shard_meta(self):
         """Fused-kernel shard metadata (seeds / orders / per-tile l0s),
@@ -532,25 +494,20 @@ class Transform:
 
     def _local_dwt(self):
         def build():
-            impl = self.schedule.impl
-            if impl in ("fused", "onthefly"):
+            if self.schedule.impl == "fused":
                 return parallel.make_fused_local_dwt(
                     self.soft_plan, self.n_shards, interpret=self.interpret,
                     meta=self.shard_meta())
-            if impl in ("dense", "ragged"):
-                slices = batched.bucket_boundaries(
-                    self.soft_plan, self.n_shards, self.n_buckets)
-                return parallel.make_bucketed_local_dwt(slices, self.B)
             return None          # reference: plain einsum in the body
         return self._res("local_dwt", build)
 
     def _local_idwt(self):
         def build():
-            if self.schedule.impl in ("fused", "onthefly"):
+            if self.schedule.impl == "fused":
                 return parallel.make_fused_local_idwt(
                     self.soft_plan, self.n_shards, interpret=self.interpret,
                     meta=self.shard_meta())
-            return None          # dense einsum (no bucketed inverse kernel)
+            return None          # reference: plain einsum in the body
         return self._res("local_idwt", build)
 
     def executor(self) -> "parallel.DistExecutor":
@@ -790,32 +747,32 @@ def _mesh_key(mesh):
         return id(mesh)
 
 
-def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
-         tk: int | None = None, tl: int | None = None, tj: int | None = None,
-         lchunk: int | None = None, precision: str | None = None,
-         streaming: bool | None = None,
+def plan(B: int, dtype=jnp.float64, *, impl: str = "fused", V="auto",
+         tk: int | None = None, lchunk: int | None = None,
+         precision: str | None = None, streaming: bool | None = None,
          mesh=None, axis=("data", "model"), tune: str | None = None,
          overlap: str | None = None, vmem_budget: int | None = None,
-         interpret=None, n_buckets: int = 8,
-         tune_reps: int = 3, tune_cache=None) -> Transform:
+         interpret=None, tune_reps: int = 3, tune_cache=None) -> Transform:
     """Plan one SO(3) FFT configuration; returns a memoized Transform.
 
-    impl: "auto" | "reference" | "dense" | "ragged" | "onthefly" | "fused".
+    impl: "fused" (the Pallas kernel family) | "reference" (pure jnp).
     V:    "auto" or an explicit lane width for the batch executors.
+    tk:   the cluster tile (default: the largest of 8, 4, 2, 1 dividing
+          the cluster count).
     lchunk: None (monolithic kernel, or auto-engaged streaming when the
           monolithic tile cannot fit the VMEM budget at any lane width)
           or an explicit l-chunk (divisor of B) forcing the streaming
-          fused schedule (single-shard fused plans only).
+          kernel (single-shard fused plans only).
     streaming: build the SoftPlan WITHOUT the dense (K, L, J) Wigner
           table (core.batched.build_plan(streaming=True)): plan
           construction never materializes any O(B^3) host array, the
-          grid FFT stages run in beta slabs, and only the recurrence
-          family (fused/onthefly) can execute.  None -- the default --
-          auto-engages it for recurrence-capable non-mesh plans whose
-          dense-table host footprint would exceed
-          $REPRO_PLAN_DENSE_TABLE_BYTES (512 MiB default: B <= 64 keeps
-          the dense build bit-for-bit, paper-scale B streams).  Explicit
-          True/False overrides; True rejects table-dependent impls.
+          grid FFT stages run in beta slabs, and only the fused
+          kernels can execute.  None -- the default -- auto-engages it
+          for fused non-mesh plans whose dense-table host footprint
+          would exceed $REPRO_PLAN_DENSE_TABLE_BYTES (512 MiB default:
+          B <= 64 keeps the dense build bit-for-bit, paper-scale B
+          streams).  Explicit
+          True/False overrides; True rejects impl="reference".
     precision: None (the default: fp32 / plan-dtype storage, bitwise-
           safe -- a default plan never trades accuracy implicitly),
           "auto" (opt-in heuristic: bf16 storage for FLOAT32 plans at
@@ -840,9 +797,8 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
     consumer of one configuration shares one SoftPlan, one Wigner table,
     and one set of compiled kernels.
     """
-    if impl != "auto" and impl not in IMPLS:
-        raise ValueError(f"impl must be 'auto' or one of {IMPLS}, "
-                         f"got {impl!r}")
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
     if (impl != "reference" and jnp.dtype(dtype).itemsize == 8
             and not resolve_interpret(interpret)):
         raise ValueError(
@@ -856,7 +812,7 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
         raise ValueError(f"precision must be None, 'auto' or one of "
                          f"{autotune.PRECISIONS}, got {precision!r}")
     if lchunk is not None or precision == "bf16":
-        if impl not in ("auto", "fused"):
+        if impl != "fused":
             raise ValueError(
                 f"streaming schedules (lchunk/bf16) exist only for the "
                 f"fused family, not impl={impl!r}")
@@ -874,8 +830,7 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
             raise ValueError(
                 f"overlap={overlap!r} needs a mesh plan; local batches "
                 "have no collective to pipeline")
-    recurrence_capable = impl in ("auto", "fused", "onthefly") \
-        and mesh is None
+    recurrence_capable = impl == "fused" and mesh is None
     if streaming is None:
         dense_bytes = autotune.estimate_host_plan_bytes(
             B, itemsize=jnp.dtype(dtype).itemsize)
@@ -883,17 +838,17 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
             and dense_bytes > dense_table_bytes_limit()
     elif streaming and not recurrence_capable:
         raise ValueError(
-            f"streaming=True needs a recurrence-family plan (impl in "
-            f"'auto'/'fused'/'onthefly', no mesh); got impl={impl!r}, "
+            f"streaming=True needs a fused plan without a mesh; got "
+            f"impl={impl!r}, "
             f"mesh={'set' if mesh is not None else None}")
     mode = _tune_mode(tune)
     limit = autotune.vmem_limit_bytes() if vmem_budget is None \
         else int(vmem_budget)
     axis = (axis,) if isinstance(axis, str) else tuple(axis)
-    key = (B, jnp.dtype(dtype).str, impl, V, tk, tl, tj, lchunk, precision,
+    key = (B, jnp.dtype(dtype).str, impl, V, tk, lchunk, precision,
            bool(streaming),
            _mesh_key(mesh), axis if mesh is not None else None, mode,
-           overlap, limit, interpret, n_buckets,
+           overlap, limit, interpret,
            None if tune_cache is None else str(tune_cache))
     hit = _CACHE.get(key)
     if hit is not None:
@@ -942,27 +897,21 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "auto", V="auto",
             soft_plan = batched.build_plan(B, dtype=dtype, pad_to=base_tk,
                                            streaming=bool(streaming))
 
-        # mesh plans resolve (tk, tl, tj, V) against the per-device shard:
-        # the measured sweep exists only for the fused device-local kernel
-        # family, so other impls fall back to the static VMEM guard
-        measurable = impl in ("auto", "fused", "onthefly") or n_shards == 1
+        # mesh plans resolve (tk, V) against the per-device shard
         with obs.span("plan.schedule", B=B, impl=impl, tune=mode,
                       n_shards=n_shards):
-            if mode == "measure" and impl != "reference" and measurable \
-                    and tk is None and tl is None and tj is None:
+            if mode == "measure" and impl == "fused" and tk is None:
                 schedule = _measured_schedule(
-                    soft_plan, impl, V, limit, interpret, tune_reps,
-                    tune_cache, n_shards, overlap, mesh, axis, lchunk,
-                    precision)
+                    soft_plan, V, limit, interpret, tune_reps, tune_cache,
+                    n_shards, overlap, mesh, axis, lchunk, precision)
             else:
                 schedule = _static_schedule(
-                    soft_plan, impl, V, tk, tl, tj, limit, n_shards,
-                    overlap, lchunk, precision)
+                    soft_plan, impl, V, tk, limit, n_shards, overlap,
+                    lchunk, precision)
 
         t = Transform(soft_plan=soft_plan, schedule=schedule, mesh=mesh,
                       axis=axis if mesh is not None else None,
-                      n_shards=n_shards, n_buckets=n_buckets,
-                      interpret=interpret, tune=mode)
+                      n_shards=n_shards, interpret=interpret, tune=mode)
     _bump_host_peak_rss()
     _CACHE[key] = t
     while len(_CACHE) > _CACHE_MAX:

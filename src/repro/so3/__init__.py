@@ -53,8 +53,7 @@ Latency/throughput note
 One fused launch serves V requests; each on-the-fly Wigner d-row is
 generated once and contracted against V*C*2 lanes, so per-request cost
 approaches 1/V of a solo launch as lanes fill (benchmarks/run.py
---section correlation measures occupancy and per-request wall time; the
-dwt_schedules section shows the V = 4 amortization at the kernel level).
+--section correlation measures occupancy and per-request wall time).
 Latency-sensitive callers keep ``max_wait_ms`` small (partial lanes are
 zero-padded -- the compiled kernel shape never changes); throughput
 callers batch via :meth:`CorrelationEngine.match_batch` directly.

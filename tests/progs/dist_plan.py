@@ -23,7 +23,7 @@ B = 8
 def check_single_transforms(mesh, t_local, fhat, mask):
     f_ref = np.asarray(t_local.inverse(fhat))
     back_ref = np.asarray(t_local.forward(f_ref))
-    for impl in ("fused", "dense", "reference"):
+    for impl in ("fused", "reference"):
         t_mesh = plan(B, impl=impl, mesh=mesh, axis=("data",))
         assert t_mesh.n_shards == 2
         assert t_mesh.schedule.n_shards == 2

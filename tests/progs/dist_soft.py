@@ -47,25 +47,21 @@ def main():
         plan, parallel.dense_to_packed(plan, fhat)))
     np.testing.assert_array_equal(rt, fhat)
 
-    # bucketed (extent-truncated) distributed DWT with the shard-balanced
-    # order equals the plain path exactly
+    # the shard-balanced cluster order distributes like the plain path
     n = 8
     order = batched.shard_balanced_order(
         np.asarray([m for m, _ in batched.clusters_mod.build_cluster_table(
             B).rep]), n)
     plan_b = batched.build_plan(B, pad_to=n, order=order)
-    slices = batched.bucket_boundaries(plan_b, n, 4)
-    local = parallel.make_bucketed_local_dwt(slices, B)
     f_b = np.asarray(parallel.distributed_inverse(
         plan_b, parallel.dense_to_packed(plan_b, fhat), mesh,
         ("data", "model")))
     np.testing.assert_allclose(f_b, f_ref, rtol=1e-11, atol=1e-11)
     packed_bb = parallel.distributed_forward(plan_b, f_b, mesh,
-                                             ("data", "model"),
-                                             local_dwt=local)
+                                             ("data", "model"))
     back_b = np.asarray(parallel.packed_to_dense(plan_b, packed_bb))
     np.testing.assert_allclose(back_b, fhat, rtol=1e-9, atol=1e-11,
-                               err_msg="bucketed path")
+                               err_msg="shard-balanced path")
 
     # fused (ragged + on-the-fly) distributed DWT: the shard_map runs with
     # NO Wigner-table shard at all -- seeds + recurrence replace plan.d

@@ -1,7 +1,8 @@
-"""Fused ragged+on-the-fly DWT kernel: parity against the jnp oracle and
-the other schedules, the Wigner panel contraction (full and short panels,
-padded clusters, lane batching), multi-transform lane batching, the batch
-transform wrappers, and the measured autotuner."""
+"""Fused ragged+on-the-fly DWT kernel family: parity against the jnp
+oracle over dtypes, bandwidths and cluster tiles, the Wigner panel
+contraction (full and short panels, padded clusters, lane batching),
+multi-transform lane batching of the monolithic and streaming members,
+the batch transform wrappers, and the measured autotuner."""
 import numpy as np
 import pytest
 import jax
@@ -24,35 +25,27 @@ def _tol(dtype):
 
 
 # ---------------------------------------------------------------------------
-# kernel parity vs the jnp oracle and the sibling schedules
+# kernel parity vs the jnp oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B", [4, 8, 16])
-@pytest.mark.parametrize("dtype", [np.float32, np.float64])
-def test_fused_forward_matches_oracle(B, dtype):
+# (B, dtype, tk): every bandwidth in both dtypes at tk = 4, and the f64
+# B = 16 case (136 clusters) at every cluster tile that divides it
+ORACLE_CASES = [(B, dtype, 4) for B in (4, 8, 16)
+                for dtype in (np.float32, np.float64)] + [
+    (16, np.float64, 2), (16, np.float64, 8)]
+
+
+@pytest.mark.parametrize("B,dtype,tk", ORACLE_CASES)
+def test_fused_forward_matches_oracle(B, dtype, tk):
     jdt = jnp.float32 if dtype == np.float32 else jnp.float64
     plan = batched.build_plan(B, dtype=jdt, pad_to=4)
     K, L, J = plan.d.shape
     rhs = rand((K, J, 8, 2), dtype, scale=0.3)
-    out = np.asarray(ops.make_dwt_fn(plan, "fused", tk=4)(plan, rhs))
+    out = np.asarray(ops.make_dwt_fn(plan, tk=tk)(plan, rhs))
     expect = np.asarray(ref.dwt_ref(plan.d, rhs.reshape(K, J, 16)))
     rtol, atol = _tol(dtype)
     np.testing.assert_allclose(out.reshape(K, L, 16), expect, rtol=rtol,
                                atol=atol)
-
-
-@pytest.mark.parametrize("B", [4, 8, 16])
-def test_fused_matches_ragged_and_onthefly(B):
-    plan = batched.build_plan(B, dtype=jnp.float64, pad_to=4)
-    K, L, J = plan.d.shape
-    rhs = rand((K, J, 8, 2))
-    fused = np.asarray(ops.make_dwt_fn(plan, "fused", tk=4)(plan, rhs))
-    otf = np.asarray(ops.make_dwt_fn(plan, "onthefly", tk=4)(plan, rhs))
-    rag = np.asarray(ops.make_dwt_fn(plan, "ragged", tk=4, tl=max(B // 4, 2),
-                                     tj=J)(plan, rhs))
-    np.testing.assert_allclose(fused, otf, rtol=1e-11, atol=1e-12)
-    # ragged masks l < l_start to zero; fused rows there are zero too
-    np.testing.assert_allclose(fused, rag, rtol=1e-10, atol=1e-11)
 
 
 def test_fused_actually_skips_rows():
@@ -66,16 +59,20 @@ def test_fused_actually_skips_rows():
     assert int(np.sum(L - l0s)) < (K // tk) * L
 
 
-def test_fused_inverse_matches_oracle():
-    plan = batched.build_plan(8, dtype=jnp.float64, pad_to=4)
+@pytest.mark.parametrize("B,dtype,tk", ORACLE_CASES)
+def test_fused_inverse_matches_oracle(B, dtype, tk):
+    jdt = jnp.float32 if dtype == np.float32 else jnp.float64
+    cdt = np.complex64 if dtype == np.float32 else np.complex128
+    plan = batched.build_plan(B, dtype=jdt, pad_to=4)
     K, L, J = plan.d.shape
     # lhs as produced by _gather_coeffs: zero below each cluster's l-start
-    fhat = soft.random_coeffs(8, 5)
+    fhat = soft.random_coeffs(B, 5).astype(cdt)
     lhs = np.asarray(batched._gather_coeffs(plan, jnp.asarray(fhat)))
-    out = np.asarray(ops.make_idwt_fn(plan, "fused", tk=4)(plan, lhs))
+    out = np.asarray(ops.make_idwt_fn(plan, tk=tk)(plan, lhs))
     expect = np.asarray(ref.idwt_ref(plan.d, lhs.reshape(K, L, 16)))
-    np.testing.assert_allclose(out.reshape(K, J, 16), expect, rtol=1e-10,
-                               atol=1e-11)
+    rtol, atol = _tol(dtype)
+    np.testing.assert_allclose(out.reshape(K, J, 16), expect, rtol=rtol,
+                               atol=atol)
 
 
 # ---------------------------------------------------------------------------
@@ -98,13 +95,13 @@ def test_panel_contraction_matches_oracle(B, panel, V, direction):
     assert (l0s > 0).any()
     limit = None
     if panel == "short":
-        limit = autotune.estimate_vmem_bytes("fused", L=L, J=J, C2=C2, tk=tk,
+        limit = autotune.estimate_vmem_bytes(L=L, J=J, C2=C2, tk=tk,
                                              itemsize=8, panel=8)
     want_P = 8 if panel == "short" else B
     assert autotune.panel_depth(L=L, J=J, C2=C2, tk=tk, itemsize=8,
                                 limit=limit) == want_P
     maker = ops.make_dwt_fn if direction == "fwd" else ops.make_idwt_fn
-    fn = maker(plan, "fused", tk=tk, vmem_limit=limit,
+    fn = maker(plan, tk=tk, vmem_limit=limit,
                batch=None if V == 1 else V)
     if direction == "fwd":
         x = rand((V, K, J, 8, 2), scale=0.3)
@@ -145,17 +142,24 @@ def test_pack_unpack_roundtrip():
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@pytest.mark.parametrize("impl", ["dense", "ragged", "onthefly", "fused"])
+# the monolithic kernel and a streaming member (4-row chunks) at B = 8
+MEMBERS = [pytest.param({}, id="monolithic"),
+           pytest.param({"lchunk": 4}, id="streaming")]
+
+
+@pytest.mark.parametrize("member", MEMBERS)
 @pytest.mark.parametrize("V", [1, 4])
-def test_batched_dwt_matches_per_transform(impl, V):
+@pytest.mark.parametrize("direction", ["dwt", "idwt"])
+def test_batched_dwt_matches_per_transform(member, V, direction):
     B = 8
     plan = batched.build_plan(B, dtype=jnp.float64, pad_to=4)
     K, L, J = plan.d.shape
-    kw = dict(tk=4, tl=4, tj=J)
-    single = ops.make_dwt_fn(plan, impl, **kw)
-    rhs = rand((V, K, J, 8, 2))
-    out = np.asarray(ops.make_dwt_fn(plan, impl, batch=V, **kw)(plan, rhs))
-    expect = np.stack([np.asarray(single(plan, rhs[v])) for v in range(V)])
+    maker = ops.make_dwt_fn if direction == "dwt" else ops.make_idwt_fn
+    single = maker(plan, tk=4, **member)
+    rows = J if direction == "dwt" else L
+    x = rand((V, K, rows, 8, 2))
+    out = np.asarray(maker(plan, tk=4, batch=V, **member)(plan, x))
+    expect = np.stack([np.asarray(single(plan, x[v])) for v in range(V)])
     np.testing.assert_allclose(out, expect, rtol=1e-11, atol=1e-12)
 
 
@@ -171,16 +175,16 @@ def test_batched_rhs_matches_stacked_gather():
                                rtol=1e-12, atol=1e-13)
 
 
-@pytest.mark.parametrize("impl", ["fused", "onthefly"])
+@pytest.mark.parametrize("member", MEMBERS)
 @pytest.mark.parametrize("V", [1, 4])
-def test_batch_transform_roundtrip(impl, V):
+def test_batch_transform_roundtrip(member, V):
     """forward_clustered_batch o inverse_clustered_batch == identity."""
     B = 8
     plan = batched.build_plan(B, dtype=jnp.float64, pad_to=4)
     fhats = jnp.stack([jnp.asarray(soft.random_coeffs(B, s))
                        for s in range(V)])
-    idwt_fn = ops.make_idwt_fn(plan, impl, tk=4, batch=V)
-    dwt_fn = ops.make_dwt_fn(plan, impl, tk=4, batch=V)
+    idwt_fn = ops.make_idwt_fn(plan, tk=4, batch=V, **member)
+    dwt_fn = ops.make_dwt_fn(plan, tk=4, batch=V, **member)
     f = batched.inverse_clustered_batch(plan, fhats, idwt_fn=idwt_fn)
     # matches V independent single transforms
     for v in range(V):
@@ -195,7 +199,7 @@ def test_batch_transform_roundtrip(impl, V):
 def test_batch_fn_rejects_wrong_batch():
     plan = batched.build_plan(8, dtype=jnp.float64, pad_to=4)
     K, _, J = plan.d.shape
-    fn = ops.make_dwt_fn(plan, "fused", tk=4, batch=4)
+    fn = ops.make_dwt_fn(plan, tk=4, batch=4)
     with pytest.raises(ValueError, match="batch=4"):
         fn(plan, jnp.asarray(rand((2, K, J, 8, 2))))
 
@@ -207,27 +211,27 @@ def test_batch_fn_rejects_wrong_batch():
 def test_autotune_caches_and_reuses(tmp_path):
     plan = batched.build_plan(8, dtype=jnp.float32, pad_to=4)
     cache = tmp_path / "autotune.json"
-    cfg = autotune.autotune_dwt(plan, "fused", cache=cache, reps=1)
+    cfg = autotune.autotune_dwt(plan, cache=cache, reps=1)
     assert cache.exists()
     assert cfg["tk"] >= 1 and cfg["V"] == 1 and cfg["per_transform_s"] > 0
     # second call must hit the cache (identical dict, no re-measure drift)
-    assert autotune.autotune_dwt(plan, "fused", cache=cache, reps=1) == cfg
+    assert autotune.autotune_dwt(plan, cache=cache, reps=1) == cfg
     # tuned fn produces oracle-parity output
     K, L, J = plan.d.shape
     rhs = rand((K, J, 8, 2), np.float32, scale=0.3)
-    out = np.asarray(autotune.tuned_dwt_fn(plan, "fused", cache=cache)(plan,
-                                                                       rhs))
+    out = np.asarray(autotune.tuned_dwt_fn(plan, cache=cache)(plan, rhs))
     expect = np.asarray(ref.dwt_ref(plan.d, rhs.reshape(K, J, 16)))
     np.testing.assert_allclose(out.reshape(K, L, 16), expect, rtol=5e-4,
                                atol=1e-4)
 
 
 def test_candidate_tiles_respect_divisibility():
-    for impl in ("dense", "fused"):
-        for cand in autotune.candidate_tiles(24, 16, 32, impl):
-            assert 24 % cand["tk"] == 0
-            assert 16 % cand["tl"] == 0
-            assert 32 % cand["tj"] == 0
+    assert autotune.candidate_tiles(24) == [4, 8]
+    for K in (24, 36, 136, 7):
+        tks = autotune.candidate_tiles(K)
+        assert tks and all(K % tk == 0 for tk in tks)
+    # a per-device shard no primary tile divides falls back to small ones
+    assert autotune.candidate_tiles(18) == [2, 3, 6]
 
 
 # ---------------------------------------------------------------------------
@@ -236,26 +240,24 @@ def test_candidate_tiles_respect_divisibility():
 
 def test_vmem_estimate_grows_with_lanes_and_tiles():
     kw = dict(L=16, J=32, itemsize=4)
-    base = autotune.estimate_vmem_bytes("fused", tk=8, C2=16, **kw)
+    base = autotune.estimate_vmem_bytes(tk=8, C2=16, **kw)
     assert base > 0
     # lane packing (C2 = V*C*2) and cluster tiling both grow the footprint
-    assert autotune.estimate_vmem_bytes("fused", tk=8, C2=128, **kw) > base
-    assert autotune.estimate_vmem_bytes("fused", tk=16, C2=16, **kw) > base
+    assert autotune.estimate_vmem_bytes(tk=8, C2=128, **kw) > base
+    assert autotune.estimate_vmem_bytes(tk=16, C2=16, **kw) > base
     # the fused kernels' (tk, P, J) Wigner panel is counted: the whole
     # degree range under the default budget, 4 bytes a row element
-    short = autotune.estimate_vmem_bytes("fused", tk=8, C2=16, panel=8, **kw)
+    short = autotune.estimate_vmem_bytes(tk=8, C2=16, panel=8, **kw)
     assert base - short == 4 * 8 * (16 - 8) * 32
-    assert autotune.estimate_vmem_bytes("onthefly", tk=8, C2=16,
-                                        **kw) == base - 4 * 8 * 16 * 32
     # a budget between the two shrinks the panel instead of failing
-    assert autotune.estimate_vmem_bytes("fused", tk=8, C2=16, limit=short,
+    assert autotune.estimate_vmem_bytes(tk=8, C2=16, limit=short,
                                         **kw) == short
     # a streaming schedule's panel is its chunk
-    assert autotune.estimate_vmem_bytes("fused", tk=8, C2=16, lchunk=8,
-                                        **kw) < base
-    dense = autotune.estimate_vmem_bytes("dense", tk=8, tl=16, tj=32, C2=16,
-                                         **kw)
-    assert dense > 0
+    stream = autotune.estimate_vmem_bytes(tk=8, C2=16, lchunk=8, **kw)
+    assert stream < base
+    # bf16 storage halves the staged (2, tk, J) window block
+    assert stream - autotune.estimate_vmem_bytes(
+        tk=8, C2=16, lchunk=8, precision="bf16", **kw) == 2 * 2 * 8 * 32
 
 
 def test_vmem_limit_env_override(monkeypatch):
@@ -269,10 +271,10 @@ def test_autotune_skips_over_budget_lane_candidates(tmp_path):
     the 8-lane kernel."""
     plan = batched.build_plan(8, dtype=jnp.float32, pad_to=4)
     K, L, J = plan.d.shape
-    tks = [c["tk"] for c in autotune.candidate_tiles(K, L, J, "fused")]
-    limit = autotune.estimate_vmem_bytes("fused", tk=min(tks), C2=16,
-                                         L=L, J=J, itemsize=4)
-    cfg = autotune.autotune_dwt(plan, "fused", Vs=(1, 8), reps=1,
+    tks = autotune.candidate_tiles(K)
+    limit = autotune.estimate_vmem_bytes(tk=min(tks), C2=16, L=L, J=J,
+                                         itemsize=4)
+    cfg = autotune.autotune_dwt(plan, Vs=(1, 8), reps=1,
                                 cache=tmp_path / "c.json", vmem_limit=limit)
     assert cfg["V"] == 1 and cfg["tk"] == min(tks)
 
@@ -280,5 +282,5 @@ def test_autotune_skips_over_budget_lane_candidates(tmp_path):
 def test_autotune_all_candidates_over_budget_raises(tmp_path):
     plan = batched.build_plan(8, dtype=jnp.float32, pad_to=4)
     with pytest.raises(RuntimeError, match="VMEM"):
-        autotune.autotune_dwt(plan, "fused", Vs=(8,), reps=1,
+        autotune.autotune_dwt(plan, Vs=(8,), reps=1,
                               cache=tmp_path / "c.json", vmem_limit=1)

@@ -6,10 +6,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import batched, quadrature, soft, wigner
-from repro.kernels import dwt as dwt_k
+from repro.core import batched, soft
 from repro.kernels import folded_attention as fa
-from repro.kernels import ops, ref, wigner_rec
+from repro.kernels import ops, ref
 
 
 RNG = np.random.default_rng(0)
@@ -20,83 +19,8 @@ def rand(shape, dtype=np.float32, scale=1.0):
 
 
 # ---------------------------------------------------------------------------
-# DWT kernels
+# the Wigner recurrence: oracle and fused-kernel precision
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("K,L,J,C2,tk,tl,tj", [
-    (4, 8, 16, 16, 2, 4, 8),
-    (8, 16, 32, 16, 8, 16, 32),   # single tile in l/j
-    (6, 32, 64, 8, 3, 8, 16),     # uneven tile counts
-    (2, 8, 16, 2, 1, 8, 16),
-])
-@pytest.mark.parametrize("dtype", [np.float32, np.float64])
-def test_dwt_dense_sweep(K, L, J, C2, tk, tl, tj, dtype):
-    d = rand((K, L, J), dtype)
-    rhs = rand((K, J, C2), dtype)
-    out = dwt_k.dwt_dense(d, rhs, tk=tk, tl=tl, tj=tj)
-    expect = ref.dwt_ref(d, rhs)
-    tol = 1e-5 if dtype == np.float32 else 1e-12
-    np.testing.assert_allclose(out, expect, rtol=tol, atol=tol * 10)
-
-
-@pytest.mark.parametrize("K,L,J,C2,tk,tl,tj", [
-    (4, 8, 16, 16, 2, 4, 8),
-    (6, 32, 64, 8, 3, 8, 16),
-])
-def test_idwt_dense_sweep(K, L, J, C2, tk, tl, tj):
-    d = rand((K, L, J))
-    lhs = rand((K, L, C2))
-    out = dwt_k.idwt_dense(d, lhs, tk=tk, tl=tl, tj=tj)
-    np.testing.assert_allclose(out, ref.idwt_ref(d, lhs), rtol=1e-5,
-                               atol=1e-4)
-
-
-def test_dwt_ragged_skips_and_matches():
-    """Ragged work-list schedule: correct on valid blocks AND provably
-    skips the l < m zero-triangle."""
-    B = 16
-    plan = batched.build_plan(B, dtype=jnp.float32, pad_to=8)
-    K, L, J = plan.d.shape
-    tk, tl = 8, 4
-    perm, l_start, kk, ll, n_dense = ops._ragged_metadata(plan, tk, tl)
-    assert len(kk) < n_dense  # the schedule actually skips blocks
-
-    rhs = rand((K, J, 16))
-    out = dwt_k.dwt_ragged(np.asarray(plan.d)[perm], rhs[perm], kk, ll,
-                           tk=tk, tl=tl, tj=J)
-    out = np.asarray(out)[np.argsort(perm)]
-    mask = np.arange(L)[None, :] >= l_start[:, None]
-    out = np.where(mask[:, :, None], out, 0.0)
-    expect = np.asarray(ref.dwt_ref(plan.d, rhs))
-    expect = np.where(mask[:, :, None], expect, 0.0)
-    np.testing.assert_allclose(out, expect, rtol=1e-5, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# on-the-fly Wigner recurrence kernels
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,tk", [(8, 4), (16, 8)])
-def test_wigner_onthefly_forward(B, tk):
-    plan = batched.build_plan(B, dtype=jnp.float64, pad_to=tk)
-    seeds, m, mp, cb = ops.onthefly_inputs(plan)
-    K, J = seeds.shape
-    rhs = rand((K, J, 16), np.float64)
-    out = wigner_rec.dwt_onthefly(seeds, m, mp, cb, rhs, B=B, tk=tk)
-    expect = ref.dwt_ref(plan.d, rhs)
-    np.testing.assert_allclose(out, expect, rtol=1e-10, atol=1e-10)
-
-
-@pytest.mark.parametrize("B,tk", [(8, 4)])
-def test_wigner_onthefly_inverse(B, tk):
-    plan = batched.build_plan(B, dtype=jnp.float64, pad_to=tk)
-    seeds, m, mp, cb = ops.onthefly_inputs(plan)
-    K, J = seeds.shape
-    lhs = rand((K, B, 16), np.float64)
-    out = wigner_rec.idwt_onthefly(seeds, m, mp, cb, lhs, B=B, tk=tk)
-    expect = ref.idwt_ref(plan.d, lhs)
-    np.testing.assert_allclose(out, expect, rtol=1e-10, atol=1e-10)
-
 
 def test_wigner_rec_ref_matches_table():
     """The jnp recurrence oracle itself reproduces the host f64 table."""
@@ -107,45 +31,51 @@ def test_wigner_rec_ref_matches_table():
     np.testing.assert_allclose(tab, plan.d, rtol=1e-11, atol=1e-12)
 
 
-def test_wigner_onthefly_f32_accuracy():
-    """f32 on-the-fly recurrence vs f64 table: documented precision ladder
-    step (DESIGN.md Sec. 8)."""
-    B = 32
+def test_fused_f32_accuracy():
+    """The fused kernel's f32 recurrence against the same kernel in f64:
+    the documented f32 drift at B = 16 stays at the f32 rung."""
+    B = 16
     plan64 = batched.build_plan(B, dtype=jnp.float64, pad_to=8)
     plan32 = batched.build_plan(B, dtype=jnp.float32, pad_to=8)
-    seeds, m, mp, cb = ops.onthefly_inputs(plan32)
-    K, J = seeds.shape
-    rhs = rand((K, J, 16), np.float32, scale=0.1)
-    out32 = wigner_rec.dwt_onthefly(seeds, m, mp, cb, rhs, B=B, tk=8)
-    out64 = ref.dwt_ref(plan64.d, rhs.astype(np.float64))
+    K, J = plan32.n_padded, 2 * B
+    rhs = rand((K, J, 8, 2), np.float32, scale=0.1)
+    out32 = ops.make_dwt_fn(plan32, tk=8)(plan32, rhs)
+    out64 = ops.make_dwt_fn(plan64, tk=8)(plan64, rhs.astype(np.float64))
     err = np.abs(np.asarray(out32) - np.asarray(out64)).max()
-    assert err < 5e-4, err
+    assert 0 < err < 5e-4, err
 
 
 # ---------------------------------------------------------------------------
 # integration: kernels inside the full transform
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["dense", "ragged", "onthefly"])
-def test_forward_clustered_with_kernel(impl):
+# the fused family's members at B = 8: the monolithic kernel and the
+# streaming kernel at two l-chunks
+MEMBERS = [pytest.param({}, id="monolithic"),
+           pytest.param({"lchunk": 4}, id="streaming-4"),
+           pytest.param({"lchunk": 2}, id="streaming-2")]
+
+
+@pytest.mark.parametrize("member", MEMBERS)
+def test_forward_clustered_with_kernel(member):
     B = 8
     plan = batched.build_plan(B, dtype=jnp.float64, pad_to=8)
     fhat = soft.random_coeffs(B, 11)
     f = batched.inverse_clustered(plan, fhat)
     back_ref = np.asarray(batched.forward_clustered(plan, f))
-    dwt_fn = ops.make_dwt_fn(plan, impl, tk=4, tl=4, tj=16)
+    dwt_fn = ops.make_dwt_fn(plan, tk=4, **member)
     back_kernel = np.asarray(batched.forward_clustered(plan, f, dwt_fn=dwt_fn))
     np.testing.assert_allclose(back_kernel, back_ref, rtol=1e-9, atol=1e-10)
     np.testing.assert_allclose(back_kernel, fhat, rtol=1e-8, atol=1e-9)
 
 
-@pytest.mark.parametrize("impl", ["dense", "onthefly"])
-def test_inverse_clustered_with_kernel(impl):
+@pytest.mark.parametrize("member", MEMBERS)
+def test_inverse_clustered_with_kernel(member):
     B = 8
     plan = batched.build_plan(B, dtype=jnp.float64, pad_to=8)
     fhat = soft.random_coeffs(B, 12)
     f_ref = np.asarray(batched.inverse_clustered(plan, fhat))
-    idwt_fn = ops.make_idwt_fn(plan, impl, tk=4, tl=4, tj=16)
+    idwt_fn = ops.make_idwt_fn(plan, tk=4, **member)
     f_kernel = np.asarray(batched.inverse_clustered(plan, fhat,
                                                     idwt_fn=idwt_fn))
     np.testing.assert_allclose(f_kernel, f_ref, rtol=1e-9, atol=1e-10)

@@ -40,9 +40,11 @@ def test_recorder_spans_counters_quantiles():
 
 
 def test_span_lands_on_the_profiler_host_plane(tmp_path):
-    """Under a jax.profiler capture a Recorder span is also a host event
-    of the same name, on the profiler's clock, as long as the Recorder's
-    own reading (within 10%, or 50 us)."""
+    """Under a jax.profiler capture a Recorder span is also one host event
+    of the same name, on the profiler's clock.  The annotation encloses
+    the Recorder's own reading, which covers the 20 ms sleep; the two
+    differ by the span's bookkeeping, which host scheduling stretches by
+    a few ms at most, never by the factor 1000 of a wrong unit."""
     import jax
     from jax.profiler import ProfileData
 
@@ -57,7 +59,7 @@ def test_span_lands_on_the_profiler_host_plane(tmp_path):
            for e in line.events if e.name == "obs.test.sleep"]
     assert len(got) == 1
     dur_ns = rec.events()[0]["dur"] * 1e3
-    assert abs(got[0] - dur_ns) <= max(0.1 * dur_ns, 50e3)
+    assert 20e6 <= dur_ns <= got[0] <= dur_ns + 5e6, (got[0], dur_ns)
 
 
 def test_recorder_memory_is_bounded():

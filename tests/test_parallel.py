@@ -70,7 +70,7 @@ def test_shard_balanced_layout_invariants(n_shards):
     # minimal padding: fewer than n_shards zero rows
     assert plan.n_padded - plan.n_clusters < n_shards
     # (a) extent-sorted WITHIN each shard: ascending l-start rows, so
-    # every local block supports bucketed/ragged l-truncation
+    # every local block supports the fused kernels' per-tile l-truncation
     for s in range(n_shards):
         assert (np.diff(per_shard[s]) >= 0).all(), f"shard {s} unsorted"
     # (b) work-balanced ACROSS shards: total contraction rows per shard
@@ -159,13 +159,6 @@ def test_dist_executor_memoized_and_validates():
         (0, plan.n_padded, B, C)
     assert ex.inverse_batch(np.zeros((0, plan.n_padded, B, C))).shape == \
         (0, 2 * B, 2 * B, 2 * B)
-
-
-def test_autotune_mesh_key_requires_recurrence_impl():
-    from repro.kernels import autotune
-    plan = _balanced_plan(8, 2)
-    with pytest.raises(ValueError, match="onthefly"):
-        autotune.autotune_dwt(plan, "dense", n_shards=2)
 
 
 # ---------------------------------------------------------------------------

@@ -247,12 +247,42 @@ def test_build_smoke_program_small_b():
 # roundtrip for every schedule the planner can select
 # ---------------------------------------------------------------------------
 
+# each schedule the planner can select: the reference, the monolithic
+# fused kernel, the streaming member at B/2-row chunks, and a window-built
+# (d-free) plan
+SCHEDULES = {"reference": dict(impl="reference"),
+             "fused": dict(impl="fused"),
+             "fused-lchunk": dict(impl="fused", lchunk="B/2"),
+             "fused-streaming": dict(impl="fused", streaming=True)}
+
+
 @pytest.mark.parametrize("B", [4, 8, 16])
-@pytest.mark.parametrize("impl", plan_mod.IMPLS)
+@pytest.mark.parametrize("impl", list(SCHEDULES))
 def test_roundtrip_every_impl(B, impl):
-    t = plan_mod.plan(B, impl=impl, V=1, tk=4)
-    assert t.impl == impl
+    kw = dict(SCHEDULES[impl])
+    if kw.get("lchunk") == "B/2":
+        kw["lchunk"] = B // 2
+    t = plan_mod.plan(B, V=1, tk=4, **kw)
+    assert plan_mod.IMPLS == ("reference", "fused")
+    assert t.impl == kw["impl"]
+    assert t.schedule.lchunk == kw.get("lchunk")
+    assert t.soft_plan.streaming == kw.get("streaming", False)
     assert roundtrip_err(t, seed=B) < 1e-11
+
+
+@pytest.mark.parametrize("removed", [
+    dict(impl="dense"), dict(impl="ragged"), dict(impl="onthefly"),
+    dict(tl=4), dict(tj=16), dict(n_buckets=4)],
+    ids=["dense", "ragged", "onthefly", "tl", "tj", "n_buckets"])
+def test_removed_schedules_and_tiles_are_refused(removed):
+    """The dense, ragged and on-the-fly schedules and the tiles and
+    buckets only they used are gone: naming them fails loudly."""
+    if "impl" in removed:
+        with pytest.raises(ValueError, match=r"\('reference', 'fused'\)"):
+            plan_mod.plan(8, **removed)
+    else:
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            plan_mod.plan(8, **removed)
 
 
 def test_plan_matches_dense_oracle_bitwise_tolerances():
@@ -370,8 +400,8 @@ def test_shim_parity_with_old_entry_points():
     # old layer-by-layer path with the identical configuration
     old_plan = batched.build_plan(B, dtype=jnp.float64, pad_to=4)
     assert old_plan is t.soft_plan               # one plan, every consumer
-    idwt = ops.make_idwt_fn(old_plan, "fused", tk=4)
-    dwt = ops.make_dwt_fn(old_plan, "fused", tk=4)
+    idwt = ops.make_idwt_fn(old_plan, tk=4)
+    dwt = ops.make_dwt_fn(old_plan, tk=4)
     f_old = np.asarray(batched.inverse_clustered(old_plan, fhat,
                                                  idwt_fn=idwt))
     np.testing.assert_array_equal(np.asarray(t.inverse(fhat)), f_old)

@@ -48,22 +48,6 @@ def test_cluster_table_partitions_orders(B):
 
 
 @settings(max_examples=10, deadline=None)
-@given(st.integers(3, 16), st.integers(1, 4), st.integers(1, 6))
-def test_bucketed_dwt_exact(B, n_shards, n_buckets):
-    """Extent-bucketed DWT == plain contraction for any shard/bucket split."""
-    order = batched.shard_balanced_order(
-        clusters.build_cluster_table(B).rep[:, 0], n_shards)
-    plan = batched.build_plan(B, pad_to=n_shards, order=order)
-    rng = np.random.default_rng(B)
-    rhs = jnp.asarray(rng.normal(size=(plan.n_padded, 2 * B, 8, 2)))
-    plain = batched.dwt_apply(plan, rhs)
-    bucketed = batched.make_bucketed_dwt_fn(plan, n_shards, n_buckets)(
-        plan, rhs)
-    np.testing.assert_allclose(np.asarray(bucketed), np.asarray(plain),
-                               rtol=1e-12, atol=1e-12)
-
-
-@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 10**6), st.integers(1, 4), st.integers(8, 64))
 def test_moe_dispatch_conserves_tokens(seed, top_k, T):
     """Every kept (token, slot) lands in exactly one expert buffer cell and

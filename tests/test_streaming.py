@@ -101,15 +101,20 @@ def test_bf16_within_error_table_gate():
 
 
 @pytest.mark.parametrize("B", [8, 16, 32])
-def test_fp32_roundtrip_within_recorded_bounds(B):
+@pytest.mark.parametrize("member", ["monolithic", "streaming"])
+def test_fp32_roundtrip_within_recorded_bounds(B, member):
     """Accuracy-regression guard for the in-kernel f32 Wigner recurrence
     drift (ROADMAP's fp32 accuracy cliff: ~2.2e-3 in d by l = 127 at
     B = 128).  The measured fp32 fused roundtrip max-rel per bandwidth is
     recorded with headroom in autotune.FP32_ROUNDTRIP_BOUNDS; a
     recurrence/seed change that worsens the drift trips this gate instead
-    of silently degrading f32 serving accuracy."""
+    of silently degrading f32 serving accuracy.  The streaming member
+    (B/2-row chunks, resumed from two-row windows) meets the same gate."""
     bound = autotune.FP32_ROUNDTRIP_BOUNDS[B]
-    t32 = plan_mod.plan(B, dtype=jnp.float32, impl="fused", tk=4)
+    lchunk = B // 2 if member == "streaming" else None
+    t32 = plan_mod.plan(B, dtype=jnp.float32, impl="fused", tk=4,
+                        lchunk=lchunk)
+    assert t32.schedule.lchunk == lchunk
     mask = soft.coeff_mask(B)
     worst = 0.0
     for seed in range(3):
@@ -175,7 +180,7 @@ def test_bf16_schedule_records_the_streaming_kernel():
     K, L, J = t.soft_plan.d.shape
     C = t.soft_plan.gather_m.shape[1]
     assert s.vmem_bytes == autotune.estimate_vmem_bytes(
-        "fused", L=L, J=J, C2=s.V * C * 2, tk=s.tk, itemsize=4,
+        L=L, J=J, C2=s.V * C * 2, tk=s.tk, itemsize=4,
         lchunk=s.lchunk, precision="bf16")
     # and the plan matches its explicitly-chunked twin bit for bit
     tw = plan_mod.plan(16, dtype=jnp.float32, impl="fused", V=2, tk=4,
@@ -230,15 +235,18 @@ def test_window_table_rejects_bad_lchunk():
 # ---------------------------------------------------------------------------
 
 def test_streaming_args_rejected_off_fused():
-    assert ops._check_streaming_args("fused", 2, None) is True
-    assert ops._check_streaming_args("fused", None, "bf16") is True
-    assert ops._check_streaming_args("dense", None, None) is False
-    with pytest.raises(ValueError, match="fused"):
-        ops._check_streaming_args("dense", 2, None)
+    """lchunk and bf16 select the streaming kernels; impl="reference" has
+    none and refuses them."""
+    assert ops._check_streaming_args(2, None) is True
+    assert ops._check_streaming_args(None, "bf16") is True
+    assert ops._check_streaming_args(None, "fp32") is False
+    assert ops._check_streaming_args(None, None) is False
     with pytest.raises(ValueError, match="precision"):
-        ops._check_streaming_args("fused", None, "fp16")
+        ops._check_streaming_args(None, "fp16")
     with pytest.raises(ValueError, match="fused"):
         plan_mod.plan(8, impl="reference", lchunk=2)
+    with pytest.raises(ValueError, match="fused"):
+        plan_mod.plan(8, impl="reference", precision="bf16")
     with pytest.raises(ValueError, match="precision"):
         plan_mod.plan(8, impl="fused", precision="fp16")
     with pytest.raises(ValueError, match="divide"):
@@ -296,7 +304,7 @@ def test_describe_reports_streaming_fields_and_live_memory_drop():
 
 # ---------------------------------------------------------------------------
 # window-built (d-free) plans: bitwise parity with dense-built plans for
-# every recurrence-capable ladder impl, loud guards on dense-only consumers
+# every member of the fused family, loud guards on dense-only consumers
 # ---------------------------------------------------------------------------
 
 STREAM_LADDER = [
@@ -305,7 +313,8 @@ STREAM_LADDER = [
                  id="fused-lchunk"),
     pytest.param(dict(impl="fused", dtype=jnp.float32, lchunk=2,
                       precision="bf16"), id="fused-bf16"),
-    pytest.param(dict(impl="onthefly", dtype=jnp.float64), id="onthefly"),
+    pytest.param(dict(impl="fused", dtype=jnp.float64, V=4),
+                 id="fused-lanes"),
 ]
 
 
@@ -314,16 +323,27 @@ STREAM_LADDER = [
 def test_window_built_plan_bitwise_equals_dense_built(B, cfg):
     """streaming=True builds the plan without ever materializing the
     dense (K, L, J) d table -- and the result must be bitwise-identical
-    to the dense-built plan under every recurrence-capable kernel,
-    forward AND inverse (the PR's core acceptance criterion)."""
+    to the dense-built plan under every member of the fused family,
+    forward AND inverse (the PR's core acceptance criterion).  The V = 4
+    case runs the lane path: forward_batch and inverse_lanes."""
     kw = dict(cfg)
     dtype = kw.pop("dtype")
-    td = plan_mod.plan(B, dtype=dtype, V=1, tk=4, **kw)
-    ts = plan_mod.plan(B, dtype=dtype, V=1, tk=4, streaming=True, **kw)
+    V = kw.pop("V", 1)
+    td = plan_mod.plan(B, dtype=dtype, V=V, tk=4, **kw)
+    ts = plan_mod.plan(B, dtype=dtype, V=V, tk=4, streaming=True, **kw)
     assert not td.soft_plan.streaming and td.soft_plan.d is not None
     assert ts.soft_plan.streaming and ts.soft_plan.d is None
     assert ts.soft_plan.dtype == td.soft_plan.dtype
     cd = np.complex64 if dtype == jnp.float32 else np.complex128
+    if V > 1:
+        fhats = jnp.stack([soft.random_coeffs(B, seed=B + v).astype(cd)
+                           for v in range(V)])
+        f = np.asarray(td.inverse_lanes(fhats))
+        np.testing.assert_array_equal(np.asarray(ts.inverse_lanes(fhats)), f)
+        np.testing.assert_array_equal(
+            np.asarray(ts.forward_batch(jnp.asarray(f))),
+            np.asarray(td.forward_batch(jnp.asarray(f))))
+        return
     fhat = soft.random_coeffs(B, seed=B).astype(cd)
     f = np.asarray(td.inverse(fhat))
     np.testing.assert_array_equal(np.asarray(ts.inverse(fhat)), f)
@@ -356,31 +376,29 @@ def test_window_built_plan_padded_permuted_order():
                                   np.asarray(pd.gather_m))
     fhat = jnp.asarray(soft.random_coeffs(B, seed=11))
     f_d = np.asarray(batched.inverse_clustered(
-        pd, fhat, idwt_fn=ops.make_idwt_fn(pd, "fused", tk=4)))
+        pd, fhat, idwt_fn=ops.make_idwt_fn(pd, tk=4)))
     f_s = np.asarray(batched.inverse_clustered(
-        ps, fhat, idwt_fn=ops.make_idwt_fn(ps, "fused", tk=4)))
+        ps, fhat, idwt_fn=ops.make_idwt_fn(ps, tk=4)))
     np.testing.assert_array_equal(f_s, f_d)
     b_d = np.asarray(batched.forward_clustered(
-        pd, jnp.asarray(f_d), dwt_fn=ops.make_dwt_fn(pd, "fused", tk=4)))
+        pd, jnp.asarray(f_d), dwt_fn=ops.make_dwt_fn(pd, tk=4)))
     b_s = np.asarray(batched.forward_clustered(
-        ps, jnp.asarray(f_s), dwt_fn=ops.make_dwt_fn(ps, "fused", tk=4)))
+        ps, jnp.asarray(f_s), dwt_fn=ops.make_dwt_fn(ps, tk=4)))
     np.testing.assert_array_equal(b_s, b_d)
 
 
 def test_streaming_plan_rejects_dense_only_consumers():
     sp = batched.build_plan(8, dtype=jnp.float64, pad_to=4, streaming=True)
-    for consumer in (lambda: ops.make_dwt_fn(sp, "dense", tk=4),
-                     lambda: ops.make_dwt_fn(sp, "ragged", tk=4),
-                     lambda: ops.make_idwt_fn(sp, "dense", tk=4),
-                     lambda: batched.dwt_apply(sp, jnp.zeros(())),
+    for consumer in (lambda: batched.dwt_apply(sp, jnp.zeros(())),
                      lambda: batched.idwt_apply(sp, jnp.zeros(())),
-                     lambda: batched.make_bucketed_dwt_fn(sp)):
+                     lambda: batched.forward_clustered(
+                         sp, jnp.zeros((16, 16, 16))),
+                     lambda: batched.inverse_clustered(
+                         sp, jnp.zeros((8, 15, 15)))):
         with pytest.raises(ValueError, match="streaming"):
             consumer()
     with pytest.raises(ValueError, match="streaming"):
         plan_mod.plan(8, impl="reference", streaming=True)
-    with pytest.raises(ValueError, match="streaming"):
-        plan_mod.plan(8, impl="dense", streaming=True)
 
 
 def test_host_window_stack_matches_device_windows(monkeypatch):

@@ -90,8 +90,8 @@ def test_fused_kernel_compiles_with_short_panels(one_chip, direction):
     kernel closes four panels per grid step."""
     B, P = 128, 32
     K, tk, _ = _schedule(B)
-    limit = autotune.estimate_vmem_bytes("fused", L=B, J=2 * B, C2=16,
-                                         tk=tk, panel=P)
+    limit = autotune.estimate_vmem_bytes(L=B, J=2 * B, C2=16, tk=tk,
+                                         panel=P)
     assert autotune.panel_depth(L=B, J=2 * B, C2=16, tk=tk,
                                 limit=limit) == P
     fn = dwt_fused.dwt_fused if direction == "fwd" else dwt_fused.idwt_fused
