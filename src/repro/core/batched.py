@@ -3,14 +3,15 @@
 This module reshapes the paper's parallel design into dense array programs:
 the whole DWT stage (all clusters) becomes ONE batched contraction
 
-    forward :  out[k, l, c] = sum_j  d[k, l, j] * rhs[k, j, c]
-    inverse :  g[k, j, c]   = sum_l  d[k, l, j] * lhs[k, l, c]
+    forward :  out[k, c, l] = sum_j  d[k, l, j] * rhs[k, c, j]
+    inverse :  g[k, c, j]   = sum_l  d[k, l, j] * lhs[k, c, l]
 
 where k runs over symmetry clusters (paper's work packages, kappa-ordered),
-c over the <= 8 cluster members, and d is the fundamental-domain Wigner
-table.  Gather/sign metadata comes from :mod:`clusters`; the inverse
-index tables that place members into FFT bins and dense coefficients are
-built with the plan (:func:`member_sources`).
+c over the <= 8 cluster members' real and imaginary rows, and d is the
+fundamental-domain Wigner table.  Gather/sign metadata comes from
+:mod:`clusters`; the inverse index tables that place members into FFT
+bins and dense coefficients are built with the plan
+(:func:`member_sources`).
 
 The same plan drives
   * the pure-jnp path below (runs anywhere, differentiable),
@@ -18,9 +19,15 @@ The same plan drives
   * the fused Pallas DWT kernels (:mod:`repro.kernels.dwt_fused`) -- grid
     over k tiles, Wigner rows generated on the fly.
 
-Complex arithmetic is carried as a trailing real/imag axis so the heavy
-contraction is a real matmul (MXU-friendly; complex einsum would promote the
-real Wigner operand and double the FLOPs).
+Complex arithmetic is carried as real MEMBER ROWS so the heavy contraction
+is a real matmul (MXU-friendly; complex einsum would promote the real
+Wigner operand and double the FLOPs).  A cluster's operand is (C*2, A):
+member c's real part in row 2c, its imaginary part in row 2c+1, and the
+beta samples (A = J) or degrees (A = L) along the row.  On a TPU the C*2
+rows sit on the sublanes and A on the 128 lanes, so every array between
+the grid stages and the kernels is dense under the (8, 128) tiling; and
+(K, C*2, A) is, byte for byte, the (K*C, 2A) [Re | Im] rows that the
+placement gathers read.
 """
 from __future__ import annotations
 
@@ -37,6 +44,7 @@ from . import quadrature, soft, wigner
 
 __all__ = ["SoftPlan", "build_plan", "plan_cache_stats",
            "fft_analysis_slab", "streamed_rhs", "streamed_synthesis",
+           "member_rows",
            "forward_clustered", "inverse_clustered",
            "forward_clustered_batch", "inverse_clustered_batch"]
 
@@ -380,9 +388,7 @@ def _gather_rhs_slab(plan: SoftPlan, S_direct, S_mirror, j0: int, j1: int):
     direct = S_direct[plan.gather_m, :, plan.gather_mp]       # (K, C, js)
     mirror = S_mirror[plan.gather_m, :, plan.gather_mp][..., ::-1]
     Sm = jnp.where(plan.reflected[..., None], mirror, direct)
-    Sm = Sm * (plan.sign[..., None] * plan.w[None, None, j0:j1])
-    rhs = jnp.stack([Sm.real, Sm.imag], axis=-1)              # (K, C, js, 2)
-    return jnp.swapaxes(rhs, 1, 2)                            # (K, js, C, 2)
+    return member_rows(Sm * (plan.sign[..., None] * plan.w[None, None, j0:j1]))
 
 
 def streamed_rhs(plan: SoftPlan, f):
@@ -394,19 +400,20 @@ def streamed_rhs(plan: SoftPlan, f):
         S_direct = fft_analysis_slab(f, j0, j1)
         S_mirror = fft_analysis_slab(f, J - j1, J - j0)
         parts.append(_gather_rhs_slab(plan, S_direct, S_mirror, j0, j1))
-    return jnp.concatenate(parts, axis=1)
+    return jnp.concatenate(parts, axis=2)
 
 
-def streamed_synthesis(plan: SoftPlan, gc):
+def streamed_synthesis(plan: SoftPlan, g):
     """Bin placement + FFT-synthesis, streamed in beta slabs: bitwise
-    equal to fft_synthesis(_place_bins(plan, gc)) without the monolithic
-    (2B, 2B, 2B) bin buffer."""
+    equal to fft_synthesis(_place_bins(plan, g)) without the monolithic
+    (2B, 2B, 2B) bin buffer.  g: the iDWT's member rows (K, C*2, J)."""
     J = 2 * plan.B
+    refl = _row_mask(plan.reflected)
     parts = []
     for j0, j1 in _slab_bounds(J):
-        direct = gc[:, j0:j1, :]
-        mirror = gc[:, J - j1:J - j0, :][:, ::-1, :]
-        gs = jnp.where(plan.reflected[:, None, :], mirror, direct)
+        direct = g[:, :, j0:j1]
+        mirror = g[:, :, J - j1:J - j0][..., ::-1]
+        gs = jnp.where(refl, mirror, direct)
         parts.append(fft_synthesis(_place_bins_nomirror(plan, gs)))
     return jnp.concatenate(parts, axis=1)
 
@@ -415,96 +422,106 @@ def streamed_synthesis(plan: SoftPlan, gc):
 # stage 2: clustered DWT (forward) / iDWT (inverse)
 # ---------------------------------------------------------------------------
 
-def _gather_rhs(plan: SoftPlan, S):
-    """Build rhs[k, j, c, ri] from S[mbin, j, m'bin] (complex).
+def member_rows(x):
+    """Complex members (K, C, A) -> real member rows (K, C*2, A): member
+    c's real part in row 2c, its imaginary part in row 2c+1."""
+    K, C, A = x.shape
+    return jnp.stack([x.real, x.imag], axis=2).reshape(K, 2 * C, A)
 
-    rhs column c of cluster k = sign * w * S(member), with j reversed for
+
+def _row_mask(mask):
+    """A per-member (K, C) mask -> (K, C*2, 1), over member rows."""
+    return jnp.repeat(mask, 2, axis=1)[:, :, None]
+
+
+def _gather_rhs(plan: SoftPlan, S):
+    """Build the DWT's member rows rhs (K, C*2, J) from S[mbin, j, m'bin]
+    (complex).
+
+    Member c of cluster k reads sign * w * S(member), with j reversed for
     beta-reflected members.
     """
     # S gathered at member bins: (K, C, J) complex
     Sm = S[plan.gather_m, :, plan.gather_mp]
     Sm = jnp.where(plan.reflected[..., None], Sm[..., ::-1], Sm)
-    Sm = Sm * (plan.sign[..., None] * plan.w[None, None, :])
-    rhs = jnp.stack([Sm.real, Sm.imag], axis=-1)     # (K, C, J, 2)
-    return jnp.swapaxes(rhs, 1, 2)                    # (K, J, C, 2)
+    return member_rows(Sm * (plan.sign[..., None] * plan.w[None, None, :]))
 
 
 def dwt_apply(plan: SoftPlan, rhs):
-    """The clustered DWT contraction: (K,L,J) x (K,J,C,2) -> (K,L,C,2).
+    """The clustered DWT contraction: (K,L,J) x (K,C2,J) -> (K,C2,L).
 
     Kept as its own function: this is the compute hot-spot the fused
     Pallas kernels (kernels/dwt_fused.py) replace 1:1.
     """
     d = plan.require_dense("dwt_apply")
-    C2 = rhs.shape[2] * rhs.shape[3]
-    out = jnp.einsum("klj,kjc->klc", d,
-                     rhs.reshape(rhs.shape[0], rhs.shape[1], C2),
-                     preferred_element_type=d.dtype)
-    return out.reshape(out.shape[0], out.shape[1], rhs.shape[2], rhs.shape[3])
+    return jnp.einsum("klj,kcj->kcl", d, rhs, preferred_element_type=d.dtype)
 
 
 def idwt_apply(plan: SoftPlan, lhs):
-    """The clustered iDWT contraction: (K,L,J) x (K,L,C,2) -> (K,J,C,2)."""
+    """The clustered iDWT contraction: (K,L,J) x (K,C2,L) -> (K,C2,J)."""
     d = plan.require_dense("idwt_apply")
-    C2 = lhs.shape[2] * lhs.shape[3]
-    out = jnp.einsum("klj,klc->kjc", d,
-                     lhs.reshape(lhs.shape[0], lhs.shape[1], C2),
-                     preferred_element_type=d.dtype)
-    return out.reshape(out.shape[0], out.shape[1], lhs.shape[2], lhs.shape[3])
+    return jnp.einsum("klj,kcl->kcj", d, lhs, preferred_element_type=d.dtype)
 
 
 def place_members(src, rows):
-    """Gather complex member rows (N, n) into the cells of ``src``, an index
-    table of :func:`member_sources`: (len(src), n), 0 where a cell has no
-    member.  One gather moves both parts, each row laid out [Re | Im] so
-    that it is lane-dense."""
-    n = rows.shape[-1]
-    ri = jnp.concatenate([rows.real, rows.imag], axis=-1)      # (N, 2n)
-    out = ri.at[src].get(mode="fill", fill_value=0,
-                         wrap_negative_indices=False)
+    """Gather member rows (N, 2n), each laid out [Re | Im] so that it is
+    lane-dense, into the cells of ``src``, an index table of
+    :func:`member_sources`: complex (len(src), n), 0 where a cell has no
+    member.  One gather moves both parts."""
+    n = rows.shape[-1] // 2
+    out = rows.at[src].get(mode="fill", fill_value=0,
+                           wrap_negative_indices=False)
     return jax.lax.complex(out[:, :n], out[:, n:])
 
 
-def place_coeffs(plan: SoftPlan, out):
-    """out[k, l, c] (complex) -> dense coefficients (L, 2B-1, 2B-1)."""
+def place_coeffs(plan: SoftPlan, rows):
+    """Member rows (K, C*2, L) -> dense coefficients (L, 2B-1, 2B-1)."""
     n = 2 * plan.B - 1
-    rows = jnp.swapaxes(out, 1, 2).reshape(-1, out.shape[1])   # (K*C, L)
-    return place_members(plan.coeff_src, rows).T.reshape(-1, n, n)
+    K, C2, L = rows.shape
+    cells = place_members(plan.coeff_src, rows.reshape(K * C2 // 2, 2 * L))
+    return cells.T.reshape(-1, n, n)
 
 
 def _output_coeffs(plan: SoftPlan, out):
-    """out[k, l, c] (complex) -> the dense coefficient layout, with the
-    output sign (-1)^l of reflected members and scale (2l+1)/(8 pi B)."""
-    sgn = jnp.where(plan.reflected[:, None, :], plan.parity[None, :, None],
+    """The DWT's member rows (K, C*2, L) -> the dense coefficient layout,
+    with the output sign (-1)^l of reflected members and scale
+    (2l+1)/(8 pi B)."""
+    sgn = jnp.where(_row_mask(plan.reflected), plan.parity[None, None, :],
                     jnp.ones((), plan.parity.dtype))
-    return place_coeffs(plan, out * (sgn * plan.scale[None, :, None]))
+    return place_coeffs(plan, out * (sgn * plan.scale[None, None, :]))
 
 
 def _gather_coeffs(plan: SoftPlan, fhat):
-    """Gather lhs[k, l, c] = sign * (-1)^{l if reflected} * fhat(member)."""
-    B = plan.B
-    fpad = jnp.pad(fhat, ((0, 0), (0, 1), (0, 1)))   # trash cell reads 0
-    lhs = fpad[:, plan.scatter_m, plan.scatter_mp]   # (L, K, C)
-    lhs = jnp.moveaxis(lhs, 0, 1)                     # (K, L, C)
-    sgn = jnp.where(plan.reflected[:, None, :], plan.parity[None, :, None],
+    """The iDWT's member rows lhs (K, C*2, L): member c of cluster k reads
+    sign * (-1)^{l if reflected} * fhat(member).  A row gather from the
+    coefficients laid out as (m, m') rows of [Re | Im] over l."""
+    L, n = fhat.shape[0], fhat.shape[1]
+    K, C = plan.sign.shape
+    ft = fhat.reshape(L, n * n).T                             # (n*n, L)
+    rows = jnp.concatenate([ft.real, ft.imag], axis=-1)       # (n*n, 2L)
+    src = jnp.where(plan.sign != 0, plan.scatter_m * n + plan.scatter_mp,
+                    n * n)                                    # unused: fill
+    lhs = rows.at[src.reshape(-1)].get(mode="fill", fill_value=0,
+                                       wrap_negative_indices=False)
+    sgn = jnp.where(_row_mask(plan.reflected), plan.parity[None, None, :],
                     jnp.ones((), plan.parity.dtype))
-    lhs = lhs * (sgn * plan.sign[:, None, :])
-    return jnp.stack([lhs.real, lhs.imag], axis=-1)  # (K, L, C, 2)
+    return lhs.reshape(K, 2 * C, L) * (sgn * _row_mask(plan.sign))
 
 
 def _place_bins_nomirror(plan: SoftPlan, g):
-    """FFT bins (2B, j, 2B) of g[k, j, c] (complex, reflection already
-    applied): each bin gathers its member's column (plan.bin_src), the
-    Nyquist bins read 0.  j-independent, so slab callers pass partial-j g."""
+    """FFT bins (2B, j, 2B) of the iDWT's member rows g (K, C*2, j)
+    (reflection already applied): each bin gathers its member's row
+    (plan.bin_src), the Nyquist bins read 0.  j-independent, so slab
+    callers pass partial-j g."""
     n = 2 * plan.B
-    rows = jnp.swapaxes(g, 1, 2).reshape(-1, g.shape[1])       # (K*C, j)
-    bins = place_members(plan.bin_src, rows).reshape(n, n, -1)
-    return bins.transpose(0, 2, 1)
+    K, C2, j = g.shape
+    bins = place_members(plan.bin_src, g.reshape(K * C2 // 2, 2 * j))
+    return bins.reshape(n, n, j).transpose(0, 2, 1)
 
 
 def _place_bins(plan: SoftPlan, g):
-    """FFT bins (2B, j, 2B) of g[k, j, c] (complex)."""
-    g = jnp.where(plan.reflected[:, None, :], g[:, ::-1, :], g)
+    """FFT bins (2B, j, 2B) of the iDWT's member rows g (K, C*2, J)."""
+    g = jnp.where(_row_mask(plan.reflected), g[..., ::-1], g)
     return _place_bins_nomirror(plan, g)
 
 
@@ -516,9 +533,7 @@ def _place_bins(plan: SoftPlan, g):
 def _forward_jit(plan: SoftPlan, f):
     S = fft_analysis(f)
     rhs = _gather_rhs(plan, S)
-    out = dwt_apply(plan, rhs)
-    outc = out[..., 0] + 1j * out[..., 1]
-    return _output_coeffs(plan, outc)
+    return _output_coeffs(plan, dwt_apply(plan, rhs))
 
 
 def _require_recurrence_fn(plan: SoftPlan, fn, which: str):
@@ -541,18 +556,13 @@ def forward_clustered(plan: SoftPlan, f, dwt_fn=None):
         return _forward_jit(plan, f)
     rhs = streamed_rhs(plan, f) if plan.streaming \
         else _gather_rhs(plan, fft_analysis(f))
-    out = dwt_fn(plan, rhs)
-    outc = out[..., 0] + 1j * out[..., 1]
-    return _output_coeffs(plan, outc)
+    return _output_coeffs(plan, dwt_fn(plan, rhs))
 
 
 @partial(jax.jit, static_argnums=())
 def _inverse_jit(plan: SoftPlan, fhat):
     lhs = _gather_coeffs(plan, fhat)
-    g = idwt_apply(plan, lhs)
-    gc = g[..., 0] + 1j * g[..., 1]
-    gbin = _place_bins(plan, gc)
-    return fft_synthesis(gbin)
+    return fft_synthesis(_place_bins(plan, idwt_apply(plan, lhs)))
 
 
 def inverse_clustered(plan: SoftPlan, fhat, idwt_fn=None):
@@ -561,12 +571,10 @@ def inverse_clustered(plan: SoftPlan, fhat, idwt_fn=None):
     _require_recurrence_fn(plan, idwt_fn, "idwt_fn")
     if idwt_fn is None:
         return _inverse_jit(plan, fhat)
-    lhs = _gather_coeffs(plan, fhat)
-    g = idwt_fn(plan, lhs)
-    gc = g[..., 0] + 1j * g[..., 1]
+    g = idwt_fn(plan, _gather_coeffs(plan, fhat))
     if plan.streaming:
-        return streamed_synthesis(plan, gc)
-    return fft_synthesis(_place_bins(plan, gc))
+        return streamed_synthesis(plan, g)
+    return fft_synthesis(_place_bins(plan, g))
 
 
 # ---------------------------------------------------------------------------
@@ -578,9 +586,9 @@ def forward_clustered_batch(plan: SoftPlan, f, dwt_fn=None):
     2B-1).
 
     The FFT stage and the gather/scatter run vmapped (XLA batches them);
-    the DWT contraction takes the whole (V, K, J, C, 2) stack at once, so a
+    the DWT contraction takes the whole (V, K, C*2, J) stack at once, so a
     batch-aware dwt_fn (ops.make_dwt_fn(..., batch=V)) packs the V
-    transforms onto the kernel's lane axis and launches ONCE -- at V = 4
+    transforms onto the kernel's C2 axis and launches ONCE -- at V = 4
     the per-transform launch + Wigner-generation cost drops ~4x (the d-rows
     are reused across all V lanes).  dwt_fn=None falls back to a vmapped
     einsum (pure jnp, differentiable).
@@ -590,13 +598,12 @@ def forward_clustered_batch(plan: SoftPlan, f, dwt_fn=None):
         rhs = jax.vmap(lambda ff: streamed_rhs(plan, ff))(f)
     else:
         S = jax.vmap(fft_analysis)(f)
-        rhs = jax.vmap(lambda s: _gather_rhs(plan, s))(S)  # (V, K, J, C, 2)
+        rhs = jax.vmap(lambda s: _gather_rhs(plan, s))(S)  # (V, K, C2, J)
     if dwt_fn is None:
         out = jax.vmap(lambda r: dwt_apply(plan, r))(rhs)
     else:
-        out = dwt_fn(plan, rhs)                          # (V, K, L, C, 2)
-    outc = out[..., 0] + 1j * out[..., 1]
-    return jax.vmap(lambda o: _output_coeffs(plan, o))(outc)
+        out = dwt_fn(plan, rhs)                          # (V, K, C2, L)
+    return jax.vmap(lambda o: _output_coeffs(plan, o))(out)
 
 
 def inverse_clustered_batch(plan: SoftPlan, fhat, idwt_fn=None):
@@ -604,13 +611,12 @@ def inverse_clustered_batch(plan: SoftPlan, fhat, idwt_fn=None):
     2B).  idwt_fn must be batch-aware when given (ops.make_idwt_fn(...,
     batch=V)); see forward_clustered_batch."""
     _require_recurrence_fn(plan, idwt_fn, "idwt_fn")
-    lhs = jax.vmap(lambda h: _gather_coeffs(plan, h))(fhat)  # (V, K, L, C, 2)
+    lhs = jax.vmap(lambda h: _gather_coeffs(plan, h))(fhat)  # (V, K, C2, L)
     if idwt_fn is None:
         g = jax.vmap(lambda x: idwt_apply(plan, x))(lhs)
     else:
-        g = idwt_fn(plan, lhs)                            # (V, K, J, C, 2)
-    gc = g[..., 0] + 1j * g[..., 1]
+        g = idwt_fn(plan, lhs)                            # (V, K, C2, J)
     if plan.streaming:
-        return jax.vmap(lambda x: streamed_synthesis(plan, x))(gc)
-    gbin = jax.vmap(lambda x: _place_bins(plan, x))(gc)
+        return jax.vmap(lambda x: streamed_synthesis(plan, x))(g)
+    gbin = jax.vmap(lambda x: _place_bins(plan, x))(g)
     return jax.vmap(fft_synthesis)(gbin)

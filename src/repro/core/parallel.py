@@ -21,7 +21,7 @@ Pipeline (forward; inverse is the exact mirror):
            then the clustered DWT contraction runs entirely device-local
            (the paper's 'exclusive memory range' property).
 
-Batches ride the kernel's lane axis INSIDE the shard_map:
+Batches ride the kernel's member-row axis INSIDE the shard_map:
 ``forward_lanes`` / ``inverse_lanes`` take a (V, ...) transform stack,
 fold the V lanes into the contraction axis (C2 = V*C*2), and issue ONE
 all-to-all and one local-kernel launch for the whole stack -- V
@@ -81,7 +81,8 @@ from repro import obs
 
 from .compat import shard_map, shard_map_norep
 
-from .batched import SoftPlan, fft_analysis, fft_synthesis, place_coeffs
+from .batched import (SoftPlan, fft_analysis, fft_synthesis, member_rows,
+                      place_coeffs)
 
 __all__ = [
     "DistExecutor", "dist_executor", "check_mesh_compat",
@@ -176,8 +177,10 @@ class LocalDWT:
     operands: global arrays handed to the shard_map body before the
     rhs/lhs; cluster_sharded: per-operand flag (True -> sharded over the
     leading cluster axis, False -> replicated); fn(*local_operands, x2)
-    runs on each device's shard.  Forward contract: (Kloc, J, C2) rhs ->
-    (Kloc, L, C2); inverse: (Kloc, L, C2) lhs -> (Kloc, J, C2).
+    runs on each device's shard.  Forward contract: (Kloc, C2, J) rhs ->
+    (Kloc, C2, L); inverse: (Kloc, C2, L) lhs -> (Kloc, C2, J), the
+    member-row layout of :mod:`repro.core.batched` (C2 rows on the
+    sublanes, J or L on the lanes).
 
     The fused variants (make_fused_local_dwt/_idwt) carry recurrence seeds
     instead of plan.d, so NO Wigner-table shard enters the shard_map at all
@@ -315,7 +318,7 @@ class DistExecutor:
       forward_batch / inverse_batch       any count, chunked to lane_width
 
     Lane packing folds the V transforms into the local kernel's
-    contraction lane axis (C2 = V*C*2), so the fused kernel generates
+    member-row axis (C2 = V*C*2), so the fused kernel generates
     each on-the-fly Wigner row once per V transforms and the collective
     payload per transform is unchanged while the collective COUNT drops
     V-fold.
@@ -342,8 +345,8 @@ class DistExecutor:
             raise ValueError(f"lane_width must be >= 1, got {lane_width}")
         self.lane_width = int(lane_width)
         self.overlap = check_overlap_mode(overlap)
-        self._ld = _normalize_local_dwt(plan, local_dwt, "klj,kjc->klc")
-        self._lid = _normalize_local_dwt(plan, local_idwt, "klj,klc->kjc")
+        self._ld = _normalize_local_dwt(plan, local_dwt, "klj,kcj->kcl")
+        self._lid = _normalize_local_dwt(plan, local_idwt, "klj,kcl->kcj")
         self._calls: dict = {}
 
     @property
@@ -383,36 +386,35 @@ class DistExecutor:
 
                 def gather(s):
                     Sm = s[gm, :, gmp]                # (K, C, jloc)
-                    r = Sm * (sign[..., None] * w[None, None, :])
-                    r = jnp.stack([r.real, r.imag], -1)  # (K, C, jloc, 2)
-                    return jnp.swapaxes(r, 1, 2)      # (K, jloc, C, 2)
+                    return member_rows(Sm * (sign[..., None]
+                                             * w[None, None, :]))
 
-                rhs = jax.vmap(gather)(S)             # (V, K, jloc, C, 2)
-                V, K, jloc = rhs.shape[:3]
-                rhs = jnp.moveaxis(rhs, 0, 2)         # (K, jloc, V, C, 2)
-                return rhs.reshape(K, jloc, V * C * 2)
+                rhs = jax.vmap(gather, out_axes=1)(S)  # (K, V, C2, jloc)
+                K, V, C2, jloc = rhs.shape
+                return rhs.reshape(K, V * C2, jloc)
 
         def reshard(rhs):
             # ONE all-to-all reshards all V lanes together:
-            # (K, jloc, VC2) beta-sharded -> (K/n, jloc*n, VC2)
+            # (K, VC2, jloc) beta-sharded -> (K/n, VC2, jloc*n)
             with jax.named_scope("obs.all_to_all"):
                 return jax.lax.all_to_all(rhs, axis, split_axis=0,
-                                          concat_axis=1, tiled=True)
+                                          concat_axis=2, tiled=True)
 
         def stage2(rhs):
             # refl/scale applied post-reshard on the cluster shard
             with jax.named_scope("obs.local_kernel"):
-                Kn, jn = rhs.shape[0], rhs.shape[1]
-                V = rhs.shape[2] // (C * 2)
-                rhs = rhs.reshape(Kn, jn, V, C, 2)
-                rhs = jnp.where(refl[:, None, None, :, None],
-                                rhs[:, ::-1], rhs)
-                out = ld.fn(*dwt_ops, rhs.reshape(Kn, jn, V * C * 2))
-                out = out.reshape(*out.shape[:2], V, C, 2)
-                outc = out[..., 0] + 1j * out[..., 1]  # (Kloc, L, V, C)
-                outc = outc * (_refl_sign(refl, parity)[:, :, None, :]
-                               * scale[None, :, None, None])
-                return jnp.moveaxis(outc, 2, 0)       # (V, Kloc, L, C)
+                Kn, VC2, jn = rhs.shape
+                V = VC2 // (C * 2)
+                rhs = rhs.reshape(Kn, V, C, 2, jn)
+                rhs = jnp.where(refl[:, None, :, None, None],
+                                rhs[..., ::-1], rhs)
+                out = ld.fn(*dwt_ops, rhs.reshape(Kn, VC2, jn))
+                out = out.reshape(Kn, V, C, 2, -1)
+                outc = out[:, :, :, 0] + 1j * out[:, :, :, 1]  # (Kloc,V,C,L)
+                outc = jnp.swapaxes(outc, 2, 3)       # (Kloc, V, L, C)
+                outc = outc * (_refl_sign(refl, parity)[:, None]
+                               * scale[None, None, :, None])
+                return jnp.moveaxis(outc, 1, 0)       # (V, Kloc, L, C)
 
         return stage1, reshard, stage2
 
@@ -428,41 +430,40 @@ class DistExecutor:
             with jax.named_scope("obs.local_kernel"):
                 lhs = packed_loc * (_refl_sign(refl, parity)[None]
                                     * sign_sh[None, :, None, :])
-                lhs = jnp.stack([lhs.real, lhs.imag], -1)  # (V,Kloc,L,C,2)
-                V, Kloc, L = lhs.shape[:3]
-                lhs = jnp.moveaxis(lhs, 0, 2)          # (Kloc, L, V, C, 2)
-                g = ld.fn(*idwt_ops, lhs.reshape(Kloc, L, V * C * 2))
-                J = g.shape[1]
-                g = g.reshape(Kloc, J, V, C, 2)
-                g = jnp.where(refl[:, None, None, :, None], g[:, ::-1], g)
-                return g.reshape(Kloc, J, V * C * 2)
+                V, Kloc, L, _ = lhs.shape
+                lhs = jax.vmap(lambda x: member_rows(jnp.swapaxes(x, 1, 2)),
+                               out_axes=1)(lhs)        # (Kloc, V, C2, L)
+                g = ld.fn(*idwt_ops, lhs.reshape(Kloc, V * C * 2, L))
+                g = g.reshape(Kloc, V, C, 2, -1)
+                g = jnp.where(refl[:, None, :, None, None], g[..., ::-1], g)
+                return g.reshape(Kloc, V * C * 2, -1)
 
         def reshard(g):
             # ONE all-to-all reshards all V lanes together:
-            # (Kloc, J, VC2) cluster-sharded -> (K, jloc, VC2)
+            # (Kloc, VC2, J) cluster-sharded -> (K, VC2, jloc)
             with jax.named_scope("obs.all_to_all"):
-                return jax.lax.all_to_all(g, axis, split_axis=1,
+                return jax.lax.all_to_all(g, axis, split_axis=2,
                                           concat_axis=0, tiled=True)
 
         def stage2(g):
             # sign replicated: masks the global bin scatter post-reshard
             with jax.named_scope("obs.scatter_fft"):
-                K, jloc = g.shape[0], g.shape[1]
-                V = g.shape[2] // (C * 2)
-                g = g.reshape(K, jloc, V, C, 2)
-                gc = g[..., 0] + 1j * g[..., 1]        # (K, jloc, V, C)
-                # scatter member columns into FFT bins (unused -> bin 2B)
+                K, VC2, jloc = g.shape
+                V = VC2 // (C * 2)
+                g = g.reshape(K, V, C, 2, jloc)
+                gc = g[:, :, :, 0] + 1j * g[:, :, :, 1]   # (K, V, C, jloc)
+                # scatter member rows into FFT bins (unused -> bin 2B)
                 gmask = jnp.where(sign != 0, gm, 2 * B).reshape(-1)
                 gmpask = jnp.where(sign != 0, gmp, 2 * B).reshape(-1)
 
-                def scatter(gl):                       # (K, jloc, C)
+                def scatter(gl):                       # (K, C, jloc)
                     buf = jnp.zeros((2 * B + 1, jloc, 2 * B + 1),
                                     dtype=gl.dtype)
-                    vals = jnp.swapaxes(gl, 1, 2).reshape(-1, jloc)
-                    buf = buf.at[gmask, :, gmpask].set(vals, mode="drop")
+                    buf = buf.at[gmask, :, gmpask].set(
+                        gl.reshape(-1, jloc), mode="drop")
                     return fft_synthesis(buf[: 2 * B, :, : 2 * B])
 
-                return jax.vmap(scatter, in_axes=2)(gc)  # (V,2B,jloc,2B)
+                return jax.vmap(scatter, in_axes=1)(gc)  # (V,2B,jloc,2B)
 
         return stage1, reshard, stage2
 
@@ -790,7 +791,8 @@ def distributed_inverse(plan: SoftPlan, packed, mesh, axis=("data", "model"),
 
 def packed_to_dense(plan: SoftPlan, packed):
     """packed[k, l, c] -> dense fhat[l, m + B - 1, m' + B - 1]."""
-    return place_coeffs(plan, jnp.asarray(packed))
+    return place_coeffs(plan, member_rows(jnp.swapaxes(jnp.asarray(packed),
+                                                       1, 2)))
 
 
 def dense_to_packed(plan: SoftPlan, fhat):

@@ -31,18 +31,23 @@ kernel-level binding the planner (and kernel tests/benchmarks) build on.
 The members of the family:
 
   monolithic  dwt_fused.py: host-sorted clusters, per-tile
-              scalar-prefetch l0, recurrence starts at l0; batch=V packs
-              V transforms onto the lane axis (C2 = V*C*2): one launch,
+              scalar-prefetch l0, recurrence starts at l0; operands
+              C2-major, rhs (K, C2, J) -> out (K, C2, L) and lhs
+              (K, C2, L) -> g (K, C2, J), J and L on the lanes; batch=V
+              packs V transforms onto the C2 axis (C2 = V*C*2): one launch,
               each generated d-row reused V times
               (Transform.forward_batch / inverse_batch).  Rows go to a
               (TK, P, J) VMEM panel, contracted by one MXU product per
               cluster (P = B where VMEM allows: autotune.panel_depth).
-  streaming   streaming.py, with ``lchunk`` set: only a (TK, lchunk, C2)
+  streaming   streaming.py, with ``lchunk`` set: only a (TK, C2, lchunk)
               coefficient tile is VMEM-live (the stack stays
               HBM-resident, staged through double-buffered slots), the
               recurrence resumes from per-chunk two-row windows, and the
-              chunk is the panel.  Auto-engaged when no monolithic V
-              fits VMEM.
+              chunk is the panel.  A compiled chunk is a multiple of
+              128 or B (it sits on the lanes), and 128 panel rows need
+              more VMEM than the chunk saves, so at B <= 512 where no
+              monolithic panel fits no compiled chunk does: the planner
+              engages this member by itself only for bf16.
   bf16        the streaming kernels with ``precision="bf16"``: the stored
               window table and the rows are rounded to bf16 while state,
               panel and accumulation stay in the plan dtype.  Keyed by
@@ -51,8 +56,9 @@ The members of the family:
               pure-jnp einsum path (differentiable, runs anywhere) -- the
               correctness oracle.
 
-VMEM budget (f32, TK = 8): seeds + 2 state rows (3*TK*J) + rhs (TK*J*C2)
-+ out (TK*L*C2) + the (TK, P, J) panel.  ``V="auto"`` picks the widest
+VMEM budget (f32, TK = 8): two pipeline buffers of seeds (TK*J), rhs
+(TK*C2*J) and out (TK*C2*L), plus 2 state rows (2*TK*J) and the (TK, P,
+J) panel.  ``V="auto"`` picks the widest
 lane packing whose estimate fits $REPRO_VMEM_BYTES
 (autotune.vmem_limit_bytes).
 

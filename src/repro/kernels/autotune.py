@@ -22,7 +22,7 @@ static n_shards > 1 heuristic).
 Cache location: $REPRO_AUTOTUNE_CACHE, else ~/.cache/repro/autotune.json.
 Delete the file (or pass refresh=True) to re-measure after a toolchain or
 hardware change.  Candidate cluster tiles divide K; V candidates pack V
-transforms onto the lane axis and are scored by *per-transform* time.
+transforms onto the kernel's C2 axis and are scored by *per-transform* time.
 """
 from __future__ import annotations
 
@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from repro import obs
 
 from . import ops
+from .streaming import lane_tileable
 
 __all__ = ["autotune_dwt", "autotune_overlap", "static_overlap",
            "static_precision", "static_lchunk", "tuned_dwt_fn",
@@ -49,8 +50,9 @@ __all__ = ["autotune_dwt", "autotune_overlap", "static_overlap",
 
 _DEF_CACHE = "~/.cache/repro/autotune.json"
 
-# Conservative per-core VMEM ceiling (TPU cores carry ~16 MB; leave margin
-# for Pallas double-buffering of the streamed operands).
+# Conservative per-core VMEM ceiling (TPU cores carry ~16 MB; the
+# estimate counts the pipeline's double buffers, the rest is margin for
+# the compiler's own scratch).
 _DEF_VMEM = 12 * 1024 * 1024
 
 # Mixed-precision schedule policies for the recurrence family.  "fp32"
@@ -125,29 +127,30 @@ def estimate_vmem_bytes(*, L: int, J: int, C2: int, tk: int,
                         limit: int | None = None) -> int:
     """Static VMEM footprint of one grid step of a fused-family tiling.
 
-    A grid step holds seeds + the two recurrence state rows (3 * TK * J),
-    the order/cos-beta vectors, the rhs tile (TK * J * C2), the
-    coefficient tile and the (TK, P, J) Wigner panel: ``panel`` rows if
-    given, else the depth :func:`panel_depth` derives under ``limit``.
-    C2 = V*C*2 grows linearly with lane packing, which is what caps V.
+    The kernel's pipeline holds two buffers of every block it streams
+    from or to HBM: seeds (TK * J), the order/cos-beta vectors, the rhs
+    tile (TK * C2 * J), the coefficient tile, and for a streaming
+    schedule its window block.  Its scratch is held once: the two
+    recurrence state rows (2 * TK * J) and the (TK, P, J) Wigner panel,
+    ``panel`` rows if given, else the depth :func:`panel_depth` derives
+    under ``limit``.  C2 = V*C*2 grows linearly with lane packing, which
+    is what caps V.
 
     itemsize must be the PLAN dtype's (f64 plans really do hold 8-byte
     tiles; assuming fp32 under-guards them 2x).  An l-chunked streaming
     schedule (lchunk != None) shrinks the coefficient tile from
-    TK * L * C2 to TK * lchunk * C2 -- the memory cliff this family
+    TK * C2 * L to TK * C2 * lchunk -- the memory cliff this family
     exists to cut -- and adds the staged 2 * TK * J window block, stored
     at 2 bytes under precision="bf16".
     """
     lt = L if lchunk is None else lchunk
-    extra = 0
+    blocks = itemsize * (tk * J + 2 * tk + J + tk * C2 * J + tk * C2 * lt)
     if lchunk is not None:                              # window block
-        extra += (2 if precision == "bf16" else itemsize) * 2 * tk * J
+        blocks += (2 if precision == "bf16" else itemsize) * 2 * tk * J
     if panel is None:
         panel = panel_depth(L=L, J=J, C2=C2, tk=tk, itemsize=itemsize,
                             lchunk=lchunk, limit=limit)
-    extra += itemsize * tk * panel * J
-    return (itemsize * (3 * tk * J + 2 * tk + J + tk * J * C2
-                        + tk * lt * C2) + extra)
+    return 2 * blocks + itemsize * (2 * tk * J + tk * panel * J)
 
 
 def panel_depth(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
@@ -186,7 +189,7 @@ def estimate_hbm_bytes(*, B: int, K: int, L: int, J: int, C2: int,
     """Estimated peak HBM residency of one transform at bandwidth B.
 
     Counts the (2B)^3 complex grid (the paper's second memory cliff), the
-    (K, L, C2) coefficient stack and (K, J, C2) beta-grid stack, and the
+    (K, C2, L) coefficient stack and (K, C2, J) beta-grid stack, and the
     fused family's Wigner working set: seeds (K, J) plus -- for
     streaming schedules -- the (nL, 2, K, J) chunk-boundary window table
     (2-byte elements under precision="bf16").  Diagnostic, not an
@@ -246,12 +249,12 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
                   precision: str = "fp32", limit: int | None = None,
                   monolithic_ok: bool = True) -> int | None:
     """Static l-chunk heuristic for the fused family: stay monolithic
-    (None) when the full (TK, L, C2) coefficient tile fits the VMEM
+    (None) when the full (TK, C2, L) coefficient tile fits the VMEM
     ceiling, otherwise the LARGEST divisor lchunk of L that fits (largest
     chunk = fewest window reloads + longest in-kernel recurrence runs).
-    Only chunks the TPU tiling accepts are candidates: multiples of 8, or
-    L itself (:func:`repro.kernels.streaming.check_lchunk`).  Raises when
-    no candidate fits (shrink tk or V instead).
+    Only chunks a compiled kernel accepts are candidates: multiples of 128
+    or L itself (:func:`repro.kernels.streaming.lane_tileable`).  Raises
+    when no candidate fits (shrink tk or V instead).
 
     ``monolithic_ok=False`` skips the monolithic fast-path and admits
     lchunk = L as a candidate: bf16 schedules have no monolithic kernel
@@ -268,14 +271,14 @@ def static_lchunk(*, L: int, J: int, C2: int, tk: int, itemsize: int = 4,
         return None
     top = L + 1 if not monolithic_ok else L
     for lc in sorted((d for d in range(1, top)
-                      if L % d == 0 and (d % 8 == 0 or d == L)),
+                      if L % d == 0 and lane_tileable(L, d)),
                      reverse=True):
         if est(lc) <= limit:
             return lc
     raise RuntimeError(
         f"no l-chunk fits the {limit}-byte VMEM ceiling at L={L}, J={J}, "
-        f"C2={C2}, tk={tk} (chunks are multiples of 8 or L; shrink tk/V "
-        f"or raise $REPRO_VMEM_BYTES)")
+        f"C2={C2}, tk={tk} (compiled chunks are multiples of 128 or L; "
+        f"shrink tk/V or raise $REPRO_VMEM_BYTES)")
 
 
 def cache_path() -> pathlib.Path:
@@ -369,7 +372,7 @@ def autotune_dwt(plan, *, Vs=(1,), reps: int = 3, refresh: bool = False,
 
     Returns {"tk", "V", "per_transform_s"}.  Sweeps the
     candidate tilings for every V in Vs (V > 1 packs V transforms onto the
-    kernel lane axis; scored per transform so wider packing must EARN its
+    kernel's C2 axis; scored per transform so wider packing must EARN its
     place by amortizing launch + Wigner-generation cost).
 
     n_shards > 1 tunes the MESH decomposition instead of the local
@@ -414,10 +417,10 @@ def autotune_dwt(plan, *, Vs=(1,), reps: int = 3, refresh: bool = False,
     with sweep:
         for V in Vs:
             if n_shards > 1:
-                rhs = jnp.asarray(rng.normal(size=(K_eff, J, V * C * 2)),
+                rhs = jnp.asarray(rng.normal(size=(K_eff, V * C * 2, J)),
                                   plan.dtype)
             else:
-                shape = (K, J, C, 2) if V == 1 else (V, K, J, C, 2)
+                shape = (K, C * 2, J) if V == 1 else (V, K, C * 2, J)
                 rhs = jnp.asarray(rng.normal(size=shape), plan.dtype)
             for tk in candidate_tiles(K_eff):
                 if estimate_vmem_bytes(L=L, J=J, C2=V * C * 2, tk=tk,

@@ -14,19 +14,27 @@ lane batching:
     loop runs l = l0s[g] .. L-1: the l < max(|m|, |m'|) zero-triangle
     (paper point P3) is neither stored nor executed;
   * seeds + (d_prev, d_cur) recurrence state live in VMEM; HBM traffic is
-    seeds (K*J) + rhs (K*J*C2) + out (K*L*C2) with NO d-table term;
-  * the contraction lane axis C2 is V*C*2 for V simultaneous transforms
+    seeds (K*J) + rhs (K*C2*J) + out (K*C2*L) with NO d-table term;
+  * the member-row axis C2 is V*C*2 for V simultaneous transforms
     (ops.batched_rhs / ops.make_dwt_fn(batch=V) pack them), so a batch of
     rotations costs one kernel launch and re-uses each generated d-row
     V times -- the recurrence FLOPs amortize linearly in V.
+
+Operand contract: C2-major.  The forward maps rhs (K, C2, J) to out
+(K, C2, L); the inverse maps lhs (K, C2, L) to g (K, C2, J).  C2 = 16, 64
+or 128 rows sit on the sublanes and J = 2B or L = B on the 128 lanes, so
+under the TPU's (8, 128) tiling every block the kernels move, and every
+array the grid stages hand them, is dense.
 
 The contraction runs on the MXU, a PANEL of rows at a time.  The
 recurrence loop only generates rows: row l goes to row l - base of a
 (TK, P, J) VMEM panel in the plan dtype (rows below the tile's l0 are
 zero).  When the panel is full, each cluster of the tile does one matrix
-product: the forward writes out[k, base:base+P] = panel[k] @ rhs[k]
-((P x J) . (J x C2)), the inverse adds panel[k]^T @ lhs[k, base:base+P]
-((J x P) . (P x C2)) into its (J, C2) output.  Both products run at
+product: the forward writes out[k, :, base:base+P] = rhs[k] @ panel[k]^T
+((C2 x J) . (J x P)), the inverse adds lhs[k, :, base:base+P] @ panel[k]
+((C2 x P) . (P x J)) into its (C2, J) output.  The panels of a grid step
+are unrolled, so each product's degree slice of the lanes is static.
+Both products run at
 ``Precision.HIGHEST`` (f32 on the chip: a one-pass bf16 product would
 read ~1e-3 where f32 reads ~2e-6).  The panel depth P is the whole
 degree range (P = L, one product per cluster per grid step) wherever
@@ -38,10 +46,16 @@ Work accounting: a grid step of tile g marches L - l0s[g] rows, so a
 launch runs sum_g (L - l0s[g]) row-steps where a full-range march would
 run (K/TK) * L (~2.4x more at B = 512).
 
-VMEM per grid step (f32, TK=8, B=512): seeds/prev/cur 3*TK*J = 96 KB,
-rhs TK*J*C2 = 512 KB (V=1), out TK*L*C2 = 256 KB, panel TK*P*J = 4 MB
-per 128 rows.  Under the 12 MB default budget P = L up to B = 256 at
-every V <= 8; at B = 512 P = 256 for V <= 4 and 128 for V = 8.
+VMEM per grid step (f32, TK=8): the pipeline holds two buffers of every
+block it streams (seeds, rhs, out) and one of its scratch (recurrence
+state, panel).  At B = 128, V = 1 (the ladder) the (TK, C2, J) rhs block
+is 128 KB, the (TK, C2, L) coefficient block 64 KB, the seeds 8 KB, the
+state 2*TK*J = 16 KB and the (TK, P, J) panel 1 MB.  At B = 512: rhs
+TK*C2*J = 512 KB and out TK*C2*L = 256 KB per lane of V, state 64 KB,
+panel TK*P*J = 4 MB per 128 rows.  Under the 12 MB default budget
+(autotune.estimate_vmem_bytes) P = L up to B = 256 at every V <= 8; at
+B = 512 P = 256 for V <= 2 and 128 for V = 4, and V = 8 does not fit
+(two buffers of its rhs and out blocks alone take 12 MB).
 """
 from __future__ import annotations
 
@@ -125,21 +139,21 @@ def fill_panel(row, lo, base, panel_ref):
     jax.lax.fori_loop(lo, base + P, body, None)
 
 
-def contract_panel(panel_ref, x_ref, o_ref, row0, *, inverse):
-    """One MXU product per cluster of the tile.  Forward: o[k, row0:row0+P]
-    = panel[k] @ x[k] ((P x J) . (J x C2)).  Inverse: o[k] +=
-    panel[k]^T @ x[k, row0:row0+P] ((J x P) . (P x C2))."""
+def contract_panel(panel_ref, x_ref, o_ref, col0, *, inverse):
+    """One MXU product per cluster of the tile, on C2-major operands.
+    Forward: o[k, :, col0:col0+P] = x[k] @ panel[k]^T ((C2 x J) . (J x
+    P)).  Inverse: o[k] += x[k, :, col0:col0+P] @ panel[k] ((C2 x P) .
+    (P x J)).  col0 is static: the degree slice sits on the lanes."""
     P = panel_ref.shape[1]
     for k in range(panel_ref.shape[0]):
         if inverse:
-            o_ref[k] += jax.lax.dot_general(
-                panel_ref[k], x_ref[k, pl.ds(row0, P), :],
-                (((0,), (0,)), ((), ())), precision=_HIGHEST,
-                preferred_element_type=o_ref.dtype)
+            o_ref[k] += jnp.dot(
+                x_ref[k, :, col0:col0 + P], panel_ref[k],
+                precision=_HIGHEST, preferred_element_type=o_ref.dtype)
         else:
-            o_ref[k, pl.ds(row0, P), :] = jnp.dot(
-                panel_ref[k], x_ref[k], precision=_HIGHEST,
-                preferred_element_type=o_ref.dtype)
+            o_ref[k, :, col0:col0 + P] = jax.lax.dot_general(
+                x_ref[k], panel_ref[k], (((1,), (1,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=o_ref.dtype)
 
 
 def _fused_kernel(L, P, inverse, l0_ref, seeds_ref, m_ref, mp_ref, cb_ref,
@@ -161,13 +175,13 @@ def _fused_kernel(L, P, inverse, l0_ref, seeds_ref, m_ref, mp_ref, cb_ref,
     def row(l):
         return march(l, m, mp, cb, seeds, prev_ref, cur_ref)
 
-    def panel(i, _):
-        base = pl.multiple_of(i * P, P)
-        fill_panel(row, jnp.maximum(l0, base), base, panel_ref)
-        contract_panel(panel_ref, x_ref, o_ref, base, inverse=inverse)
-
-    first = jax.lax.div(l0, jnp.int32(P)) if P < L else I0   # l0 >= 0
-    jax.lax.fori_loop(first, jnp.int32(L // P), panel, None)
+    # panels unrolled, so that each product's degree slice of the lanes
+    # is static; the recurrence state carries from panel to panel
+    for base in range(0, L, P):
+        @pl.when(l0 < base + P)
+        def _panel():
+            fill_panel(row, jnp.maximum(l0, base), base, panel_ref)
+            contract_panel(panel_ref, x_ref, o_ref, base, inverse=inverse)
 
 
 def _fused_call(seeds, m, mp, cos_beta, x, l0s, *, B, tk, vmem_limit,
@@ -176,7 +190,7 @@ def _fused_call(seeds, m, mp, cos_beta, x, l0s, *, B, tk, vmem_limit,
 
     interpret = resolve_interpret(interpret)
     K, J = seeds.shape
-    C2 = x.shape[-1]
+    C2 = x.shape[1]
     tk = min(tk, K)
     if K % tk:
         raise ValueError(f"K={K} % tk={tk}")
@@ -187,7 +201,7 @@ def _fused_call(seeds, m, mp, cos_beta, x, l0s, *, B, tk, vmem_limit,
     mf = m.astype(dt)[:, None]
     mpf = mp.astype(dt)[:, None]
     cb = cos_beta.astype(dt)[None, :]
-    x_rows, o_rows = (B, J) if inverse else (J, B)
+    x_cols, o_cols = (B, J) if inverse else (J, B)
     return pl.pallas_call(
         partial(_fused_kernel, B, P, inverse),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -198,14 +212,14 @@ def _fused_call(seeds, m, mp, cos_beta, x, l0s, *, B, tk, vmem_limit,
                 pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),      # m
                 pl.BlockSpec((tk, 1), lambda k, l0s: (k, I0)),      # mp
                 pl.BlockSpec((1, J), lambda k, l0s: (I0, I0)),     # cos_beta
-                pl.BlockSpec((tk, x_rows, C2), lambda k, l0s: (k, I0, I0)),
+                pl.BlockSpec((tk, C2, x_cols), lambda k, l0s: (k, I0, I0)),
             ],
-            out_specs=pl.BlockSpec((tk, o_rows, C2),
+            out_specs=pl.BlockSpec((tk, C2, o_cols),
                                    lambda k, l0s: (k, I0, I0)),
             scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt),
                             pltpu.VMEM((tk, P, J), dt)],
         ),
-        out_shape=jax.ShapeDtypeStruct((K, o_rows, C2), dt),
+        out_shape=jax.ShapeDtypeStruct((K, C2, o_cols), dt),
         interpret=interpret,
     )(jnp.asarray(l0s, jnp.int32), seeds, mf, mpf, cb, x)
 
@@ -215,12 +229,12 @@ def dwt_fused(seeds, m, mp, cos_beta, rhs, l0s, *, B, tk=8, vmem_limit=None,
               interpret=None):
     """Forward fused DWT: ragged l-range + on-the-fly Wigner rows.
 
-    seeds: (K, J); m, mp: (K,) int; cos_beta: (J,); rhs: (K, J, C2) with
-    C2 = V*C*2 lanes for V batched transforms; l0s: (K // tk,) int32 tile
-    l-starts (build_tile_lstarts).  Clusters must be sorted so each
-    TK-tile's l-extents agree with l0s.  vmem_limit (default
+    seeds: (K, J); m, mp: (K,) int; cos_beta: (J,); rhs: (K, C2, J) with
+    C2 = V*C*2 member rows for V batched transforms; l0s: (K // tk,)
+    int32 tile l-starts (build_tile_lstarts).  Clusters must be sorted so
+    each TK-tile's l-extents agree with l0s.  vmem_limit (default
     :func:`repro.kernels.autotune.vmem_limit_bytes`) is the budget the
-    panel depth is derived against.  Returns out (K, B, C2).
+    panel depth is derived against.  Returns out (K, C2, B).
     """
     return _fused_call(seeds, m, mp, cos_beta, rhs, l0s, B=B, tk=tk,
                        vmem_limit=vmem_limit, inverse=False,
@@ -230,8 +244,8 @@ def dwt_fused(seeds, m, mp, cos_beta, rhs, l0s, *, B, tk=8, vmem_limit=None,
 @partial(jax.jit, static_argnames=("B", "tk", "vmem_limit", "interpret"))
 def idwt_fused(seeds, m, mp, cos_beta, lhs, l0s, *, B, tk=8, vmem_limit=None,
                interpret=None):
-    """Inverse fused iDWT.  lhs: (K, B, C2), zero below each cluster's
-    l-start; returns g (K, J, C2).  Arguments as in :func:`dwt_fused`."""
+    """Inverse fused iDWT.  lhs: (K, C2, B), zero below each cluster's
+    l-start; returns g (K, C2, J).  Arguments as in :func:`dwt_fused`."""
     return _fused_call(seeds, m, mp, cos_beta, lhs, l0s, B=B, tk=tk,
                        vmem_limit=vmem_limit, inverse=True,
                        interpret=interpret)

@@ -35,26 +35,18 @@ __all__ = ["default_interpret", "make_dwt_fn", "make_idwt_fn",
            "batched_rhs", "pad_lanes", "attention"]
 
 
-def _split_ri(x):
-    """(K, A, C, 2) -> (K, A, C*2) merging the real/imag axis into lanes."""
-    return x.reshape(*x.shape[:2], -1)
-
-
-def _unsplit_ri(x, c):
-    return x.reshape(*x.shape[:2], c, 2)
-
-
 def pack_lanes(x):
-    """(V, K, A, C, 2) -> (K, A, V*C*2): V batched transforms side by side
-    on the contraction lane axis, one kernel launch for the whole batch."""
-    V, K, A, C, _ = x.shape
-    return jnp.moveaxis(x, 0, 2).reshape(K, A, V * C * 2)
+    """(V, K, C2, A) -> (K, V*C2, A): V batched transforms stacked on the
+    kernel's C2 axis (sublanes), one kernel launch for the whole batch.
+    A (J or L) stays on the lanes."""
+    V, K, C2, A = x.shape
+    return jnp.moveaxis(x, 0, 1).reshape(K, V * C2, A)
 
 
-def unpack_lanes(x, V, C):
-    """(K, A, V*C*2) -> (V, K, A, C, 2), inverse of pack_lanes."""
-    K, A, _ = x.shape
-    return jnp.moveaxis(x.reshape(K, A, V, C, 2), 2, 0)
+def unpack_lanes(x, V):
+    """(K, V*C2, A) -> (V, K, C2, A), inverse of pack_lanes."""
+    K, VC2, A = x.shape
+    return jnp.moveaxis(x.reshape(K, V, VC2 // V, A), 1, 0)
 
 
 def pad_lanes(x, V):
@@ -173,26 +165,26 @@ def _streaming_inputs(plan: SoftPlan, tk: int, lchunk: int, precision: str,
 
 
 def _wrap_batch(raw, batch):
-    """Lift raw(p, rhs2: (K, A, C2)) to the (plan, rhs) dwt_fn contract.
+    """Lift raw(p, x2: (K, C2, A)) to the (plan, x) dwt_fn contract.
 
-    batch=None: rhs (K, A, C, 2) (the single-transform contract).
-    batch=V (any int >= 1): rhs (V, K, A, C, 2); the V transforms are
-    packed onto the lane axis so the kernel launches once.
+    batch=None: x (K, C*2, A) (the single-transform contract).
+    batch=V (any int >= 1): x (V, K, C*2, A); the V transforms are
+    packed onto the C2 axis so the kernel launches once.
     """
     if batch is None:
-        def fn(p: SoftPlan, rhs):
-            if rhs.ndim != 4:
+        def fn(p: SoftPlan, x):
+            if x.ndim != 3:
                 raise ValueError(f"dwt_fn built without batch expects "
-                                 f"(K, A, C, 2), got {rhs.shape}; pass "
+                                 f"(K, C2, A), got {x.shape}; pass "
                                  f"batch=V to make_dwt_fn for a V-stack")
-            return _unsplit_ri(raw(p, _split_ri(rhs)), rhs.shape[2])
+            return raw(p, x)
         return fn
 
-    def fn(p: SoftPlan, rhs):
-        if rhs.ndim != 5 or rhs.shape[0] != batch:
+    def fn(p: SoftPlan, x):
+        if x.ndim != 4 or x.shape[0] != batch:
             raise ValueError(f"dwt_fn built with batch={batch}, expected "
-                             f"(V, K, A, C, 2), got {rhs.shape}")
-        return unpack_lanes(raw(p, pack_lanes(rhs)), batch, rhs.shape[3])
+                             f"(V, K, C2, A), got {x.shape}")
+        return unpack_lanes(raw(p, pack_lanes(x)), batch)
     return fn
 
 
@@ -244,11 +236,14 @@ def make_dwt_fn(plan: SoftPlan, *, tk=8, lchunk=None, precision=None,
     """Build a dwt_fn(plan, rhs) for core.batched.forward_clustered on the
     fused kernel family.
 
-    batch=V makes the fn accept a (V, K, J, C, 2) stack of RHS
+    The fn maps rhs (K, C*2, J) -> out (K, C*2, L): each cluster's C
+    member rows, real and imaginary part, on the sublanes and the beta
+    samples or degrees on the lanes (core.batched's member-row layout).
+    batch=V makes the fn accept a (V, K, C*2, J) stack of RHS
     (core.batched.forward_clustered_batch) contracted in ONE kernel launch
-    with V*C*2 lanes.  lchunk selects the l-chunked streaming kernel
-    (kernels/streaming.py): HBM-resident coefficients staged as (tk,
-    lchunk, C2) VMEM tiles, recurrence re-seeded per chunk from a two-row
+    with V*C*2 rows.  lchunk selects the l-chunked streaming kernel
+    (kernels/streaming.py): HBM-resident coefficients staged as (tk, C2,
+    lchunk) VMEM tiles, recurrence re-seeded per chunk from a two-row
     window.  precision: "fp32" (default; compute in the plan dtype) or
     "bf16" (bf16 recurrence windows / d-rows, plan-dtype accumulation;
     forces the streaming kernel, the monolithic one has no
@@ -263,9 +258,10 @@ def make_dwt_fn(plan: SoftPlan, *, tk=8, lchunk=None, precision=None,
 
 def make_idwt_fn(plan: SoftPlan, *, tk=8, lchunk=None, precision=None,
                  vmem_limit=None, interpret=None, batch=None):
-    """Build an idwt_fn(plan, lhs) for core.batched.inverse_clustered;
-    arguments as in :func:`make_dwt_fn` (with batch=V, lhs gains a
-    leading V axis, packed onto lanes for one launch)."""
+    """Build an idwt_fn(plan, lhs) for core.batched.inverse_clustered,
+    mapping lhs (K, C*2, L) -> g (K, C*2, J); arguments as in
+    :func:`make_dwt_fn` (with batch=V, lhs gains a leading V axis, packed
+    onto the C2 axis for one launch)."""
     return _make_fn(plan, inverse=True, tk=tk, lchunk=lchunk,
                     precision=precision, vmem_limit=vmem_limit,
                     interpret=interpret, batch=batch)
@@ -275,12 +271,12 @@ def batched_rhs(plan: SoftPlan, S):
     """Lane-packed DWT right-hand side for V simultaneous transforms.
 
     S: (V, 2B, J, 2B) complex FFT-analysis outputs (stage 1 of V forward
-    transforms).  Returns (K, J, V*C*2) real -- the widened-C2 operand the
+    transforms).  Returns (K, V*C*2, J) real -- the widened-C2 operand the
     fused DWT kernels contract in a single launch.
     """
     from repro.core import batched as _b
 
-    rhs = jax.vmap(lambda s: _b._gather_rhs(plan, s))(S)  # (V, K, J, C, 2)
+    rhs = jax.vmap(lambda s: _b._gather_rhs(plan, s))(S)  # (V, K, C2, J)
     return pack_lanes(rhs)
 
 

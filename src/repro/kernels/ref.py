@@ -11,14 +11,14 @@ __all__ = ["dwt_ref", "idwt_ref", "wigner_rec_table_ref", "attention_ref"]
 
 
 def dwt_ref(d, rhs):
-    """Clustered DWT: out[k, l, c] = sum_j d[k, l, j] rhs[k, j, c]."""
-    return jnp.einsum("klj,kjc->klc", d, rhs,
+    """Clustered DWT: out[k, c, l] = sum_j d[k, l, j] rhs[k, c, j]."""
+    return jnp.einsum("klj,kcj->kcl", d, rhs,
                       preferred_element_type=jnp.promote_types(d.dtype, jnp.float32))
 
 
 def idwt_ref(d, lhs):
-    """Clustered iDWT: g[k, j, c] = sum_l d[k, l, j] lhs[k, l, c]."""
-    return jnp.einsum("klj,klc->kjc", d, lhs,
+    """Clustered iDWT: g[k, c, j] = sum_l d[k, l, j] lhs[k, c, l]."""
+    return jnp.einsum("klj,kcl->kcj", d, lhs,
                       preferred_element_type=jnp.promote_types(d.dtype, jnp.float32))
 
 

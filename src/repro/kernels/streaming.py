@@ -1,16 +1,19 @@
 """Pallas TPU kernels: l-chunked STREAMING fused DWT/iDWT for paper-scale B.
 
 The monolithic fused kernel (dwt_fused.py) holds a cluster-tile's ENTIRE
-l-range in VMEM per grid step: the forward out tile is (TK, L, C2) and the
-inverse coefficient tile (TK, L, C2).  At the paper's "accuracy- and
+l-range in VMEM per grid step: the forward out tile is (TK, C2, L) and the
+inverse coefficient tile (TK, C2, L).  At the paper's "accuracy- and
 memory-critical bandwidth 512" with V-lane packing (C2 = V*C*2) that tile
-alone is TK*512*C2*4 bytes -- 2 MB at V = 1 and 16 MB at V = 8, past the
-per-core VMEM budget exactly where lane packing matters most.  This module
-splits the degree axis into nL = L/lchunk chunks so only an (TK, lchunk,
-C2) coefficient tile is ever VMEM-live:
+is TK*C2*512*4 bytes -- 256 KB at V = 1 and 2 MB at V = 8, on top of a
+Wigner panel of 4 MB per 128 rows.  This module splits the degree axis
+into nL = L/lchunk chunks so only a (TK, C2, lchunk) coefficient tile is
+ever VMEM-live.  The operands follow the family's C2-major contract
+(dwt_fused.py): rhs (K, C2, J), coefficients (K, C2, L), the chunk on
+the lanes -- so a compiled kernel's lchunk is a multiple of 128, or L
+(check_lchunk):
 
-  * coefficient blocks stay HBM-RESIDENT: the (K, L, C2) stack is carried
-    in HBM and Pallas stages one (TK, lchunk, C2) tile per grid step into
+  * coefficient blocks stay HBM-RESIDENT: the (K, C2, L) stack is carried
+    in HBM and Pallas stages one (TK, C2, lchunk) tile per grid step into
     double-buffered VMEM slots (the same two-slot overlap pattern the
     DistExecutor pipeline uses per V-chunk, here at the DMA level inside
     one kernel -- chunk i's tile contracts while chunk i+1's tile streams);
@@ -44,13 +47,14 @@ Grid layout: (K/TK, nL) with the chunk axis innermost.  The forward rhs
 block index is constant over lc (the tile stays VMEM-resident across a
 cluster-tile's chunks); the inverse output block revisits (K-indexed, lc
 ignored) and accumulates one product per chunk -- initialization happens
-at lc == 0.  Every forward output element is one product over J, as in
-the monolithic kernel, so the forward and the lchunk = L inverse are
-bitwise equal to it in fp32/f64 (but for lchunk = 1 under XLA's CPU
-backend, which sums a one-row product in another order); an inverse at
-lchunk < L sums nL chunk products where the monolithic kernel sums one,
-so it agrees to the dtype's rounding, and bit for bit only with a
-monolithic kernel whose panel is lchunk rows deep.
+at lc == 0.  At lchunk = L both directions are bitwise equal to the
+monolithic kernel in fp32/f64.  Every forward output element is one
+product over J, as in the monolithic kernel, but a chunk's product puts
+lchunk degrees on the output's lanes, and XLA's CPU backend (interpret
+mode) sums a narrow product over J in another order than a wide one; an
+inverse at lchunk < L sums nL chunk products where the monolithic kernel
+sums one.  So both agree to the dtype's rounding, and bit for bit only
+with a monolithic kernel whose panel is lchunk rows deep.
 """
 from __future__ import annotations
 
@@ -65,25 +69,32 @@ from .dwt_fused import _recurrence_step, contract_panel, fill_panel, march
 from .runtime import I0, resolve_interpret
 
 __all__ = ["build_windows", "dwt_streaming", "idwt_streaming",
-           "check_lchunk"]
+           "check_lchunk", "lane_tileable"]
 
 
 def check_lchunk(L: int, lchunk: int, *, tiled: bool = False) -> int:
     """Validate an l-chunk size: 1 <= lchunk <= L and lchunk | L (the
     chunk grid must tile the degree axis exactly).  ``tiled`` adds the
-    TPU's rule for the (tk, lchunk, C2) blocks: lchunk is a multiple of
-    the 8-row sublane tile, or the whole degree axis.  Interpret mode has
-    no tiling, so tests there may use smaller chunks."""
+    TPU's rule for the (tk, C2, lchunk) blocks, whose chunk sits on the
+    lanes: lchunk is a multiple of the 128-lane tile, or the whole degree
+    axis.  Interpret mode has no tiling, so tests there may use smaller
+    chunks."""
     lchunk = int(lchunk)
     if not 1 <= lchunk <= L:
         raise ValueError(f"lchunk={lchunk} outside [1, L={L}]")
     if L % lchunk:
         raise ValueError(f"lchunk={lchunk} does not divide L={L}")
-    if tiled and lchunk % 8 and lchunk != L:
+    if tiled and not lane_tileable(L, lchunk):
         raise ValueError(
-            f"lchunk={lchunk} is neither a multiple of 8 nor L={L}: the "
-            f"TPU compiler refuses its (tk, lchunk, C2) blocks")
+            f"lchunk={lchunk} is neither a multiple of 128 nor L={L}: the "
+            f"TPU compiler refuses its (tk, C2, lchunk) blocks")
     return lchunk
+
+
+def lane_tileable(L: int, lchunk: int) -> bool:
+    """Whether a (.., lchunk) block of a degree axis of length L meets the
+    TPU's lane tiling: a multiple of 128 lanes, or the whole axis."""
+    return lchunk % 128 == 0 or lchunk == L
 
 
 @partial(jax.jit, static_argnames=("L", "lchunk", "state_dtype"))
@@ -173,7 +184,7 @@ def _stream_call(seeds, m, mp, cos_beta, x, l0s, windows, *, B, tk, lchunk,
     interpret = resolve_interpret(interpret)
     lchunk = check_lchunk(B, lchunk, tiled=not interpret)
     K, J = seeds.shape
-    C2 = x.shape[-1]
+    C2 = x.shape[1]
     tk = min(tk, K)
     if K % tk:
         raise ValueError(f"K={K} % tk={tk}")
@@ -186,15 +197,15 @@ def _stream_call(seeds, m, mp, cos_beta, x, l0s, windows, *, B, tk, lchunk,
     mpf = mp.astype(dt)[:, None]
     cb = cos_beta.astype(dt)[None, :]
     if inverse:     # coefficients staged chunk by chunk; output revisited
-        x_spec = pl.BlockSpec((tk, lchunk, C2),
-                              lambda k, lc, l0s: (k, lc, I0))
-        o_spec = pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0))
-        o_rows = J
+        x_spec = pl.BlockSpec((tk, C2, lchunk),
+                              lambda k, lc, l0s: (k, I0, lc))
+        o_spec = pl.BlockSpec((tk, C2, J), lambda k, lc, l0s: (k, I0, I0))
+        o_cols = J
     else:           # the rhs tile stays resident across a tile's chunks
-        x_spec = pl.BlockSpec((tk, J, C2), lambda k, lc, l0s: (k, I0, I0))
-        o_spec = pl.BlockSpec((tk, lchunk, C2),
-                              lambda k, lc, l0s: (k, lc, I0))
-        o_rows = B
+        x_spec = pl.BlockSpec((tk, C2, J), lambda k, lc, l0s: (k, I0, I0))
+        o_spec = pl.BlockSpec((tk, C2, lchunk),
+                              lambda k, lc, l0s: (k, I0, lc))
+        o_cols = B
     return pl.pallas_call(
         partial(_stream_kernel, lchunk, sdt, inverse),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -213,7 +224,7 @@ def _stream_call(seeds, m, mp, cos_beta, x, l0s, windows, *, B, tk, lchunk,
             scratch_shapes=[pltpu.VMEM((tk, J), dt), pltpu.VMEM((tk, J), dt),
                             pltpu.VMEM((tk, lchunk, J), dt)],
         ),
-        out_shape=jax.ShapeDtypeStruct((K, o_rows, C2), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((K, C2, o_cols), x.dtype),
         interpret=interpret,
     )(jnp.asarray(l0s, jnp.int32), seeds, mf, mpf, cb,
       windows.astype(sdt), x)
@@ -230,9 +241,9 @@ def dwt_streaming(seeds, m, mp, cos_beta, rhs, l0s, windows, *, B, tk=8,
     :func:`build_windows` (in the storage dtype); lchunk -- chunk length
     (must divide B), which is also the panel depth; precision -- "fp32"
     (everything in the plan dtype; bitwise-equal to the monolithic
-    kernel) or "bf16" (bf16 window storage + bf16-rounded rows; recurrence
+    kernel at lchunk = B) or "bf16" (bf16 window storage + bf16-rounded rows; recurrence
     state and accumulation stay in the plan dtype).  Returns out
-    (K, B, C2) in the rhs dtype.
+    (K, C2, B) in the rhs dtype.
     """
     return _stream_call(seeds, m, mp, cos_beta, rhs, l0s, windows, B=B,
                         tk=tk, lchunk=lchunk, precision=precision,
@@ -243,9 +254,9 @@ def dwt_streaming(seeds, m, mp, cos_beta, rhs, l0s, windows, *, B, tk=8,
                                    "interpret"))
 def idwt_streaming(seeds, m, mp, cos_beta, lhs, l0s, windows, *, B, tk=8,
                    lchunk=8, precision="fp32", interpret=None):
-    """Inverse fused iDWT, l-chunked: the (K, B, C2) coefficient stack
-    stays HBM-resident and is staged chunk-by-chunk into (tk, lchunk, C2)
-    VMEM tiles; see :func:`dwt_streaming`.  Returns g (K, J, C2)."""
+    """Inverse fused iDWT, l-chunked: the (K, C2, B) coefficient stack
+    stays HBM-resident and is staged chunk-by-chunk into (tk, C2, lchunk)
+    VMEM tiles; see :func:`dwt_streaming`.  Returns g (K, C2, J)."""
     return _stream_call(seeds, m, mp, cos_beta, lhs, l0s, windows, B=B,
                         tk=tk, lchunk=lchunk, precision=precision,
                         inverse=True, interpret=interpret)

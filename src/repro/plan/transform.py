@@ -100,9 +100,9 @@ class Schedule:
     ``lchunk`` engages the l-chunked STREAMING fused family
     (:mod:`repro.kernels.streaming`): None runs the monolithic kernel;
     an integer divisor of B streams the coefficient stack through
-    (tk, lchunk, C2) VMEM tiles.  The static resolver auto-engages it
-    (largest fitting chunk) when no monolithic lane width fits the VMEM
-    budget.  ``precision`` is the storage precision of the streaming
+    (tk, C2, lchunk) VMEM tiles; a compiled plan's chunk sits on the
+    lanes, so it is a multiple of 128 or B.  The static resolver engages
+    it for bf16 schedules (largest fitting chunk).  ``precision`` is the storage precision of the streaming
     Wigner working set ("fp32" = the plan dtype, bitwise-safe; "bf16" =
     bf16 window table + bf16 contraction rows, gated by
     :data:`repro.kernels.autotune.PRECISION_ERROR_BOUNDS`); both are
@@ -162,10 +162,12 @@ def _static_schedule(soft_plan: SoftPlan, impl, V, tk, limit: int,
     Streaming resolution (single-shard): an explicit ``lchunk``
     is honored; with lchunk=None the resolver first tries the monolithic
     kernel at every lane width, and only when NONE fits the VMEM budget
-    does it auto-engage the streaming family -- widest lane width first,
-    each with its largest fitting chunk (:func:`repro.kernels.autotune.
-    static_lchunk`) -- so existing small-B plans keep their schedules
-    bit-for-bit while paper-scale B stops failing the guard.  The
+    does it try the streaming family -- widest lane width first, each
+    with its largest fitting chunk (:func:`repro.kernels.autotune.
+    static_lchunk`).  A chunk is a multiple of 128 or B and its panel
+    is the chunk, so in fp32 at B <= 512 none fits where no monolithic
+    panel did, and the resolver raises; the branch resolves bf16
+    schedules.  The
     storage precision resolves through :func:`repro.kernels.autotune.
     static_precision` (plan-dtype-aware; only an explicit
     ``precision="auto"`` opts into the error-table bf16 heuristic).
@@ -459,7 +461,7 @@ class Transform:
 
     @property
     def dwt_fn_batch(self):
-        """V-lane batch DWT closure ((V, K, J, C, 2) rhs, one launch)."""
+        """V-lane batch DWT closure ((V, K, C*2, J) rhs, one launch)."""
         return self._res("dwt_V", lambda: self._make(
             ops.make_dwt_fn, self.schedule.V))
 
@@ -759,10 +761,10 @@ def plan(B: int, dtype=jnp.float64, *, impl: str = "fused", V="auto",
     V:    "auto" or an explicit lane width for the batch executors.
     tk:   the cluster tile (default: the largest of 8, 4, 2, 1 dividing
           the cluster count).
-    lchunk: None (monolithic kernel, or auto-engaged streaming when the
-          monolithic tile cannot fit the VMEM budget at any lane width)
-          or an explicit l-chunk (divisor of B) forcing the streaming
-          kernel (single-shard fused plans only).
+    lchunk: None (monolithic kernel; bf16 resolves its own chunk) or an
+          explicit l-chunk forcing the streaming kernel (single-shard
+          fused plans only): a divisor of B, and for a compiled plan a
+          multiple of 128 or B itself.
     streaming: build the SoftPlan WITHOUT the dense (K, L, J) Wigner
           table (core.batched.build_plan(streaming=True)): plan
           construction never materializes any O(B^3) host array, the

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from repro import plan as plan_mod
 from repro.core import batched, soft
-from repro.kernels import autotune, dwt_fused, ops, ref
+from repro.kernels import autotune, dwt_fused, ops, ref, streaming
 
 
 RNG = np.random.default_rng(1)
@@ -40,12 +40,12 @@ def test_fused_forward_matches_oracle(B, dtype, tk):
     jdt = jnp.float32 if dtype == np.float32 else jnp.float64
     plan = batched.build_plan(B, dtype=jdt, pad_to=4)
     K, L, J = plan.d.shape
-    rhs = rand((K, J, 8, 2), dtype, scale=0.3)
+    rhs = rand((K, 16, J), dtype, scale=0.3)
     out = np.asarray(ops.make_dwt_fn(plan, tk=tk)(plan, rhs))
-    expect = np.asarray(ref.dwt_ref(plan.d, rhs.reshape(K, J, 16)))
+    expect = np.asarray(ref.dwt_ref(plan.d, rhs))
     rtol, atol = _tol(dtype)
-    np.testing.assert_allclose(out.reshape(K, L, 16), expect, rtol=rtol,
-                               atol=atol)
+    assert out.shape == (K, 16, L)
+    np.testing.assert_allclose(out, expect, rtol=rtol, atol=atol)
 
 
 def test_fused_actually_skips_rows():
@@ -68,11 +68,81 @@ def test_fused_inverse_matches_oracle(B, dtype, tk):
     # lhs as produced by _gather_coeffs: zero below each cluster's l-start
     fhat = soft.random_coeffs(B, 5).astype(cdt)
     lhs = np.asarray(batched._gather_coeffs(plan, jnp.asarray(fhat)))
+    assert lhs.shape == (K, 16, L)
     out = np.asarray(ops.make_idwt_fn(plan, tk=tk)(plan, lhs))
-    expect = np.asarray(ref.idwt_ref(plan.d, lhs.reshape(K, L, 16)))
+    expect = np.asarray(ref.idwt_ref(plan.d, lhs))
     rtol, atol = _tol(dtype)
-    np.testing.assert_allclose(out.reshape(K, J, 16), expect, rtol=rtol,
-                               atol=atol)
+    assert out.shape == (K, 16, J)
+    np.testing.assert_allclose(out, expect, rtol=rtol, atol=atol)
+
+
+# ---------------------------------------------------------------------------
+# the operand contract: C2 member rows in front of the lanes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("V", [1, 4, 8])
+@pytest.mark.parametrize("member", ["monolithic", "streaming"])
+@pytest.mark.parametrize("direction", ["fwd", "inv"])
+def test_kernel_operands_are_c2_major(V, member, direction):
+    """Every member of the family takes rhs (K, C2, J) or lhs (K, C2, L),
+    with C2 = V*C*2 = 16, 64 or 128 rows in front of the lanes, returns
+    out (K, C2, L) or g (K, C2, J), and agrees with the jnp oracle."""
+    B, tk, C2 = 8, 4, V * 16
+    plan = batched.build_plan(B, dtype=jnp.float64, pad_to=4)
+    K, L, J = plan.d.shape
+    perm, l_start, l0s = ops.fused_metadata(plan, tk)
+    seeds, m, mp, cb = ops.onthefly_inputs(plan)
+    if direction == "fwd":
+        x = rand((K, C2, J), scale=0.3)
+    else:   # coefficients are zero below each cluster's l-start
+        x = rand((K, C2, L)) * (np.arange(L) >= l_start[perm][:, None, None])
+    args = (seeds[perm], m[perm], mp[perm], cb, jnp.asarray(x), l0s)
+    if member == "monolithic":
+        kernel = dwt_fused.dwt_fused if direction == "fwd" \
+            else dwt_fused.idwt_fused
+        out = kernel(*args, B=B, tk=tk)
+    else:
+        windows = ops.streaming_inputs(plan, tk, 4, "fp32")[-1]
+        kernel = streaming.dwt_streaming if direction == "fwd" \
+            else streaming.idwt_streaming
+        out = kernel(*args, windows, B=B, tk=tk, lchunk=4)
+    oracle = ref.dwt_ref if direction == "fwd" else ref.idwt_ref
+    assert out.shape == (K, C2, L if direction == "fwd" else J)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(oracle(plan.d[perm], x)),
+                               rtol=1e-10, atol=1e-11)
+
+
+def _count_eqns(jaxpr, name):
+    """Equations of primitive ``name`` in a jaxpr and its sub-jaxprs."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if hasattr(sub, "eqns"):
+                    n += _count_eqns(sub, name)
+                elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                    n += _count_eqns(sub.jaxpr, name)
+    return n
+
+
+@pytest.mark.parametrize("streaming_plan,want", [(None, (4, 3)),
+                                                 (True, (13, 17))])
+def test_no_layout_swap_around_the_kernels(streaming_plan, want):
+    """The grid stages hand the kernels their member rows as built and
+    read the kernels' rows as returned: the transposes left in the local
+    B = 8 transforms belong to the FFTs (axis moves) and the dense
+    coefficients' (L, m, m') <-> (m, m', L) turn, one a direction, and
+    each of the 4 beta slabs of a streaming plan adds its FFTs' own."""
+    B = 8
+    t = plan_mod.plan(B, jnp.float32, streaming=streaming_plan)
+    t.dwt_fn, t.idwt_fn            # lazy operands, built untraced
+    fhat = jax.ShapeDtypeStruct((B, 2 * B - 1, 2 * B - 1), jnp.complex64)
+    grid = jax.ShapeDtypeStruct((2 * B,) * 3, jnp.complex64)
+    got = (_count_eqns(jax.make_jaxpr(t.inverse)(fhat).jaxpr, "transpose"),
+           _count_eqns(jax.make_jaxpr(t.forward)(grid).jaxpr, "transpose"))
+    assert got == want
 
 
 # ---------------------------------------------------------------------------
@@ -104,19 +174,20 @@ def test_panel_contraction_matches_oracle(B, panel, V, direction):
     fn = maker(plan, tk=tk, vmem_limit=limit,
                batch=None if V == 1 else V)
     if direction == "fwd":
-        x = rand((V, K, J, 8, 2), scale=0.3)
+        x = rand((V, K, 16, J), scale=0.3)
     else:   # coefficients are zero below each cluster's l-start
-        x = rand((V, K, L, 8, 2)) * (np.arange(L)[:, None, None]
-                                     >= l_start[:, None, None, None])
-    out = np.asarray(fn(plan, x[0] if V == 1 else x)).reshape(V, K, -1, 16)
+        x = rand((V, K, 16, L)) * (np.arange(L)[None, None, :]
+                                   >= l_start[:, None, None])
+    out = np.asarray(fn(plan, x[0] if V == 1 else x)).reshape(V, K, 16, -1)
     oracle = ref.dwt_ref if direction == "fwd" else ref.idwt_ref
     for v in range(V):
-        expect = np.asarray(oracle(plan.d, x[v].reshape(K, -1, 16)))
+        expect = np.asarray(oracle(plan.d, x[v]))
         np.testing.assert_allclose(out[v], expect, rtol=1e-10, atol=1e-11)
     if direction == "fwd":
-        # rows below each cluster's l-start, and padded clusters, read zero
+        # degrees below each cluster's l-start, and padded clusters, read
+        # zero
         below = np.arange(L)[None, :] < l_start[:, None]
-        assert not out[:, below].any()
+        assert not np.swapaxes(out, 2, 3)[:, below].any()
         assert not out[:, plan.n_clusters:].any()
 
 
@@ -137,8 +208,10 @@ def test_describe_reports_the_panel():
 # ---------------------------------------------------------------------------
 
 def test_pack_unpack_roundtrip():
-    x = jnp.asarray(rand((3, 4, 6, 8, 2)))
-    y = ops.unpack_lanes(ops.pack_lanes(x), 3, 8)
+    x = jnp.asarray(rand((3, 4, 16, 6)))
+    packed = ops.pack_lanes(x)
+    assert packed.shape == (4, 3 * 16, 6)
+    y = ops.unpack_lanes(packed, 3)
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
@@ -156,8 +229,8 @@ def test_batched_dwt_matches_per_transform(member, V, direction):
     K, L, J = plan.d.shape
     maker = ops.make_dwt_fn if direction == "dwt" else ops.make_idwt_fn
     single = maker(plan, tk=4, **member)
-    rows = J if direction == "dwt" else L
-    x = rand((V, K, rows, 8, 2))
+    cols = J if direction == "dwt" else L
+    x = rand((V, K, 16, cols))
     out = np.asarray(maker(plan, tk=4, batch=V, **member)(plan, x))
     expect = np.stack([np.asarray(single(plan, x[v])) for v in range(V)])
     np.testing.assert_allclose(out, expect, rtol=1e-11, atol=1e-12)
@@ -201,7 +274,7 @@ def test_batch_fn_rejects_wrong_batch():
     K, _, J = plan.d.shape
     fn = ops.make_dwt_fn(plan, tk=4, batch=4)
     with pytest.raises(ValueError, match="batch=4"):
-        fn(plan, jnp.asarray(rand((2, K, J, 8, 2))))
+        fn(plan, jnp.asarray(rand((2, K, 16, J))))
 
 
 # ---------------------------------------------------------------------------
@@ -218,11 +291,10 @@ def test_autotune_caches_and_reuses(tmp_path):
     assert autotune.autotune_dwt(plan, cache=cache, reps=1) == cfg
     # tuned fn produces oracle-parity output
     K, L, J = plan.d.shape
-    rhs = rand((K, J, 8, 2), np.float32, scale=0.3)
+    rhs = rand((K, 16, J), np.float32, scale=0.3)
     out = np.asarray(autotune.tuned_dwt_fn(plan, cache=cache)(plan, rhs))
-    expect = np.asarray(ref.dwt_ref(plan.d, rhs.reshape(K, J, 16)))
-    np.testing.assert_allclose(out.reshape(K, L, 16), expect, rtol=5e-4,
-                               atol=1e-4)
+    expect = np.asarray(ref.dwt_ref(plan.d, rhs))
+    np.testing.assert_allclose(out, expect, rtol=5e-4, atol=1e-4)
 
 
 def test_candidate_tiles_respect_divisibility():
@@ -255,9 +327,10 @@ def test_vmem_estimate_grows_with_lanes_and_tiles():
     # a streaming schedule's panel is its chunk
     stream = autotune.estimate_vmem_bytes(tk=8, C2=16, lchunk=8, **kw)
     assert stream < base
-    # bf16 storage halves the staged (2, tk, J) window block
+    # bf16 storage halves the staged (2, tk, J) window block, which the
+    # pipeline double-buffers
     assert stream - autotune.estimate_vmem_bytes(
-        tk=8, C2=16, lchunk=8, precision="bf16", **kw) == 2 * 2 * 8 * 32
+        tk=8, C2=16, lchunk=8, precision="bf16", **kw) == 2 * 2 * 2 * 8 * 32
 
 
 def test_vmem_limit_env_override(monkeypatch):

@@ -38,7 +38,7 @@ def test_fused_f32_accuracy():
     plan64 = batched.build_plan(B, dtype=jnp.float64, pad_to=8)
     plan32 = batched.build_plan(B, dtype=jnp.float32, pad_to=8)
     K, J = plan32.n_padded, 2 * B
-    rhs = rand((K, J, 8, 2), np.float32, scale=0.1)
+    rhs = rand((K, 16, J), np.float32, scale=0.1)
     out32 = ops.make_dwt_fn(plan32, tk=8)(plan32, rhs)
     out64 = ops.make_dwt_fn(plan64, tk=8)(plan64, rhs.astype(np.float64))
     err = np.abs(np.asarray(out32) - np.asarray(out64)).max()

@@ -34,6 +34,15 @@ def _members(p, shape, seed):
     return r.normal(size=shape) + 1j * r.normal(size=shape)
 
 
+def _rows(x):
+    """Complex members (..., K, A, C) -> the member rows (..., K, C*2, A)
+    the grid stages and kernels exchange: member c's real part in row 2c,
+    its imaginary part in row 2c+1."""
+    y = np.swapaxes(x, -1, -2)
+    y = np.stack([y.real, y.imag], axis=-2)
+    return y.reshape(*y.shape[:-3], -1, y.shape[-1])
+
+
 def _np_bins(p, g):
     """g[k, j, c] -> FFT bins (2B, j, 2B), by the scatter the bins used to
     be placed with: reflection, then every used slot written into a
@@ -77,10 +86,12 @@ def test_bins_bitwise_equal_scatter(B, kind, V):
     p = _plan(B, kind)
     g = _members(p, (V, p.n_padded, 2 * B, 8), seed=B + V)
     want = np.stack([_np_bins(p, x) for x in g])
-    got = np.asarray(jax.vmap(lambda x: batched._place_bins(p, x))(g))
+    rows = _rows(g)
+    got = np.asarray(jax.vmap(lambda x: batched._place_bins(p, x))(rows))
     np.testing.assert_array_equal(got, want)
     # the slab path: the same bins through the same FFT
-    syn = np.asarray(jax.vmap(lambda x: batched.streamed_synthesis(p, x))(g))
+    syn = np.asarray(jax.vmap(lambda x: batched.streamed_synthesis(p, x))(
+        rows))
     ref = np.asarray(jax.vmap(batched.fft_synthesis)(jnp.asarray(want)))
     np.testing.assert_array_equal(syn, ref)
 
@@ -92,7 +103,8 @@ def test_coeffs_bitwise_equal_scatter(B, kind, V):
     p = _plan(B, kind)
     out = _members(p, (V, p.n_padded, B, 8), seed=100 + B + V)
     want = np.stack([_np_coeffs(p, _scaled(p, x)) for x in out])
-    got = np.asarray(jax.vmap(lambda x: batched._output_coeffs(p, x))(out))
+    got = np.asarray(jax.vmap(lambda x: batched._output_coeffs(p, x))(
+        _rows(out)))
     np.testing.assert_array_equal(got, want)
     dense = np.asarray(parallel.packed_to_dense_batch(p, out))
     np.testing.assert_array_equal(
@@ -124,7 +136,7 @@ def test_bin_src_reads_every_valid_member_once(B, kind):
     assert np.all((empty // n == B) | (empty % n == B))
     assert len(empty) == 4 * B - 1
     bins = np.asarray(batched._place_bins(
-        p, _members(p, (p.n_padded, 2 * B, 8), seed=B)))
+        p, _rows(_members(p, (p.n_padded, 2 * B, 8), seed=B))))
     assert not bins[B].any() and not bins[:, :, B].any()
 
 
@@ -178,15 +190,15 @@ def test_permuted_padded_plan_places_alike(B, kind):
 
     g = _members(nat, (K, 2 * B, 8), seed=B)
     np.testing.assert_array_equal(
-        np.asarray(batched._place_bins(perm, permute(g))),
-        np.asarray(batched._place_bins(nat, g)))
+        np.asarray(batched._place_bins(perm, _rows(permute(g)))),
+        np.asarray(batched._place_bins(nat, _rows(g))))
     np.testing.assert_array_equal(
-        np.asarray(batched.streamed_synthesis(perm, permute(g))),
-        np.asarray(batched.streamed_synthesis(nat, g)))
+        np.asarray(batched.streamed_synthesis(perm, _rows(permute(g)))),
+        np.asarray(batched.streamed_synthesis(nat, _rows(g))))
     out = _members(nat, (K, B, 8), seed=B + 1)
     np.testing.assert_array_equal(
-        np.asarray(batched._output_coeffs(perm, permute(out))),
-        np.asarray(batched._output_coeffs(nat, out)))
+        np.asarray(batched._output_coeffs(perm, _rows(permute(out)))),
+        np.asarray(batched._output_coeffs(nat, _rows(out))))
 
 
 @pytest.mark.parametrize("B", [4, 8])

@@ -79,13 +79,14 @@ def test_plan_refuses_64bit_compiled_kernels(monkeypatch):
 
 
 def test_compiled_plans_follow_the_tpu_tiling():
-    """Compiled kernels get l-chunks that are multiples of 8 (or B), and
-    mesh plans whole 8-row cluster tiles per device; interpret mode keeps
-    small chunks and minimal padding."""
-    with pytest.raises(ValueError, match="multiple of 8"):
-        plan_mod.plan(16, jnp.float32, lchunk=4, interpret=False)
-    assert plan_mod.plan(16, jnp.float32, lchunk=8,
-                         interpret=False).schedule.lchunk == 8
+    """Compiled kernels get l-chunks that are multiples of 128 (or B): the
+    chunk of a (tk, C2, lchunk) block sits on the lanes.  Mesh plans get
+    whole 8-row cluster tiles per device; interpret mode keeps small
+    chunks and minimal padding."""
+    with pytest.raises(ValueError, match="multiple of 128"):
+        plan_mod.plan(16, jnp.float32, lchunk=8, interpret=False)
+    assert plan_mod.plan(16, jnp.float32, lchunk=16,
+                         interpret=False).schedule.lchunk == 16
     assert plan_mod.plan(16, jnp.float32, lchunk=4,
                          interpret=True).schedule.lchunk == 4
     from repro.core.compat import make_mesh

@@ -177,8 +177,8 @@ def test_match_rejects_bad_shapes():
 # ---------------------------------------------------------------------------
 
 def test_correlation_runs_fused_batched_lanes(monkeypatch):
-    """One match_batch of 3 requests = ONE idwt_fused launch whose lane
-    axis carries V*C*2 = 3*8*2 columns."""
+    """One match_batch of 3 requests = ONE idwt_fused launch whose (K,
+    C2, L) operand carries V*C*2 = 3*8*2 member rows."""
     calls = []
     orig = dwt_fused_mod.idwt_fused
 
@@ -192,7 +192,8 @@ def test_correlation_runs_fused_batched_lanes(monkeypatch):
     pairs = [planted_pair(B, seed=30 + n) for n in range(V)]
     engine.match_batch([p[0] for p in pairs], [p[1] for p in pairs])
     assert len(calls) == 1                       # one launch for the batch
-    assert calls[0][-1] == V * 8 * 2             # V lanes x C=8 members x 2
+    assert calls[0][1] == V * 8 * 2              # V lanes x C=8 members x 2
+    assert calls[0][2] == B                      # the degrees on the lanes
     assert engine.impl == "fused"
 
 

@@ -18,43 +18,63 @@ from repro.kernels import autotune, ops, streaming
 # parity: chunked == monolithic (fp32/f64)
 #
 # Every chunk generates the monolithic kernel's rows bit for bit, and both
-# contract them on the MXU a panel at a time (P = lchunk, P = B).  Where
-# the sums group alike the results are bitwise equal: the lchunk = B
-# inverse, and the forward, whose every output element is one product
-# over J.  The inverse at lchunk < B adds B/lchunk chunk products where
-# the monolithic kernel takes one product over all B rows, so it agrees
-# to the dtype's rounding (rtol 1e-12 in f64, 1e-5 in f32), and both
-# agree with the f64 reference (core/soft.py).  So does the forward at
-# lchunk = 1: XLA's CPU backend, which runs the interpreted kernels, sums
-# a one-row product over J in another order than a many-row one.
+# contract them on the MXU a panel at a time (P = lchunk for the chunked
+# kernel, P = B or a VMEM-bound multiple of 8 for the monolithic one).
+# Where the panels are alike the sums group alike and the results are
+# bitwise equal, forward and inverse: lchunk = B, and lchunk = 8 at B = 16
+# against a monolithic plan whose budget holds only an 8-row panel.  A chunk no
+# monolithic panel can match (lchunk = 1, 2, 4) agrees to the dtype's
+# rounding (rtol 1e-12 in f64, 1e-5 in f32): the inverse adds B/lchunk
+# chunk products where the monolithic kernel takes fewer, and the
+# forward's (C2 x J) . (J x P) product puts the panel's P degrees on the
+# output's lanes, where XLA's CPU backend, which runs the interpreted
+# kernels, sums over J in an order that depends on that width.  Both
+# agree with the f64 reference (core/soft.py).
 # ---------------------------------------------------------------------------
 
-def _assert_agree(got, want, lc, B, rtol):
-    if lc == B:
+def _assert_agree(got, want, bitwise, rtol):
+    if bitwise:
         np.testing.assert_array_equal(got, want)
     else:
         np.testing.assert_allclose(got, want, rtol=rtol,
                                    atol=rtol * np.abs(want).max())
 
 
+def _monolithic_with_panel(B, lc, dtype=jnp.float64, tk=4):
+    """The V = 1 monolithic plan whose Wigner panel is lc rows deep where
+    a panel can be (a multiple of 8, or B), else the default panel P = B.
+    V = 1 because a single transform runs the one-lane kernel, whose
+    panel is the one the schedule reports."""
+    V = 1
+    base = plan_mod.plan(B, dtype, impl="fused", V=V, tk=tk)
+    if lc % 8 or lc == B:
+        return base
+    C = base.soft_plan.gather_m.shape[1]
+    budget = autotune.estimate_vmem_bytes(
+        L=B, J=2 * B, C2=V * C * 2, tk=tk,
+        itemsize=jnp.dtype(dtype).itemsize, panel=lc)
+    mono = plan_mod.plan(B, dtype, impl="fused", V=V, tk=tk,
+                         vmem_budget=budget)
+    assert mono.schedule.lchunk is None and mono.describe()["panel"] == lc
+    return mono
+
+
 @pytest.mark.parametrize("B", [8, 16])
-@pytest.mark.parametrize("lchunk", [1, 2, "B"])
+@pytest.mark.parametrize("lchunk", [1, 2, "B/2", "B"])
 def test_streaming_bitwise_equals_monolithic(B, lchunk):
-    lc = B if lchunk == "B" else lchunk
-    mono = plan_mod.plan(B, impl="fused", V=2, tk=4)
-    strm = plan_mod.plan(B, impl="fused", V=2, tk=4, lchunk=lc)
+    lc = {"B": B, "B/2": B // 2}.get(lchunk, lchunk)
+    mono = _monolithic_with_panel(B, lc)
+    strm = plan_mod.plan(B, impl="fused", V=1, tk=4, lchunk=lc)
     assert strm is not mono                    # distinct cache entries
     assert strm.schedule.lchunk == lc
+    bitwise = mono.describe()["panel"] == lc
     fhat = soft.random_coeffs(B, seed=B)
     f_mono = np.asarray(mono.inverse(fhat))
     f_strm = np.asarray(strm.inverse(fhat))
-    _assert_agree(f_strm, f_mono, lc, B, 1e-12)
+    _assert_agree(f_strm, f_mono, bitwise, 1e-12)
     b_mono = np.asarray(mono.forward(f_mono))
     b_strm = np.asarray(strm.forward(f_mono))
-    if lc > 1:
-        np.testing.assert_array_equal(b_strm, b_mono)
-    else:
-        _assert_agree(b_strm, b_mono, lc, B, 1e-12)
+    _assert_agree(b_strm, b_mono, bitwise, 1e-12)
     d = wigner.wigner_d_table(B)
     f_ref = np.asarray(soft.inverse_soft(fhat, d))
     np.testing.assert_allclose(f_strm, f_ref, rtol=1e-11, atol=1e-11)
@@ -70,7 +90,7 @@ def test_streaming_bitwise_equals_monolithic_f32():
     fhat = soft.random_coeffs(B, seed=3).astype(np.complex64)
     f_mono = np.asarray(mono.inverse(fhat))
     f_strm = np.asarray(strm.inverse(fhat))
-    _assert_agree(f_strm, f_mono, 4, B, 1e-5)
+    _assert_agree(f_strm, f_mono, False, 1e-5)
     np.testing.assert_array_equal(np.asarray(strm.forward(f_mono)),
                                   np.asarray(mono.forward(f_mono)))
     f_ref = np.asarray(soft.inverse_soft(fhat.astype(np.complex128)))
@@ -434,19 +454,38 @@ def test_wigner_window_iter_matches_table():
 
 
 def test_static_schedule_auto_engages_streaming_under_tight_budget():
-    # monolithic V=1 at B=16/f32 needs ~36.0 KB VMEM with its smallest
-    # Wigner panel (P = 8) and the smallest tiled chunk (lchunk=8) ~34.0
-    # KB: a 35 KB budget forces the planner onto the chunked schedule
-    # instead of failing.
-    t = plan_mod.plan(16, dtype=jnp.float32, impl="fused",
-                      vmem_budget=35_000)
-    assert t.schedule.lchunk == 8
-    assert t.schedule.vmem_bytes <= 35_000
+    # A compiled chunk sits on the lanes, so the resolver's candidates are
+    # multiples of 128 or B on every backend; plans at B = 256 resolve
+    # and build on the CPU without running a kernel.  bf16 has no
+    # monolithic kernel: a budget that holds the 128-row chunk but not
+    # the whole degree range engages the 128-row chunk at the widest lane
+    # width.
+    wide = plan_mod.plan(256, jnp.float32, precision="bf16", interpret=False)
+    assert wide.schedule.lchunk == 256 and wide.V == 8
+    budget = wide.schedule.vmem_bytes - 1
+    t = plan_mod.plan(256, jnp.float32, precision="bf16", interpret=False,
+                      vmem_budget=budget)
+    assert (t.V, t.schedule.lchunk) == (8, 128)
+    assert t.schedule.vmem_bytes <= budget
+    # fp32 keeps the monolithic kernel, down to an 8-row panel: a chunk's
+    # panel is the chunk, and 128 rows of it need more VMEM than the
+    # coefficient tile saves, so where no monolithic panel fits at this
+    # B, no compiled chunk does
+    f = plan_mod.plan(256, jnp.float32, interpret=False, vmem_budget=budget)
+    assert f.schedule.lchunk is None
+    C = f.soft_plan.gather_m.shape[1]
+    tight = autotune.estimate_vmem_bytes(L=256, J=512, C2=C * 2, tk=8,
+                                         panel=8) - 1
+    with pytest.raises(ValueError, match="no schedule fits"):
+        plan_mod.plan(256, jnp.float32, interpret=False, vmem_budget=tight)
+    # interpreted, an explicit 8-row chunk at B = 16 sums the same two
+    # products as the monolithic kernel under a budget that admits only
+    # an 8-row panel
+    strm = plan_mod.plan(16, dtype=jnp.float32, impl="fused", V=1, tk=8,
+                         lchunk=8)
+    ref = _monolithic_with_panel(16, 8, jnp.float32, tk=8)
     fhat = soft.random_coeffs(16, seed=7).astype(np.complex64)
-    # a budget that admits the monolithic kernel only with an 8-row panel:
-    # it sums the same two products the 8-row chunks do
-    ref = plan_mod.plan(16, dtype=jnp.float32, impl="fused", V=t.V,
-                        tk=t.schedule.tk, vmem_budget=36_100)
-    assert ref.schedule.lchunk is None and ref.describe()["panel"] == 8
-    np.testing.assert_array_equal(np.asarray(t.inverse(fhat)),
-                                  np.asarray(ref.inverse(fhat)))
+    f_ref = np.asarray(ref.inverse(fhat))
+    np.testing.assert_array_equal(np.asarray(strm.inverse(fhat)), f_ref)
+    np.testing.assert_array_equal(np.asarray(strm.forward(f_ref)),
+                                  np.asarray(ref.forward(f_ref)))

@@ -65,7 +65,7 @@ def _compile_hlo(fn, shapes, one_chip, **kw):
 
 def _operands(B, K, tk, V, direction):
     J, C2 = 2 * B, 16 * V
-    x = (K, J, C2) if direction == "fwd" else (K, B, C2)
+    x = (K, C2, J) if direction == "fwd" else (K, C2, B)
     return [((K, J), F32), ((K,), I32), ((K,), I32), ((J,), F32),
             (x, F32), ((K // tk,), I32)]
 
@@ -101,9 +101,30 @@ def test_fused_kernel_compiles_with_short_panels(one_chip, direction):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "inv"])
+def test_largest_bandwidth_schedule_compiles_for_v5e(one_chip, direction):
+    """B = 512, the largest bandwidth the docs size, under the default
+    VMEM budget: the planner's lane width (V = 4; V = 8 no longer fits
+    once the pipeline's double buffers are counted) and 128-row panel
+    compile.  The grid is cut to 128 cluster tiles, which leaves every
+    grid step's VMEM as it is: the whole (K, C2, J) stack at V = 4 is
+    larger than one chip's HBM."""
+    B = 512
+    t = plan(B, F32)
+    K, tk, V = _schedule(B)
+    assert (V, t.describe()["panel"]) == (4, 128)
+    assert K > 128 * tk
+    fn = dwt_fused.dwt_fused if direction == "fwd" else dwt_fused.idwt_fused
+    hlo = _compile_hlo(fn, _operands(B, 128 * tk, tk, V, direction),
+                       one_chip, B=B, tk=tk)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("direction", ["fwd", "inv"])
 @pytest.mark.parametrize("precision", ["fp32", "bf16"])
 def test_streaming_kernel_compiles_for_v5e(one_chip, precision, direction):
-    B, lchunk = 128, 32
+    """Two 128-degree chunks at B = 256: the smallest chunk the lanes
+    take."""
+    B, lchunk = 256, 128
     K, tk, V = _schedule(B)
     wdt = jnp.bfloat16 if precision == "bf16" else F32
     fn = (streaming.dwt_streaming if direction == "fwd"
